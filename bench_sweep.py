@@ -1,8 +1,10 @@
-"""Round-3 perf sweep on the real chip: measure MFU across memory/remat
-configs enabled by chunked CE + low-precision moments.  Appends one JSON line
-per variant to bench_sweep.jsonl (order: safe -> risky so OOMs lose nothing).
+"""Perf sweep on the real chip: measure MFU across memory/remat configs
+enabled by chunked CE + low-precision moments.  Appends one JSON line per
+variant to chiprun_out/bench_sweep.jsonl (order: safe -> risky so OOMs lose
+nothing).  The round-3/4 record of this sweep was taken on an older stack
+and removed at bring-up; nothing here has been re-measured since.
 
-Run: timeout 3600 python -u bench_sweep.py
+Run on the chip: chiprun --timeout 3600 -- python -u bench_sweep.py
 
 Round 9 adds the decode chunk-size sweep behind ``python -u bench_sweep.py
 decode_chunk``: times the compiled serving decode step (serving_decode_steps,
@@ -567,50 +569,34 @@ def sweep_host_tier_bytes(n_families=12, waves=3):
 
 
 def main():
-    out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "bench_sweep.jsonl")
     import sys
-    if len(sys.argv) > 1 and sys.argv[1] == "decode_chunk":
-        for rec in sweep_decode_chunk():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "prefill_chunk":
-        for rec in sweep_prefill_chunk():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "kv_dtype":
-        for rec in sweep_kv_dtype():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "attn_impl":
-        for rec in sweep_attn_impl():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "host_tier_bytes":
-        for rec in sweep_host_tier_bytes():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "spec_k":
-        for rec in sweep_spec_k():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "prefill_impl":
-        for rec in sweep_prefill_impl():
-            print(json.dumps(rec), flush=True)
-            with open(out, "a") as f:
-                f.write(json.dumps(rec) + "\n")
+
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    # chiprun_out/ is what comes back from a chip run (and git ignores it)
+    out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    out = os.path.join(out_dir, "bench_sweep.jsonl")
+
+    def record(rec):
+        print(json.dumps(rec), flush=True)
+        with open(out, "a") as f:
+            f.write(json.dumps(rec) + "\n")
+
+    sweeps = {
+        "decode_chunk": sweep_decode_chunk,
+        "prefill_chunk": sweep_prefill_chunk,
+        "kv_dtype": sweep_kv_dtype,
+        "attn_impl": sweep_attn_impl,
+        "host_tier_bytes": sweep_host_tier_bytes,
+        "spec_k": sweep_spec_k,
+        "prefill_impl": sweep_prefill_impl,
+    }
+    if len(sys.argv) > 1 and sys.argv[1] in sweeps:
+        for rec in sweeps[sys.argv[1]]():
+            record(rec)
         return
     for v in VARIANTS:
         print(f"=== {v[0]} ===", flush=True)
@@ -618,9 +604,7 @@ def main():
             rec = run_variant(*v)
         except Exception as e:  # OOM etc: record and continue
             rec = {"variant": v[0], "error": f"{type(e).__name__}: {e}"[:400]}
-        print(json.dumps(rec), flush=True)
-        with open(out, "a") as f:
-            f.write(json.dumps(rec) + "\n")
+        record(rec)
         gc.collect()
 
 

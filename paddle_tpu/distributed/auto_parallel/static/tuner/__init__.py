@@ -54,17 +54,23 @@ class DeviceSpec:
 
     @classmethod
     def detect(cls):
-        try:
-            import jax
+        """The spec of the chip jax runs on.  On the CPU platform (tests,
+        planning ahead of a deployment) the analytic planner gets the
+        field defaults — a v5e, stated here, not guessed; an accelerator
+        whose ``device_kind`` is not in ``_CHIPS`` is an error."""
+        import jax
 
-            kind = jax.devices()[0].device_kind
-            for prefix, (tf, gib, hbm, ici) in _CHIPS.items():
-                if kind.startswith(prefix):
-                    return cls(peak_tflops=tf, hbm_gib=gib, hbm_gbps=hbm,
-                               ici_gbps=ici)
-        except Exception:
-            pass
-        return cls()
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
+            return cls()
+        for prefix, (tf, gib, hbm, ici) in _CHIPS.items():
+            if dev.device_kind.startswith(prefix):
+                return cls(peak_tflops=tf, hbm_gib=gib, hbm_gbps=hbm,
+                           ici_gbps=ici)
+        raise ValueError(
+            f"DeviceSpec.detect: no spec on record for device_kind "
+            f"{dev.device_kind!r} (platform {dev.platform!r}); known: "
+            f"{sorted(_CHIPS)} — pass an explicit DeviceSpec")
 
 
 @dataclasses.dataclass

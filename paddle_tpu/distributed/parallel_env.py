@@ -57,18 +57,9 @@ def init_parallel_env():
               or os.environ.get("MASTER_ADDR")
               or os.environ.get("PADDLE_MASTER"))
     nnodes = int(os.environ.get("PADDLE_NNODES", "1"))
-    # probe the distributed client WITHOUT jax.process_count(): that call
-    # initializes the XLA backend, after which jax.distributed.initialize
-    # refuses to run.  The probe is private jax API — degrade to
-    # "not initialized" if it moves (initialize() itself then reports
-    # double-init, caught below).
-    try:
-        from jax._src import distributed as _jdist
-
-        already_initialized = _jdist.global_state.client is not None
-    except Exception:
-        already_initialized = False
-    if master and nnodes > 1 and not already_initialized:
+    # NOT jax.process_count(): that call initializes the XLA backend,
+    # after which jax.distributed.initialize refuses to run
+    if master and nnodes > 1 and not jax.distributed.is_initialized():
         port = os.environ.get("MASTER_PORT")
         addr = master if ":" in master or not port else f"{master}:{port}"
         try:

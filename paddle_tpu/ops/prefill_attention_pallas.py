@@ -37,7 +37,23 @@ grid:
 * **CPU = interpret mode.**  ``interpret`` defaults to
   ``jax.default_backend() != "tpu"`` so the parity suite runs the same
   kernel logic on CPU; never the literal ``True`` in product code
-  (tpu-lint PTL012).
+  (tpu-lint PTL012).  On the chip the kernel compiles and runs inside
+  the engine (PR 21; ``chip_smoke.py`` asserts the ``tpu_custom_call``
+  in the compiled prefill program, ``tests/test_chip_compile.py``
+  compiles it at real widths with ``interpret=False``).
+* **What the TPU lowering needs.**  The same views as the decode kernel
+  (ops/paged_attention_pallas.py): data as ``[.., C, Hkv*D]`` with
+  head ``h`` at lane block ``h`` (``D`` a multiple of 128), f16 scale
+  leaves as ``scale_view`` int16 bits ``[.., Hkv, C]`` converted with
+  integer math (v5e has no f16 vector type; with ``kv_dtype="int8"``
+  both ``T`` and ``C`` must be multiples of 128 — a DMA window on the
+  lane dim is whole tiles).  A scale leaf keeps every kv head in one
+  tile, so heads stage their rows into a shared scratch and the LAST
+  head appends them.  DMA window starts carry ``pl.multiple_of`` (the
+  alignment contract below, stated so the compiler can use it), and all
+  index arithmetic is typed i32 (x64).  Geometry outside this fails in
+  the compiler, loudly — there is no interpreter or reference fallback
+  on a TPU.
 
 Geometry the kernel does not cover falls back to the bitwise reference
 path: ``fused_prefill_supported`` returns the reason and the shared
@@ -60,14 +76,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from paddle_tpu.ops.paged_attention_pallas import warn_fallback  # noqa: F401 (re-export: the shared fallback logger)
+from paddle_tpu.ops.paged_attention_pallas import (
+    _vec_transpose, data_view, f16_bits_to_f32, f32_to_f16_bits, head_scale,
+    scale_unview, scale_view, warn_fallback,  # noqa: F401 (re-export: the shared fallback logger)
+)
 
 __all__ = ["fused_prefill_attention", "fused_prefill_supported",
            "warn_fallback"]
 
 _NEG_INF = -1e30
 _Q8_MAX = 127.0
-_Q8_SCALE_DTYPE = jnp.float16
 
 
 def fused_prefill_supported(chunk_size, lmax, t, paged):
@@ -99,17 +117,25 @@ def fused_prefill_supported(chunk_size, lmax, t, paged):
     return None
 
 
-def _prefill_kernel(*refs, chunk, t, group, scale, quant, paged, nw):
+def _prefill_kernel(*refs, chunk, t, group, d, scale, quant, paged, nw):
     """One (kv head, kv chunk) step: stage + append at ``j == 0``, fold
     prefix block ``j`` (double-buffered DMA reads), fold the chunk's own
     rows and finalize at the last ``j``.
 
     refs (scalar-prefetch first): offset [1], ptr ([W] table row when
-    paged, [1] slot when dense), q [1, G*T, D], k_new/v_new [T, 1, D]
-    blocks, the pool/cache leaves (ANY-space, aliased to the pool
-    outputs), the output tile, and VMEM scratch — running softmax state,
-    staged new rows (pool dtype + f16 scales when quant), 2-slot read
-    buffers, and read/write DMA semaphores.
+    paged, [1] slot when dense), q [1, G*T, D], k_new/v_new [T, D] (head
+    ``h``'s lane block of the [T, Hkv*D] view), the pool/cache leaves
+    (ANY-space, aliased to the pool outputs; data as the [.., C, Hkv*D]
+    view, f16 scales as ``scale_view``'s [.., Hkv, C] int16 bits), the
+    output tile, and VMEM scratch — running softmax state, staged new
+    rows (pool dtype, plus the [Hkv, T] scale bits of EVERY head when
+    quant), 2-slot read buffers, and read/write DMA semaphores.
+
+    A DMA window on a scale leaf spans all kv heads (the 4-wide head dim
+    is not sliceable): each program stages its head's row into the
+    shared [Hkv, T] scratch (the head axis is swept sequentially) and
+    the LAST head appends the whole tile; reads fetch whole [Hkv, C]
+    tiles and ``head_scale`` picks the row in VMEM.
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -125,59 +151,64 @@ def _prefill_kernel(*refs, chunk, t, group, scale, quant, paged, nw):
          o_ref, okp_ref, ovp_ref,
          acc_ref, m_ref, l_ref,
          kwb, vwb, kbuf, vbuf, rsem, wsem) = refs
+    i32 = jnp.int32
+    f32 = jnp.float32
     h = pl.program_id(0)
     j = pl.program_id(1)
+    last_head = h == pl.num_programs(0) - 1
     n_chunks = pl.num_programs(1)
     c = chunk
     rows = group * t
     off = off_ref[0]
+    # head h's lane block of a [.., Hkv*D] view
+    lanes = pl.ds(pl.multiple_of(h * d, d), d)
+    # lax.div/rem, not // and %: the jnp forms trace through a jitted
+    # helper whose weak int64 literal Mosaic cannot narrow under x64
+    blk0 = jax.lax.div(off, i32(c))
+    # the alignment contract (off % t == 0) as facts the compiler can
+    # use: a DMA window must start on a tile boundary.  A chunk spanning
+    # whole blocks starts at row 0 of its first block.
+    off_t = pl.multiple_of(off, t)
+    r0 = pl.multiple_of(jax.lax.rem(off, i32(c)), t) if t < c else 0
+    # (semaphore indices are i32 scalars for the same reason: a python
+    # int index is an i64 Mosaic's memref_slice refuses)
 
     def write_dmas():
         """The append DMA descriptors (identical at start and wait time):
-        (dma, valid) per started copy."""
-        r0 = off % c  # nw > 1 implies off % c == 0 (alignment contract)
+        (dma, valid) per copy.  Data leaves append per head; scale
+        leaves append once, by the last head."""
+        pairs = [(kwb, okp_ref, None), (vwb, ovp_ref, None)]
+        if quant:
+            pairs += [(ksb, oks_ref, last_head), (vsb, ovs_ref, last_head)]
         out = []
         if not paged:
             slot = ptr_ref[0]
-            pairs = [(kwb, okp_ref), (vwb, ovp_ref)]
-            if quant:
-                pairs += [(ksb, oks_ref), (vsb, ovs_ref)]
-            for li, (src, dst) in enumerate(pairs):
-                if src.shape[0] == 1:  # scale leaf [1, T] -> [T]
-                    dma = pltpu.make_async_copy(
-                        src.at[0], dst.at[slot, pl.ds(off, t), h],
-                        wsem.at[li, 0])
-                else:
-                    dma = pltpu.make_async_copy(
-                        src, dst.at[slot, pl.ds(off, t), h, :],
-                        wsem.at[li, 0])
-                out.append((dma, off >= 0))  # always valid (gate-checked)
+            for li, (src, dst, only) in enumerate(pairs):
+                dst = dst.at[slot, :, pl.ds(off_t, t)] if only is not None \
+                    else dst.at[slot, pl.ds(off_t, t), lanes]
+                dma = pltpu.make_async_copy(src, dst, wsem.at[i32(li), i32(0)])
+                # always in bounds (gate-checked)
+                out.append((dma, off >= 0 if only is None else only))
             return out
         w = ptr_ref.shape[0]
         n_blocks = okp_ref.shape[0]
         rows_m = t if nw == 1 else c
         for mi in range(nw):
-            wb = off // c + mi
-            blk = ptr_ref[jnp.clip(wb, 0, w - 1)]
+            wb = blk0 + i32(mi)
+            blk = ptr_ref[jnp.clip(wb, i32(0), i32(w - 1))]
             # the reference scatter's mode="drop": out-of-span or
             # sentinel destinations never get a DMA
             valid = (wb < w) & (blk < n_blocks)
-            phys = jnp.clip(blk, 0, n_blocks - 1)
-            pairs = [(kwb, okp_ref), (vwb, ovp_ref)]
-            if quant:
-                pairs += [(ksb, oks_ref), (vsb, ovs_ref)]
-            for li, (src, dst) in enumerate(pairs):
-                if src.shape[0] == 1:  # scale leaf [1, T]
-                    dma = pltpu.make_async_copy(
-                        src.at[0, pl.ds(mi * c, rows_m)],
-                        dst.at[phys, pl.ds(r0, rows_m), h],
-                        wsem.at[li, mi])
+            phys = jnp.clip(blk, i32(0), i32(n_blocks - 1))
+            for li, (src, dst, only) in enumerate(pairs):
+                if only is not None:
+                    src = src.at[:, pl.ds(mi * c, rows_m)]
+                    dst = dst.at[phys, :, pl.ds(r0, rows_m)]
                 else:
-                    dma = pltpu.make_async_copy(
-                        src.at[pl.ds(mi * c, rows_m)],
-                        dst.at[phys, pl.ds(r0, rows_m), h, :],
-                        wsem.at[li, mi])
-                out.append((dma, valid))
+                    src = src.at[pl.ds(mi * c, rows_m)]
+                    dst = dst.at[phys, pl.ds(r0, rows_m), lanes]
+                dma = pltpu.make_async_copy(src, dst, wsem.at[i32(li), i32(mi)])
+                out.append((dma, valid if only is None else valid & only))
         return out
 
     def read_dmas(ji, sl):
@@ -188,53 +219,68 @@ def _prefill_kernel(*refs, chunk, t, group, scale, quant, paged, nw):
             n_blocks = kp_ref.shape[0]
             # mode="clip": a sentinel entry reads a real block whose rows
             # the offset mask discards, never an OOB default
-            blk = jnp.clip(ptr_ref[jnp.clip(ji, 0, w - 1)], 0,
-                           n_blocks - 1)
-            srcs = [(kp_ref.at[blk, :, h, :], kbuf.at[sl]),
-                    (vp_ref.at[blk, :, h, :], vbuf.at[sl])]
-            if quant:
-                srcs += [(ks_ref.at[blk, :, h], ksbuf.at[sl, 0]),
-                         (vs_ref.at[blk, :, h], vsbuf.at[sl, 0])]
+            blk = jnp.clip(ptr_ref[jnp.clip(ji, i32(0), i32(w - 1))],
+                           i32(0), i32(n_blocks - 1))
+            data = lambda ref: ref.at[blk, :, lanes]
+            sc = lambda ref: ref.at[blk]
         else:
             slot = ptr_ref[0]
-            srcs = [(kp_ref.at[slot, pl.ds(ji * c, c), h, :], kbuf.at[sl]),
-                    (vp_ref.at[slot, pl.ds(ji * c, c), h, :], vbuf.at[sl])]
-            if quant:
-                srcs += [(ks_ref.at[slot, pl.ds(ji * c, c), h],
-                          ksbuf.at[sl, 0]),
-                         (vs_ref.at[slot, pl.ds(ji * c, c), h],
-                          vsbuf.at[sl, 0])]
-        return [pltpu.make_async_copy(s, d, rsem.at[li, sl])
-                for li, (s, d) in enumerate(srcs)]
+            span = pl.ds(pl.multiple_of(ji * c, c), c)
+            data = lambda ref: ref.at[slot, span, lanes]
+            sc = lambda ref: ref.at[slot, :, span]
+        srcs = [(data(kp_ref), kbuf.at[sl]), (data(vp_ref), vbuf.at[sl])]
+        if quant:
+            srcs += [(sc(ks_ref), ksbuf.at[sl]), (sc(vs_ref), vsbuf.at[sl])]
+        return [pltpu.make_async_copy(s, dst, rsem.at[i32(li), sl])
+                for li, (s, dst) in enumerate(srcs)]
+
+    def fold(k, v, live):
+        """Fold one [*, D] key/value tile into the running online
+        softmax; masked lanes are zeroed after the exp (the reference's
+        fully-masked-chunk pollution guard).  Returns (acc, l)."""
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32) * scale
+        s = jnp.where(live, s, f32(_NEG_INF))
+        m = m_ref[0]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(live, jnp.exp(s - m_new[:, None]), f32(0.0))
+        corr = jnp.exp(m - m_new)
+        l_new = l_ref[0] * corr + jnp.sum(p, axis=-1)
+        acc = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        return acc, l_new
 
     @pl.when(j == 0)
     def _init_stage_append():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        kn = kn_ref[:, 0, :]                                # [T, D]
-        vn = vn_ref[:, 0, :]
         if quant:
+            mine = jax.lax.broadcasted_iota(i32, ksb.shape, 0) == h
+
             # the reference's _q8_quantize, bit for bit: absmax over the
             # head dim, f16-ROUNDED scale as the divisor
-            def q8(x):
-                xf = x.astype(jnp.float32)
-                amax = jnp.max(jnp.abs(xf), axis=-1)
-                sc = (amax / _Q8_MAX).astype(_Q8_SCALE_DTYPE)
-                inv = 1.0 / jnp.maximum(sc.astype(jnp.float32), 1e-8)
-                qv = jnp.clip(jnp.round(xf * inv[:, None]),
-                              -_Q8_MAX, _Q8_MAX)
-                return qv.astype(jnp.int8), sc
+            def stage(x_ref, data, bits):
+                xf = x_ref[...].astype(f32)                  # [T, D]
+                amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
+                sc = f32_to_f16_bits(amax / f32(_Q8_MAX))    # [T, 1]
+                inv = f32(1.0) / jnp.maximum(f16_bits_to_f32(sc),
+                                             f32(1e-8))
+                qv = jnp.minimum(jnp.maximum(jnp.round(xf * inv),
+                                             f32(-_Q8_MAX)), f32(_Q8_MAX))
+                data[...] = qv.astype(jnp.int8)
+                # this head's row of the shared [Hkv, T] scale tile
+                bits[...] = jnp.where(
+                    mine, _vec_transpose(sc, to_col=False),
+                    bits[...].astype(i32)).astype(bits.dtype)
 
-            qk, sk = q8(kn)
-            qv, sv = q8(vn)
-            kwb[...] = qk
-            ksb[0] = sk
-            vwb[...] = qv
-            vsb[0] = sv
+            stage(kn_ref, kwb, ksb)
+            stage(vn_ref, vwb, vsb)
         else:
-            kwb[...] = kn.astype(kwb.dtype)
-            vwb[...] = vn.astype(vwb.dtype)
+            kwb[...] = kn_ref[...].astype(kwb.dtype)
+            vwb[...] = vn_ref[...].astype(vwb.dtype)
         for dma, valid in write_dmas():
             @pl.when(valid)
             def _(dma=dma):
@@ -243,49 +289,36 @@ def _prefill_kernel(*refs, chunk, t, group, scale, quant, paged, nw):
 
         @pl.when(off > 0)
         def _():
-            for dma in read_dmas(0, 0):
+            for dma in read_dmas(j, j):
                 dma.start()
 
     work = j * c < off  # this prefix block holds >= 1 written row
 
     @pl.when(work)
     def _fold_prefix():
-        sl = j % 2
+        sl = jax.lax.rem(j, i32(2))
         for dma in read_dmas(j, sl):
             dma.wait()
         nxt = j + 1
 
         @pl.when(nxt * c < off)
         def _():
-            for dma in read_dmas(nxt, nxt % 2):
+            for dma in read_dmas(nxt, jax.lax.rem(nxt, i32(2))):
                 dma.start()
 
-        k = kbuf[sl].astype(jnp.float32)                    # [C, D]
-        v = vbuf[sl].astype(jnp.float32)
+        k = kbuf[sl].astype(f32)                            # [C, D]
+        v = vbuf[sl].astype(f32)
         if quant:
-            k = k * ksbuf[sl, 0].astype(jnp.float32)[:, None]
-            v = v * vsbuf[sl, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q_ref[0], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [G*T, C]
+            k = k * head_scale(ksbuf[sl], h)
+            v = v * head_scale(vsbuf[sl], h)
         # every prefix row < offset is causally visible to EVERY query of
         # this chunk (q_pos >= offset); rows at/past the offset in the
         # partially-filled block are exactly the bytes the append DMA may
         # be writing — masked lanes are zeroed after the exp, so a torn
         # or stale read there never reaches the output
-        k_live = j * c + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, c), 1) < off
-        s = jnp.where(k_live, s, _NEG_INF)
-        m = m_ref[0]
-        l = l_ref[0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.where(k_live, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        k_live = j * c + jax.lax.broadcasted_iota(i32, (rows, c), 1) < off
+        acc, l_new = fold(k, v, k_live)
+        acc_ref[...] = acc
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(j == n_chunks - 1)
@@ -293,31 +326,20 @@ def _prefill_kernel(*refs, chunk, t, group, scale, quant, paged, nw):
         # the chunk's own rows, exactly as the reference reads them back
         # after its scatter: int8 rows dequantize the staged quantized
         # copy, float rows cast through the pool dtype
-        k = kwb[...].astype(jnp.float32)
-        v = vwb[...].astype(jnp.float32)
+        k = kwb[...].astype(f32)
+        v = vwb[...].astype(f32)
         if quant:
-            k = k * ksb[0].astype(jnp.float32)[:, None]
-            v = v * vsb[0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q_ref[0], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [G*T, T]
-        # row r of the [G, T] query tile is chunk token r % t; new key i
-        # sits at global position offset + i — intra-chunk causal mask
+            k = k * head_scale(ksb[...], h)
+            v = v * head_scale(vsb[...], h)
+        # row r of the [G, T] query tile is chunk token r % t (a 3-D
+        # iota whose leading dims merge — Mosaic refuses the 2-D -> 1-D
+        # cast); new key i sits at global position offset + i —
+        # intra-chunk causal mask
         q_rel = jax.lax.broadcasted_iota(
-            jnp.int32, (group, t), 1).reshape(rows)
-        k_rel = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
-        live = k_rel <= q_rel[:, None]
-        s = jnp.where(live, s, _NEG_INF)
-        m = m_ref[0]
-        l = l_ref[0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.where(live, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_safe = jnp.maximum(l_new, 1e-30)
+            i32, (group, t, t), 1).reshape(rows, t)
+        k_rel = jax.lax.broadcasted_iota(i32, (rows, t), 1)
+        acc, l_new = fold(k, v, k_rel <= q_rel)
+        l_safe = jnp.maximum(l_new, f32(1e-30))
         o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
         for dma, valid in write_dmas():
             @pl.when(valid)
@@ -364,60 +386,49 @@ def fused_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
 
     q2 = q.reshape(t, hkv, g, d).transpose(1, 2, 0, 3) \
         .reshape(hkv, gt, d).astype(jnp.float32)
-    kn2 = k_new.reshape(t, hkv, d)
-    vn2 = v_new.reshape(t, hkv, d)
 
+    # new rows and cache data go in as ``data_view`` (head h = lane block
+    # h), f16 scale leaves as ``scale_view`` — paged_attention_pallas
     # index maps receive (h, j, *scalar_refs); ``j * 0`` keeps the index
     # dtype i32 under jax_enable_x64 (the flash_attention Mosaic idiom)
     q_idx = lambda hi, ji, off, ptr: (hi, ji * 0, ji * 0)
-    n_idx = lambda hi, ji, off, ptr: (ji * 0, hi, ji * 0)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [pl.BlockSpec((1, gt, d), q_idx),
-                pl.BlockSpec((t, 1, d), n_idx),
-                pl.BlockSpec((t, 1, d), n_idx)]
-    args = [q2, kn2, vn2]
-    pool_dtype = k_data.dtype
+    n_idx = lambda hi, ji, off, ptr: (ji * 0, hi)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     if quant:
-        in_specs += [any_spec] * 4
-        args += [k_cache[0], k_cache[1], v_cache[0], v_cache[1]]
-        pool_leaves = [k_cache[0], k_cache[1], v_cache[0], v_cache[1]]
-        # operand index space counts the 2 scalar-prefetch operands
-        aliases = {5: 1, 6: 2, 7: 3, 8: 4}
+        pool_leaves = [data_view(k_cache[0]), scale_view(k_cache[1]),
+                       data_view(v_cache[0]), scale_view(v_cache[1])]
     else:
-        in_specs += [any_spec] * 2
-        args += [k_cache, v_cache]
-        pool_leaves = [k_cache, v_cache]
-        aliases = {5: 1, 6: 2}
+        pool_leaves = [data_view(k_cache), data_view(v_cache)]
+    in_specs = [pl.BlockSpec((1, gt, d), q_idx),
+                pl.BlockSpec((t, d), n_idx),
+                pl.BlockSpec((t, d), n_idx)] + [any_spec] * len(pool_leaves)
+    args = [q2, data_view(k_new[0]), data_view(v_new[0]), *pool_leaves]
+    # operand index space counts the 2 scalar-prefetch operands
+    aliases = {5 + li: 1 + li for li in range(len(pool_leaves))}
     out_specs = [pl.BlockSpec((1, gt, d), q_idx)] \
         + [any_spec] * len(pool_leaves)
     out_shape = [jax.ShapeDtypeStruct((hkv, gt, d), jnp.float32)] \
         + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pool_leaves]
 
+    pool_dtype = k_data.dtype
     stage = [pltpu.VMEM((t, d), pool_dtype)]
-    if quant:
-        stage += [pltpu.VMEM((1, t), _Q8_SCALE_DTYPE)]
     rbuf = [pltpu.VMEM((2, c, d), pool_dtype)]
     if quant:
-        rbuf += [pltpu.VMEM((2, 1, c), _Q8_SCALE_DTYPE)]
+        stage += [pltpu.VMEM((hkv, t), jnp.int16)]
+        rbuf += [pltpu.VMEM((2, hkv, c), jnp.int16)]
     scratch = [
         pltpu.VMEM((gt, d), jnp.float32),
         pltpu.VMEM((8, gt), jnp.float32),
         pltpu.VMEM((8, gt), jnp.float32),
         *stage, *stage,                                     # k then v
         *rbuf, *rbuf,
-        pltpu.SemaphoreType.DMA((4 if quant else 2, 2)),
-        pltpu.SemaphoreType.DMA((4 if quant else 2, nw)),
+        pltpu.SemaphoreType.DMA((len(pool_leaves), 2)),
+        pltpu.SemaphoreType.DMA((len(pool_leaves), nw)),
     ]
-    # the append runs as guarded DMAs the compiler cannot see through —
-    # without the side-effect flag it would be dead-code eliminated
-    kwargs = {}
-    if hasattr(pltpu, "CompilerParams"):
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            has_side_effects=True)
 
     outs = pl.pallas_call(
         functools.partial(
-            _prefill_kernel, chunk=c, t=t, group=g, scale=float(scale),
+            _prefill_kernel, chunk=c, t=t, group=g, d=d, scale=float(scale),
             quant=quant, paged=paged, nw=nw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -427,11 +438,18 @@ def fused_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
             scratch_shapes=scratch),
         out_shape=out_shape,
         input_output_aliases=aliases,
+        # the append runs as guarded DMAs the compiler cannot see through
+        # — without the side-effect flag it would be dead-code eliminated
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
-        **kwargs,
     )(off_arr, ptr, *args)
     out = outs[0].reshape(hkv, g, t, d).transpose(2, 0, 1, 3) \
         .reshape(1, t, h, d).astype(q.dtype)
+
     if quant:
-        return out, (outs[1], outs[2]), (outs[3], outs[4])
-    return out, outs[1], outs[2]
+        return (out,
+                (outs[1].reshape(k_data.shape),
+                 scale_unview(outs[2], k_cache[1])),
+                (outs[3].reshape(k_data.shape),
+                 scale_unview(outs[4], v_cache[1])))
+    return out, outs[1].reshape(k_data.shape), outs[2].reshape(k_data.shape)

@@ -100,6 +100,7 @@ from collections import OrderedDict, deque
 import numpy as np
 
 import jax.numpy as jnp
+from jax.errors import JaxRuntimeError
 
 from paddle_tpu.models.llama_decode import (
     _canon_weight_dtype, _decode_params_of, quantize_decode_weights,
@@ -135,15 +136,7 @@ _LOG = logging.getLogger(__name__)
 # (runtime/compile-service hiccups surface as XlaRuntimeError); the
 # injected twin from serving/faults.py rides the same path so the retry
 # machinery is provable without a flaky device
-try:
-    from jax.errors import JaxRuntimeError as _XLA_ERROR
-except ImportError:  # pragma: no cover — older jax spellings
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError as _XLA_ERROR
-    except ImportError:
-        class _XLA_ERROR(Exception):
-            pass
-_RETRYABLE = (_XLA_ERROR, InjectedDispatchError)
+_RETRYABLE = (JaxRuntimeError, InjectedDispatchError)
 
 
 class EngineOverloaded(RuntimeError):
@@ -999,7 +992,10 @@ class ServingEngine:
                 "prompt_buckets must be sorted strictly ascending (submit "
                 f"bisects over them), got {self._buckets}")
         # host mirror of the carried next-token per slot; lengths and the
-        # slot -> request table live on the cache manager
+        # slot -> request table live on the cache manager.  Handed to a
+        # dispatch as jnp.asarray(self._cur.copy()) — a copy: the mirror is written
+        # in place while dispatches are in flight, and the CPU backend may
+        # alias a numpy operand zero-copy (kv_cache.device_lengths)
         self._cur = np.zeros((self._B,), np.int32)
         if mode == "spec":
             self._hist = jnp.zeros((self._B, self._lmax), jnp.int32)
@@ -2363,7 +2359,7 @@ class ServingEngine:
         if self._mode == "greedy":
             def go(attempt):
                 self._fault_point("dispatch", attempt)
-                return self._call_decode(jnp.asarray(self._cur), dev_len)
+                return self._call_decode(jnp.asarray(self._cur.copy()), dev_len)
             with m.span_decode if m is not None else _NULL_CTX:
                 toks, okd, self._kv.caches = self._retry(
                     go, "decode dispatch")
@@ -2388,7 +2384,7 @@ class ServingEngine:
 
             def go(attempt):
                 self._fault_point("dispatch", attempt)
-                return self._call_spec(jnp.asarray(self._cur), dev_len,
+                return self._call_spec(jnp.asarray(self._cur.copy()), dev_len,
                                        jnp.asarray(active), k)
             with m.span_spec if m is not None else _NULL_CTX:
                 blk, j, cur, _, oks, self._kv.caches, self._hist, \
@@ -2456,9 +2452,9 @@ class ServingEngine:
         use_host_len = use_host.copy()
         use_host_len[list(self._dev_first)] = True
         if self._dev_cur is None:
-            cur = jnp.asarray(self._cur)
+            cur = jnp.asarray(self._cur.copy())
         else:
-            cur = jnp.where(jnp.asarray(use_host), jnp.asarray(self._cur),
+            cur = jnp.where(jnp.asarray(use_host), jnp.asarray(self._cur.copy()),
                             self._dev_cur)
         for s, f in self._dev_first.items():
             cur = cur.at[s].set(f[0])

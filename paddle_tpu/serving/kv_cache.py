@@ -415,7 +415,11 @@ class KVCacheManager:
         """The device lengths operand for one dispatch: the host mirror
         with every non-``active`` slot masked to ``max_len`` (write-drop
         parking)."""
-        return masked_lengths(jnp.asarray(self.lengths),
+        # a host-side COPY: the CPU backend may alias a numpy buffer
+        # zero-copy, dispatch is asynchronous, and the scheduler bumps
+        # ``lengths`` in place right after dispatching — an aliased
+        # operand would race with the program reading it
+        return masked_lengths(jnp.asarray(self.lengths.copy()),
                               jnp.asarray(active), self.max_len)
 
 
@@ -1109,10 +1113,12 @@ class PagedKVCacheManager(KVCacheManager):
 
     # -------------------------------------------------------------- device
     def device_tables(self):
-        """The traced ``[B, W]`` block-table operand for one dispatch."""
-        return jnp.asarray(self.block_tables)
+        """The traced ``[B, W]`` block-table operand for one dispatch — a
+        COPY of the host mirror (see ``device_lengths``: the mirror is
+        mutated in place while dispatches are in flight)."""
+        return jnp.asarray(self.block_tables.copy())
 
     def device_draft_tables(self):
         """The traced ``[B, W]`` DRAFT block-table operand — same pool,
-        second tenant."""
-        return jnp.asarray(self.draft_tables)
+        second tenant (a copy, like ``device_tables``)."""
+        return jnp.asarray(self.draft_tables.copy())

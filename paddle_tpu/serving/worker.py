@@ -569,6 +569,8 @@ def main(argv=None):
     ndev = int(cfg.get("devices_per_worker", 1))
     if cfg.get("platform", "cpu") == "cpu" and ndev > 1:
         jax.config.update("jax_num_cpu_devices", ndev)
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     proc = _WorkerProc(cfg, role, idx)
     signal.signal(signal.SIGTERM, lambda *_: setattr(proc, "draining", True))

@@ -1,0 +1,39 @@
+"""Where JAX's persistent compilation cache lives, for the entry points.
+
+The 16-layer serving and training programs take minutes to compile cold,
+and every fresh process would pay that again.  The entry points
+(``chip_smoke.py``, ``bench.py``, ``bench_sweep.py``,
+``python -m paddle_tpu.serving.worker``) call :func:`enable_compile_cache`
+once, before their first compile; ``import paddle_tpu`` never does — a
+library import must not decide where a process writes.
+
+The directory is part of the cache key, so it is placed from outside when
+``JAX_COMPILATION_CACHE_DIR`` is set (JAX reads the variable itself; this
+module then sets nothing in code) and otherwise sits at ONE fixed path
+inside the checkout — never built from a temporary name, a pid or the
+time, which would make every run a miss.
+"""
+from __future__ import annotations
+
+import os
+
+__all__ = ["CACHE_DIR", "enable_compile_cache"]
+
+#: the fixed in-checkout location (listed in .gitignore)
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def enable_compile_cache():
+    """Turn the persistent compilation cache on; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already taken the
+    directory from the environment and nothing is set here."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR

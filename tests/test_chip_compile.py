@@ -1,0 +1,186 @@
+"""Chipless compiles for a described TPU v5e: every Pallas kernel on the
+serving and training main paths, at the real widths of the ``bench.py`` /
+``chip_smoke.py`` model (hidden 2048, 16 heads / 4 kv heads, head dim 128,
+Lmax 2048, batch 8 serving / 16 training, bf16 and int8+f16-scale caches).
+
+Interpret-mode parity suites run the kernels' LOGIC on the CPU; they cannot
+see what the TPU lowering refuses (block shapes off the (8, 128) tiling, f16
+vector loads on v5e, DMA windows on a padded minor dim, int64 indices under
+x64, scoped-VMEM overflow).  These tests hand the installed TPU compiler the
+shapes — no chip, nothing runs — so each later PR keeps the kernels
+compilable for free.  A compile that passes is not a chip run:
+``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU library, and every xdist worker imports
+every test file), in this ONE file, in the test's own process.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu  # noqa: F401  (x64 mode: the index-dtype trap the kernels guard)
+from paddle_tpu.ops.decode_attention import init_kv_cache, init_kv_pool
+
+B, H, HKV, D, LMAX, C = 8, 16, 4, 128, 2048, 128
+G = H // HKV
+T_PREFILL = 256
+TRAIN_B, TRAIN_L = 16, 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on chip 0 of a described v5e 2x2.  For the
+    module, the persistent compilation cache is off (an entry written for
+    a described chip cannot be read back without one — the next run would
+    warn and recompile anyway) and the matmul precision is JAX's default,
+    as on the chip: conftest's "highest" (for the float64-referenced
+    numeric tests) would ask Mosaic for fp32 passes over bf16 operands,
+    which it refuses and no product path requests."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here / library held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_default_matmul_precision)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    cc.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was[0])
+        jax.config.update("jax_default_matmul_precision", was[1])
+        cc.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, *args, **jit_kw):
+    """Compile for the described chip; the kernel must be IN the program."""
+    compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _cache(kind, sharding):
+    """Abstract (k, v) caches of one layer at serving widths."""
+    dtype = "int8" if kind.endswith("int8") else jnp.bfloat16
+    if kind.startswith("paged"):
+        make = functools.partial(init_kv_pool, B * LMAX // C, C, HKV, D, dtype)
+    else:
+        make = functools.partial(init_kv_cache, B, LMAX, HKV, D, dtype)
+    return _shapes(jax.eval_shape(make), sharding)
+
+
+@pytest.mark.parametrize("t", [1, 5], ids=["step", "verify5"])
+@pytest.mark.parametrize("kind", ["dense-bf16", "paged-bf16", "paged-int8"])
+def test_fused_decode_kernel_compiles(one_chip, kind, t):
+    """``attn_impl="pallas"``: the decode step (T=1) and the speculative
+    verify forward (T=k+1) over dense, paged and paged-int8 caches."""
+    from paddle_tpu.ops.paged_attention_pallas import fused_decode_attention
+
+    k, v = _cache(kind, one_chip)
+    qg = jax.ShapeDtypeStruct((B, HKV, G, t, D), jnp.float32,
+                              sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    args = [qg, k, v, lengths]
+    if kind.startswith("paged"):
+        args.append(jax.ShapeDtypeStruct((B, LMAX // C), jnp.int32,
+                                         sharding=one_chip))
+
+    def run(qg, k, v, lengths, table=None):
+        return fused_decode_attention(qg, k, v, lengths, D ** -0.5, C,
+                                      block_table=table, interpret=False)
+
+    _compile(run, *args)
+
+
+@pytest.mark.parametrize(
+    "kind", ["paged-bf16", "paged-int8", "dense-bf16", "dense-int8"])
+def test_fused_prefill_kernel_compiles(one_chip, kind):
+    """``prefill_impl="pallas"`` at the engine's default 256-token chunk:
+    attention + (quantize-on-)append, pool leaves aliased in place."""
+    from paddle_tpu.ops.prefill_attention_pallas import (
+        fused_prefill_attention)
+
+    k, v = _cache(kind, one_chip)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = sds((1, T_PREFILL, H, D), jnp.bfloat16)
+    kn = sds((1, T_PREFILL, HKV, D), jnp.bfloat16)
+    scalar = sds((), jnp.int32)
+    args = [q, kn, kn, k, v, scalar, scalar]
+    if kind.startswith("paged"):
+        args.append(sds((1, LMAX // C), jnp.int32))
+
+    def run(q, kn, vn, k, v, slot, offset, table=None):
+        return fused_prefill_attention(q, kn, vn, k, v, slot, offset,
+                                       D ** -0.5, C, block_table=table,
+                                       interpret=False)
+
+    compiled = _compile(run, *args, donate_argnums=(3, 4))
+    # the append is in place: every pool leaf is aliased input -> output
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves((k, v)))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_flash_attention_fwd_bwd_compiles(one_chip):
+    """The training attention: causal GQA flash fwd + bwd at the bench
+    batch (B16 x L2048 x H16/Hkv4 x D128, bf16)."""
+    from paddle_tpu.ops.flash_attention import flash_attention_blhd
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = sds((TRAIN_B, TRAIN_L, H, D), jnp.bfloat16)
+    kv = sds((TRAIN_B, TRAIN_L, HKV, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention_blhd(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        q, kv, kv)
+    text = compiled.as_text()
+    assert "_flash_fwd_pallas" in text and "_flash_bwd_pallas" in text
+
+
+def test_fused_rope_fwd_bwd_compiles(one_chip):
+    from paddle_tpu.ops.fused_rope import fused_rope
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = sds((TRAIN_B, TRAIN_L, H * D), jnp.bfloat16)
+    k = sds((TRAIN_B, TRAIN_L, HKV * D), jnp.bfloat16)
+    table = sds((TRAIN_L, D), jnp.float32)
+
+    def loss(q, k, cos, sin):
+        oq, ok = fused_rope(q, k, cos, sin, H, HKV, False)
+        return jnp.sum(oq.astype(jnp.float32)) \
+            + jnp.sum(ok.astype(jnp.float32))
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1)), q, k, table, table)
+
+
+def test_fused_adamw_q8_compiles(one_chip):
+    """The int8-moment AdamW update on the llama MLP leaf [2048, 5632]
+    (bf16 parameter with an f32 master, int8 first moment + f32 block
+    scales, bf16 second moment)."""
+    from paddle_tpu.ops.fused_adamw import fused_adamw_q8
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    shape = (2048, 5632)
+    n = shape[0] * shape[1]
+    args = (sds(shape, jnp.float32), sds(shape, jnp.bfloat16),
+            sds(shape, jnp.int8), sds((n // 256,), jnp.float32),
+            sds(shape, jnp.bfloat16), sds((16,), jnp.float32))
+    _compile(functools.partial(fused_adamw_q8, interpret=False), *args)

@@ -301,3 +301,101 @@ class TestFusedObservability:
         assert dispatches
         assert all(e["attn_impl"] == "fused" for e in dispatches)
         assert all(e["weight_dtype"] == "int8" for e in dispatches)
+
+
+# ---------------------------------------------------------------------------
+# kernel-level: the pieces the TPU lowering forced (PR 21) — f16 scale
+# leaves as int16 bits converted with integer math, the [.., Hkv, C]
+# scale view — against XLA's own casts and the reference chunked read
+# ---------------------------------------------------------------------------
+
+class TestScaleBitHelpers:
+    def test_f16_bits_to_f32_is_exact_for_every_half(self):
+        bits = np.arange(65536, dtype=np.uint16)
+        got = np.asarray(pap.f16_bits_to_f32(
+            jnp.asarray(bits.view(np.int16).astype(np.int32))))
+        want = bits.view(np.float16).astype(np.float32)
+        # bit for bit, NaN payloads included
+        assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+
+    def test_f32_to_f16_bits_is_xlas_cast(self):
+        rng = np.random.default_rng(7)
+        halves = np.arange(0x7C00, dtype=np.uint16).view(np.float16) \
+            .astype(np.float64)
+        mids = ((halves[:-1] + halves[1:]) / 2).astype(np.float32)
+        x = np.concatenate([
+            halves.astype(np.float32), mids, -mids,
+            np.nextafter(mids, np.float32(np.inf)),
+            np.nextafter(mids, np.float32(-np.inf)),
+            rng.integers(0, 2 ** 32, 500_000, dtype=np.uint64)
+            .astype(np.uint32).view(np.float32),
+            np.array([65504, 65519.99, 65520, 65536, 1e30, np.inf, -np.inf,
+                      0.0, -0.0, 2.0 ** -24, 2.0 ** -25, 2.0 ** -14],
+                     np.float32)])
+        got = (np.asarray(pap.f32_to_f16_bits(jnp.asarray(x)))
+               & 0xFFFF).astype(np.uint16)
+        want = np.asarray(jnp.asarray(x).astype(jnp.float16)).view(np.uint16)
+        nan = np.isnan(x)
+        assert np.array_equal(got[~nan], want[~nan])
+        # a NaN stays a NaN (the poison-quarantine signal rides the scale)
+        assert np.all((got[nan] & 0x7C00) == 0x7C00)
+        assert np.all((got[nan] & 0x03FF) != 0)
+
+    def test_scale_view_round_trips_and_keeps_heads_off_the_lanes(self):
+        x = jnp.asarray(np.random.default_rng(0).standard_normal(
+            (3, 16, 4)).astype(np.float16))
+        v = pap.scale_view(x)
+        assert v.shape == (3, 4, 16) and v.dtype == jnp.int16
+        back = pap.scale_unview(v, x)
+        assert np.array_equal(np.asarray(back).view(np.uint16),
+                              np.asarray(x).view(np.uint16))
+        col = pap.head_scale(v[1], jnp.int32(2))              # [16, 1] f32
+        assert np.array_equal(np.asarray(col)[:, 0],
+                              np.asarray(x[1, :, 2], np.float32))
+
+
+class TestFusedDecodeKernelDirect:
+    @pytest.mark.parametrize("t", [1, 3], ids=["step", "verify3"])
+    @pytest.mark.parametrize("kind", ["dense-f32", "paged-f32",
+                                      "dense-int8", "paged-int8"])
+    def test_matches_reference_chunked_read(self, kind, t):
+        """fused_decode_attention against the reference _attend_chunked on
+        the same caches: live rows agree to f32 reassociation, parked and
+        short rows included; a NaN scale in ANOTHER kv head never leaks."""
+        from paddle_tpu.ops.decode_attention import (
+            _attend_chunked, _q8_quantize)
+
+        rng = np.random.default_rng(11)
+        b, hkv, g, d, c, lmax = 3, 2, 2, 16, 8, 32
+        paged, quant = kind.startswith("paged"), kind.endswith("int8")
+        n = b * lmax // c
+        shape = (n, c, hkv, d) if paged else (b, lmax, hkv, d)
+
+        def cache():
+            x = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+            if not quant:
+                return x
+            qd, qs = _q8_quantize(x)
+            # poison kv head 1's scales on the first position of every
+            # block/row: head 0's outputs must not see it
+            return qd, qs.at[:, 0, 1].set(jnp.float16(np.nan))
+
+        k, v = cache(), cache()
+        table = (jnp.asarray(rng.permutation(n).reshape(b, lmax // c),
+                             jnp.int32) if paged else None)
+        lengths = jnp.asarray([5, lmax, 17], jnp.int32)   # row 1 is parked
+        qg = jnp.asarray(rng.standard_normal(
+            (b, hkv, g, t, d)).astype(np.float32))
+        q_pos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        ref = _attend_chunked(qg, k, v, lengths, q_pos, 0.25, "blhd", None,
+                              c, table)
+        out = pap.fused_decode_attention(qg, k, v, lengths, 0.25, c,
+                                         block_table=table)
+        live = [0, 2]
+        heads = [0] if quant else [0, 1]
+        np.testing.assert_allclose(
+            np.asarray(out)[live][:, heads], np.asarray(ref)[live][:, heads],
+            rtol=2e-5, atol=2e-5)
+        if quant:   # the poisoned head reports its poison, like the reference
+            assert np.isnan(np.asarray(out)[live][:, 1]).all()
+            assert np.isnan(np.asarray(ref)[live][:, 1]).all()

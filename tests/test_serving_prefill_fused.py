@@ -16,7 +16,8 @@ The load-bearing properties:
   byte-identically, logged once per process per (call-site, reason) —
   a prefill downgrade is never silenced by an earlier decode one.
 - **One registry**: every static program axis (attn_impl,
-  prefill_impl, kv_dtype, weight_dtype, tp_overlap) flows through the
+  prefill_impl, kv_dtype, weight_dtype, tp_overlap and the three
+  speculation axes) flows through the
   single frozen ``ProgramKey`` — validated at construction, hashable,
   and carried whole by the engine and the TP program cache.
 - **Zero retraces**: a warmed fused-prefill engine serves a larger
@@ -238,10 +239,10 @@ class TestPagedChunkContract:
 # ---------------------------------------------------------------------------
 
 class TestProgramKeyRegistry:
-    def test_registry_covers_all_five_axes_in_order(self):
+    def test_registry_covers_all_eight_axes_in_order(self):
         assert tuple(ax.name for ax in PROGRAM_AXES) == (
             "attn_impl", "prefill_impl", "kv_dtype", "weight_dtype",
-            "tp_overlap")
+            "tp_overlap", "draft_source", "spec_depth", "spec_tree")
 
     def test_enum_axis_validation_names_axis_and_allowed(self):
         with pytest.raises(ValueError, match="unknown attn_impl 'flash'"):
@@ -270,8 +271,9 @@ class TestProgramKeyRegistry:
             a.replace(tp_overlap=0)  # replace re-validates
 
     def test_engine_composes_one_key_from_its_knobs(self):
-        """The acceptance property: all five static knobs flow through
-        exactly one registry value — the engine's ``_pk``."""
+        """The acceptance property: every static knob flows through
+        exactly one registry value — the engine's ``_pk`` (the three
+        speculation axes stay at their off default on a greedy engine)."""
         eng = ServingEngine(_tiny_model(), batch_size=2, max_len=64,
                             prefill_chunk=16, decode_chunk=16,
                             attn_impl="pallas", prefill_impl="pallas",
@@ -283,7 +285,8 @@ class TestProgramKeyRegistry:
         assert eng._pk.axes() == (
             ("attn_impl", "pallas"), ("prefill_impl", "pallas"),
             ("kv_dtype", "int8"), ("weight_dtype", "int8"),
-            ("tp_overlap", 2))
+            ("tp_overlap", 2), ("draft_source", None),
+            ("spec_depth", None), ("spec_tree", None))
 
     def test_engine_rejects_bad_tp_overlap(self):
         with pytest.raises(ValueError, match="tp_overlap"):
@@ -389,3 +392,56 @@ class TestPrefillObservability:
                       if e["kind"] == "dispatch"]
         assert dispatches
         assert all(e["prefill_impl"] == "fused" for e in dispatches)
+
+
+# ---------------------------------------------------------------------------
+# kernel-level: the fused attention + append against the reference
+# scatter + read on the same caches — the cache it leaves behind is
+# BITWISE the reference's (int8 data and f16 scales included), across the
+# append geometries (chunk < / = / spanning two cache blocks)
+# ---------------------------------------------------------------------------
+
+class TestFusedPrefillKernelDirect:
+    @pytest.mark.parametrize("t,c", [(8, 16), (16, 16), (32, 16)],
+                             ids=["T<C", "T=C", "T=2C"])
+    @pytest.mark.parametrize("kind", ["dense-f32", "paged-f32",
+                                      "dense-int8", "paged-int8"])
+    def test_chunk_chain_matches_reference(self, kind, t, c):
+        from paddle_tpu.ops.decode_attention import init_kv_cache
+
+        rng = np.random.default_rng(5)
+        b, hkv, g, d, lmax = 2, 2, 2, 16, 128
+        paged = kind.startswith("paged")
+        dtype = "int8" if kind.endswith("int8") else jnp.float32
+        if paged:
+            n = b * lmax // c
+            fresh = lambda: init_kv_pool(n, c, hkv, d, dtype)
+            table = jnp.asarray(rng.permutation(n).reshape(b, lmax // c),
+                                jnp.int32)
+        else:
+            fresh = lambda: init_kv_cache(b, lmax, hkv, d, dtype)
+            table = None
+        slot = jnp.int32(1)
+        caches = {impl: fresh() for impl in ("reference", "pallas")}
+        for step in range(3):                      # offsets 0, T, 2T
+            q = jnp.asarray(rng.standard_normal(
+                (1, t, hkv * g, d)).astype(np.float32))
+            kn = jnp.asarray(rng.standard_normal(
+                (1, t, hkv, d)).astype(np.float32))
+            vn = jnp.asarray(rng.standard_normal(
+                (1, t, hkv, d)).astype(np.float32))
+            outs = {}
+            for impl in caches:
+                kc, vc = caches[impl]
+                outs[impl], kc, vc = slot_prefill_attention(
+                    q, kn, vn, kc, vc, slot, jnp.int32(step * t),
+                    chunk_size=c, block_table=table, prefill_impl=impl)
+                caches[impl] = (kc, vc)
+            np.testing.assert_allclose(
+                np.asarray(outs["pallas"]), np.asarray(outs["reference"]),
+                rtol=2e-5, atol=2e-5)
+            import jax
+            for a, r in zip(jax.tree.leaves(caches["pallas"]),
+                            jax.tree.leaves(caches["reference"])):
+                assert a.dtype == r.dtype and a.shape == r.shape
+                assert np.asarray(a).tobytes() == np.asarray(r).tobytes()
