@@ -393,7 +393,6 @@ def train_phase(size=FULL, chip=True):
     """Three fused train steps of the bench_llama configuration on one
     repeated batch.  Returns (device, failed_checks)."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     import paddle_tpu as paddle
@@ -438,11 +437,7 @@ def train_phase(size=FULL, chip=True):
     # TrainStep.__call__ passes them: the program the steps ran, so the
     # compile is a cache hit (nothing is donated or run)
     t0 = time.perf_counter()
-    text = step._jitted.lower(
-        step._params, step._buffers, step._states,
-        jnp.asarray(opt.get_lr(), jnp.float32),
-        jnp.asarray(step._step_count + 1, jnp.int32),
-        ids.data, ids.data).compile().as_text()
+    text = step.lower(ids, ids).compile().as_text()
     say(phase, lower_and_compile_s=round(time.perf_counter() - t0, 1),
         **events.snapshot())
     if chip:

@@ -11,11 +11,17 @@ Design notes (TPU-first, not a translation):
   * ``llama_shardings``/``shard_llama`` lay parameters out Megatron-style over
     a ('dp', 'mp') mesh via NamedSharding; GSPMD propagates everything else —
     no hand-written collectives in the model body.
+  * the forward's components run under ``jax.named_scope`` with the names of
+    ``observability.trace.SCOPES`` — the same names the serving programs
+    (models/llama_decode.py) carry — so a device trace of the compiled train
+    step splits by component; JAX wraps them for the backward
+    (``transpose(jvp(attn.core))``) and the recompute.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu.tensor.manipulation as M
@@ -151,9 +157,10 @@ class LlamaAttention(Layer):
         b, l = hidden_states.shape[0], hidden_states.shape[1]
         nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, \
             self.head_dim
-        qp = self.q_proj(hidden_states)
-        kp = self.k_proj(hidden_states)
-        vp = self.v_proj(hidden_states)
+        with jax.named_scope("attn.qkv"):
+            qp = self.q_proj(hidden_states)
+            kp = self.k_proj(hidden_states)
+            vp = self.v_proj(hidden_states)
 
         # NOTE: rope fused INTO the flash kernels exists
         # (ops/flash_attention.py::flash_attention_packed_rope, parity-
@@ -183,9 +190,10 @@ class LlamaAttention(Layer):
                 cfg.rope_theta, position_offset)
             return q4.reshape(qa.shape), k4.reshape(ka.shape)
 
-        qp, kp = apply("rope", rope_fn, qp, kp)
-        q = M.reshape(qp, [b, l, nh, hd])
-        k = M.reshape(kp, [b, l, nkv, hd])
+        with jax.named_scope("attn.rope"):
+            qp, kp = apply("rope", rope_fn, qp, kp)
+            q = M.reshape(qp, [b, l, nh, hd])
+            k = M.reshape(kp, [b, l, nkv, hd])
 
         new_cache = None
         if cache is not None:
@@ -198,6 +206,18 @@ class LlamaAttention(Layer):
         # GQA kv heads are consumed NATIVELY by every attention path: the
         # flash kernel blocks over kv heads (KV HBM traffic /G) and ring
         # attention rotates kv-head-sized shards (ICI bytes /G).
+        with jax.named_scope("attn.core"):
+            out = self._attend(q, k, v, attn_mask, l)
+        with jax.named_scope("attn.out"):
+            out = M.reshape(out, [b, l, nh * hd])
+            out = self.o_proj(out)
+        if cache is not None:
+            return out, new_cache
+        return out
+
+
+    def _attend(self, q, k, v, attn_mask, l):
+        cfg = self.config
         if cfg.sep_axis is not None:
             from paddle_tpu.distributed.auto_parallel.process_mesh import get_mesh
             from paddle_tpu.ops.ring_attention import ring_attention_sharded
@@ -220,10 +240,6 @@ class LlamaAttention(Layer):
             # saved under the "named" remat policy: backward reuses the
             # attention output instead of re-running the quadratic kernel
             out = apply("attn_ckpt", lambda x: checkpoint_name(x, "ckpt"), out)
-        out = M.reshape(out, [b, l, nh * hd])
-        out = self.o_proj(out)
-        if cache is not None:
-            return out, new_cache
         return out
 
 
@@ -258,7 +274,8 @@ class LlamaDecoderLayer(Layer):
     def forward(self, hidden_states, attn_mask=None, cache=None,
                 position_offset=0):
         residual = hidden_states
-        h = self.input_layernorm(hidden_states)
+        with jax.named_scope("norm"):
+            h = self.input_layernorm(hidden_states)
         if cache is not None:
             h, new_cache = self.self_attn(h, attn_mask, cache, position_offset)
         else:
@@ -266,8 +283,10 @@ class LlamaDecoderLayer(Layer):
             new_cache = None
         h = residual + h
         residual = h
-        h = self.post_attention_layernorm(h)
-        h = residual + self.mlp(h)
+        with jax.named_scope("norm"):
+            h = self.post_attention_layernorm(h)
+        with jax.named_scope("mlp"):
+            h = residual + self.mlp(h)
         if cache is not None:
             return h, new_cache
         return h
@@ -287,7 +306,8 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, attn_mask=None, caches=None,
                 position_offset=0):
-        h = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
         if self.config.sequence_parallel:
             if caches is not None:
                 raise NotImplementedError(
@@ -313,7 +333,8 @@ class LlamaModel(Layer):
                 new_caches.append(c)
             else:
                 h = layer_fn(h, attn_mask)
-        h = self.norm(h)
+        with jax.named_scope("norm"):
+            h = self.norm(h)
         if self.config.sequence_parallel:
             from paddle_tpu.distributed.sep_utils import GatherOp
 
@@ -345,19 +366,17 @@ class LlamaForCausalLM(Layer):
                 _chunked_lm_loss_fn(self.config.loss_chunk_size),
                 h[:, :-1, :], labels[:, 1:], w,
             )
-        if self.config.tie_word_embeddings:
-            w = self.llama.embed_tokens.weight
-            logits = F.linear(h, M.transpose(w, [1, 0]))
-        else:
-            logits = self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            logits = self._head(h)
         if labels is None:
             return logits
         # next-token LM loss; logits in fp32 for a stable softmax
-        logits = logits.astype("float32")
-        b, l, v = logits.shape
-        shift_logits = M.reshape(logits[:, :-1, :], [b * (l - 1), v])
-        shift_labels = M.reshape(labels[:, 1:], [b * (l - 1)])
-        return F.cross_entropy(shift_logits, shift_labels)
+        with jax.named_scope("loss"):
+            logits = logits.astype("float32")
+            b, l, v = logits.shape
+            shift_logits = M.reshape(logits[:, :-1, :], [b * (l - 1), v])
+            shift_labels = M.reshape(labels[:, 1:], [b * (l - 1)])
+            return F.cross_entropy(shift_logits, shift_labels)
 
     def generate(self, input_ids, max_new_tokens=32, eos_token_id=None):
         """Greedy decode with a per-layer KV cache (eager path)."""

@@ -228,22 +228,45 @@ def _layer_step(lp, cfg, h, k_cache, v_cache, lengths, cos_t, sin_t,
     the linear-chain path is bitwise untouched."""
     b, t, hidden = h.shape
     nh, nkv, hd, eps = cfg
-    x = _rmsnorm(h, lp["ln1"], eps)
-    q = _mm(x, lp, "wq").reshape(b, t, nh, hd)
-    k = _mm(x, lp, "wk").reshape(b, t, nkv, hd)
-    v = _mm(x, lp, "wv").reshape(b, t, nkv, hd)
-    offs = jnp.arange(t, dtype=jnp.int32) if pos_offsets is None \
-        else pos_offsets.astype(jnp.int32)
-    positions = lengths[:, None] + offs[None, :]
-    q, k = _rope_at(q, k, cos_t, sin_t, positions)
+    q, k, v = _qkv(lp, cfg, h)
+    with jax.named_scope("attn.rope"):
+        offs = jnp.arange(t, dtype=jnp.int32) if pos_offsets is None \
+            else pos_offsets.astype(jnp.int32)
+        positions = lengths[:, None] + offs[None, :]
+        q, k = _rope_at(q, k, cos_t, sin_t, positions)
+    # attn.kv_write and attn.core are named inside the op
     out, k_cache, v_cache, _ = decode_attention(
         q, k, v, k_cache, v_cache, lengths, chunk_size=chunk_size,
         attn_bias=attn_bias, block_table=block_tables, attn_impl=attn_impl)
-    h = h + _mm(out.reshape(b, t, nh * hd), lp, "wo", tp_overlap=tp_overlap)
-    x2 = _rmsnorm(h, lp["ln2"], eps)
-    h = h + _mm(jax.nn.silu(_mm(x2, lp, "gate")) * _mm(x2, lp, "up"),
-                lp, "down", tp_overlap=tp_overlap)
-    return h, k_cache, v_cache
+    return _attn_out_mlp(lp, cfg, h, out, tp_overlap), k_cache, v_cache
+
+
+# the parts of a decoder layer on either side of its attention, shared by
+# the decode step and the prefill chunk.  The named scopes are the device
+# side of observability.trace.SCOPES: they label the compiled operations
+# (trace time only) and change none.
+def _qkv(lp, cfg, h):
+    b, t, _ = h.shape
+    nh, nkv, hd, eps = cfg
+    with jax.named_scope("norm"):
+        x = _rmsnorm(h, lp["ln1"], eps)
+    with jax.named_scope("attn.qkv"):
+        return (_mm(x, lp, "wq").reshape(b, t, nh, hd),
+                _mm(x, lp, "wk").reshape(b, t, nkv, hd),
+                _mm(x, lp, "wv").reshape(b, t, nkv, hd))
+
+
+def _attn_out_mlp(lp, cfg, h, out, tp_overlap):
+    b, t, _ = h.shape
+    nh, nkv, hd, eps = cfg
+    with jax.named_scope("attn.out"):
+        h = h + _mm(out.reshape(b, t, nh * hd), lp, "wo",
+                    tp_overlap=tp_overlap)
+    with jax.named_scope("norm"):
+        x2 = _rmsnorm(h, lp["ln2"], eps)
+    with jax.named_scope("mlp"):
+        return h + _mm(jax.nn.silu(_mm(x2, lp, "gate")) * _mm(x2, lp, "up"),
+                       lp, "down", tp_overlap=tp_overlap)
 
 
 def _lm_logits(params, h):
@@ -269,7 +292,8 @@ def _forward(params, cfg, tokens, caches, lengths, last_only, last_idx=None,
     are content).  ``pos_offsets`` / ``attn_bias`` thread the tree-
     speculation ROPE override and tree attention mask into every layer
     (see ``_layer_step``); None keeps the linear path bitwise unchanged."""
-    h = params["embed"][tokens]  # [B, T, hidden]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]  # [B, T, hidden]
     new_caches = []
     cos_t, sin_t = params["_rope"]
     for lp, (kc, vc) in zip(params["layers"], caches):
@@ -281,13 +305,24 @@ def _forward(params, cfg, tokens, caches, lengths, last_only, last_idx=None,
                                 pos_offsets=pos_offsets,
                                 attn_bias=attn_bias)
         new_caches.append((kc, vc))
-    h = _rmsnorm(h, params["norm"], cfg[3])
-    if last_idx is not None:
-        h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    elif last_only:
-        h = h[:, -1]  # [B, hidden]
-    logits = _lm_logits(params, h)
-    return logits.astype(jnp.float32), new_caches, lengths + tokens.shape[1]
+    with jax.named_scope("norm"):
+        h = _rmsnorm(h, params["norm"], cfg[3])
+    with jax.named_scope("lm_head"):
+        if last_idx is not None:
+            h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
+        elif last_only:
+            h = h[:, -1]  # [B, hidden]
+        logits = _lm_logits(params, h).astype(jnp.float32)
+    return logits, new_caches, lengths + tokens.shape[1]
+
+
+def _greedy_pick(logits):
+    """(argmax token [B] int32, all-finite flag [B]) of [B, V] logits: the
+    serving programs' sampling and their poison-quarantine input."""
+    with jax.named_scope("sample"):
+        return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
+                .astype(jnp.int32),
+                jnp.all(jnp.isfinite(logits), axis=-1))
 
 
 def _forward_step(params, cfg, tokens, caches, lengths, chunk_size=None,
@@ -316,13 +351,15 @@ def _pick(logits, key, temperature, top_k, sample):
     serving loop with per-request temperatures reuses one compiled program
     (review r5); top_k > 0 (static) restricts sampling to the k best (the
     reference generate()'s sampling decode)."""
-    if not sample:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / temperature
-    if top_k > 0:
-        kth = jax.lax.top_k(logits, top_k)[0][:, -1:]
-        logits = jnp.where(logits < kth, -1e30, logits)
-    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if not sample:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits = logits / temperature
+        if top_k > 0:
+            kth = jax.lax.top_k(logits, top_k)[0][:, -1:]
+            logits = jnp.where(logits < kth, -1e30, logits)
+        return jax.random.categorical(key, logits, axis=-1) \
+            .astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -613,8 +650,7 @@ def _serving_prefill_slot_impl(params, cfg, tokens, prompt_len, caches, slot,
         last_only=True, last_idx=jnp.clip(prompt_len - 1, 0, t - 1),
         chunk_size=chunk_size, attn_impl=_pk_axis(program_key, "attn_impl"),
         tp_overlap=_pk_axis(program_key, "tp_overlap"))
-    first = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # [1]
-    ok = jnp.all(jnp.isfinite(logits), axis=-1)                 # [1]
+    first, ok = _greedy_pick(logits)                            # [1], [1]
     slot = slot.astype(jnp.int32)
     zero = jnp.int32(0)
 
@@ -627,8 +663,9 @@ def _serving_prefill_slot_impl(params, cfg, tokens, prompt_len, caches, slot,
         return jax.lax.dynamic_update_slice(c, m.astype(c.dtype),
                                             (slot, zero, zero, zero))
 
-    new_caches = [(insert(kc, mk), insert(vc, mv))
-                  for (kc, vc), (mk, mv) in zip(caches, mini)]
+    with jax.named_scope("attn.kv_write"):
+        new_caches = [(insert(kc, mk), insert(vc, mv))
+                      for (kc, vc), (mk, mv) in zip(caches, mini)]
     if with_hist:
         lmax = hist.shape[1]
         row = jax.lax.dynamic_update_slice(
@@ -659,23 +696,17 @@ def _layer_prefill_chunk(lp, cfg, h, k_cache, v_cache, slot, offset,
     per-batch caches at per-batch offsets.  ``prefill_impl`` (static)
     selects the fused attention + quantize-on-append Pallas kernel
     (ops/prefill_attention_pallas.py) vs the reference scatter + read."""
-    b, t, hidden = h.shape
-    nh, nkv, hd, eps = cfg
-    x = _rmsnorm(h, lp["ln1"], eps)
-    q = _mm(x, lp, "wq").reshape(b, t, nh, hd)
-    k = _mm(x, lp, "wk").reshape(b, t, nkv, hd)
-    v = _mm(x, lp, "wv").reshape(b, t, nkv, hd)
-    positions = offset[None, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    q, k = _rope_at(q, k, cos_t, sin_t, positions)
+    t = h.shape[1]
+    q, k, v = _qkv(lp, cfg, h)
+    with jax.named_scope("attn.rope"):
+        positions = offset[None, None] \
+            + jnp.arange(t, dtype=jnp.int32)[None, :]
+        q, k = _rope_at(q, k, cos_t, sin_t, positions)
     out, k_cache, v_cache = slot_prefill_attention(
         q, k, v, k_cache, v_cache, slot, offset, chunk_size=chunk_size,
         block_table=block_tables, attn_impl=attn_impl,
         prefill_impl=prefill_impl)
-    h = h + _mm(out.reshape(b, t, nh * hd), lp, "wo", tp_overlap=tp_overlap)
-    x2 = _rmsnorm(h, lp["ln2"], eps)
-    h = h + _mm(jax.nn.silu(_mm(x2, lp, "gate")) * _mm(x2, lp, "up"),
-                lp, "down", tp_overlap=tp_overlap)
-    return h, k_cache, v_cache
+    return _attn_out_mlp(lp, cfg, h, out, tp_overlap), k_cache, v_cache
 
 
 def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
@@ -721,7 +752,8 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
     nh, nkv, hd, eps = cfg
     offset = offset.astype(jnp.int32)
     slot = slot.astype(jnp.int32)
-    h = params["embed"][tokens]                             # [1, P, hidden]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]                         # [1, P, hidden]
     cos_t, sin_t = params["_rope"]
     new_caches = []
     for lp, (kc, vc) in zip(params["layers"], caches):
@@ -732,13 +764,13 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
             prefill_impl=_pk_axis(program_key, "prefill_impl"),
             tp_overlap=_pk_axis(program_key, "tp_overlap"))
         new_caches.append((kc, vc))
-    h = _rmsnorm(h, params["norm"], eps)
-    last_rel = jnp.clip(prompt_len - 1 - offset, 0, t - 1)  # [1]
-    h = jnp.take_along_axis(h, last_rel[:, None, None], axis=1)[:, 0]
-    logits = _lm_logits(params, h)
-    first = jnp.argmax(logits.astype(jnp.float32), axis=-1) \
-        .astype(jnp.int32)                                  # [1]
-    ok = jnp.all(jnp.isfinite(logits), axis=-1)             # [1]
+    with jax.named_scope("norm"):
+        h = _rmsnorm(h, params["norm"], eps)
+    with jax.named_scope("lm_head"):
+        last_rel = jnp.clip(prompt_len - 1 - offset, 0, t - 1)  # [1]
+        h = jnp.take_along_axis(h, last_rel[:, None, None], axis=1)[:, 0]
+        logits = _lm_logits(params, h)
+    first, ok = _greedy_pick(logits)                        # [1], [1]
     if with_hist:
         lmax = hist.shape[1]
         is_final = offset + t >= prompt_len[0]
@@ -786,14 +818,14 @@ def _serving_decode_steps_impl(params, cfg, cur, caches, dev_lengths,
             chunk_size=chunk_size, block_tables=block_tables,
             attn_impl=_pk_axis(program_key, "attn_impl"),
             tp_overlap=_pk_axis(program_key, "tp_overlap"))
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        ok = ok & jnp.all(jnp.isfinite(logits), axis=-1)
-        return (nxt, ok, caches, lengths), nxt
+        nxt, finite = _greedy_pick(logits)
+        return (nxt, ok & finite, caches, lengths), nxt
 
     ok0 = jnp.ones(cur.shape, bool)
-    (_, ok, caches, _), toks = jax.lax.scan(
-        body, (cur, ok0, caches, dev_lengths.astype(jnp.int32)), None,
-        length=n_steps)
+    with jax.named_scope("decode.steps"):
+        (_, ok, caches, _), toks = jax.lax.scan(
+            body, (cur, ok0, caches, dev_lengths.astype(jnp.int32)), None,
+            length=n_steps)
     return toks.T, ok, caches
 
 

@@ -11,8 +11,10 @@ Three stdlib-only parts (no jax, no third-party deps):
   (``PADDLE_TPU_METRICS_PORT`` or ``MetricsExporter(port=...)``), with
   deterministic shutdown.
 * :mod:`~paddle_tpu.observability.trace` — ``span()`` context-manager/
-  decorator recording into the registry AND the profiler host tracer, so
-  framework spans appear in ``paddle.profiler`` chrome-trace exports.
+  decorator that enters a ``jax.profiler.TraceAnnotation`` (imported
+  lazily), so the program's phases are events of the same xplane as the
+  device lines; plus the one vocabulary of names (``SCOPES``, ``LOOPS``,
+  ``SPANS``) the device work and the host phases are labelled with.
 
 Two request-scoped modules ride on top (lazy-exported below — they load
 on first attribute access, keeping ``import paddle_tpu.observability``

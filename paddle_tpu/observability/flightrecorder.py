@@ -11,7 +11,11 @@ observability layer:
   engine events (``submit``, ``admit``, ``prefill_chunk``, ``dispatch``,
   ``retry``, ``drain``, ``stall``, ``cancel``, ``shed``, ``poison``,
   ``retire``), each carrying a monotonic ``perf_counter_ns`` timestamp, the
-  scheduler step index, rid, slot and the engine's scheduling policy.
+  scheduler step index, rid, slot and the engine's scheduling policy.  The
+  engine's PHASES (``step``, ``submit``, ``admit``, ``spend_prefill``,
+  ``prefill_chunk``, ``dispatch``, ``drain``, ``drain.wait``, ``emit``) are
+  recorded at their start by the same call that opens their
+  ``serving.<phase>`` span and gain ``seconds`` when they end.
   Recording is host-side bookkeeping only (one lock + one deque append per
   event): zero device syncs, zero retraces, and token outputs are
   byte-identical recorder-on vs recorder-off (tested).  When the ring is
@@ -19,9 +23,9 @@ observability layer:
   bounded no matter how long the engine runs.
 * **Dumps** — the ring serializes as JSONL (one event object per line,
   log-shipping friendly) and as a chrome trace with ONE TRACK PER RID
-  (``tid`` = rid, built through the same ``_HostTracer`` event shape the
-  span/profiler plumbing emits — see trace.py ``chrome_event``), so a
-  request's lifecycle reads as a horizontal lane in ``chrome://tracing``.
+  (``tid`` = rid, in the event shape ``paddle.profiler`` exports — see
+  trace.py ``chrome_event``), so a request's lifecycle reads as a
+  horizontal lane in ``chrome://tracing``.
 * **Anomaly auto-dump** — the engine calls :meth:`auto_dump` when a request
   retires ``timed_out``/``poisoned`` or a bounded dispatch retry exhausts:
   the last ``dump_last`` events are snapshotted into ``.dumps`` (bounded)
@@ -52,6 +56,9 @@ __all__ = ["EVENT_KINDS", "DUMP_REASONS", "FlightRecorder", "RequestTrace",
 # the structured event vocabulary — every engine lifecycle edge has a kind
 EVENT_KINDS = ("submit", "admit", "prefill_chunk", "dispatch", "retry",
                "drain", "stall", "cancel", "shed", "poison", "retire",
+               # the engine's phases (serving/engine.py::_phase): one event
+               # per span, same boundary, ``seconds`` stamped at its end
+               "step", "spend_prefill", "drain.wait", "emit", "first_token",
                # tiered KV cache: eviction-time demotion into the host
                # store, admission-time restore out of it, the store's own
                # budget evictions, validation failures, injected damage
@@ -104,16 +111,19 @@ class FlightRecorder:
 
     # ------------------------------------------------------------ recording
     def record(self, kind, step=-1, rid=None, slot=None, **detail):
-        """Append one event.  ``detail`` keyword pairs ride along verbatim
-        (``status=`` for retire, ``chunk=`` for prefill_chunk, ``seconds=``
-        for stall, ...).  Host bookkeeping only — never touches a device
-        value."""
-        ev = (time.perf_counter_ns(), int(step), kind, rid, slot,
-              detail or None)
+        """Append one event and return it.  ``detail`` keyword pairs ride
+        along verbatim (``status=`` for retire, ``chunk=`` for
+        prefill_chunk, ``seconds=`` for stall, ...).  The caller that
+        times a PHASE keeps the returned event and adds ``seconds`` to its
+        detail (``ev[5]``) when the phase ends: a dump taken while the
+        phase is open shows the event without it — what was in flight.
+        Host bookkeeping only — never touches a device value."""
+        ev = (time.perf_counter_ns(), int(step), kind, rid, slot, detail)
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self.dropped += 1
             self._ring.append(ev)
+        return ev
 
     def _as_dict(self, ev):
         t_ns, step, kind, rid, slot, detail = ev
@@ -150,10 +160,9 @@ class FlightRecorder:
         "displayTimeUnit": "ms"}``, ONE TRACK PER RID (``tid`` = the rid's
         discovery order; batch-scoped events — dispatch/drain/stall with no
         rid — share track 0).  Events are instants unless they carry a
-        ``seconds`` detail (stalls), which becomes the slice duration.
-        Event dicts come from trace.py's ``chrome_event`` (the profiler
-        ``_HostTracer`` shape), so the dump loads next to span/profiler
-        exports with identical semantics."""
+        ``seconds`` detail (the engine's phases, stalls), which becomes
+        the slice duration.  Event dicts come from trace.py's
+        ``chrome_event`` (the ``paddle.profiler`` export's shape)."""
         from paddle_tpu.observability.trace import chrome_event
         tids = {}
         out = []

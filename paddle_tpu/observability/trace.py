@@ -1,43 +1,69 @@
-"""Span tracing: one primitive feeding BOTH telemetry sinks.
+"""Span tracing: one primitive, one timeline, one vocabulary of names.
 
-``span("serving.step")`` is a context manager *and* a decorator.  On exit it
+``span("serving.step", step=7)`` is a context manager *and* a decorator.
+Entering it enters a ``jax.profiler.TraceAnnotation`` of the same name, so
+the span is an event on the host plane of the SAME xplane that holds the
+device lines — one clock for the program's phases and the device's work.
+Keyword details (``step=``, ``rid=``, ``slot=``, ``chunk=``, ``n_live=``)
+become the annotation's arguments (event stats in the xplane): the spans
+of one request share its ``rid``, and nesting on a thread gives each span
+its parent.  With no profiler session an annotation is a flag test; a span
+writes to no histogram and to no list.  To see the spans: run under
+``jax.profiler.trace(dir)`` (or ``start_trace`` / ``stop_trace``) and open
+the trace, or read it with ``jax.profiler.ProfileData``.
 
-* observes the wall duration into the registry histogram
-  ``span_seconds{name=...}`` (always — metrics are the production sink), and
-* forwards the event to the profiler's host tracer
-  (``paddle_tpu.profiler.profiler._HostTracer``), so when a
-  ``paddle.profiler.Profiler`` session is recording, framework spans appear
-  in the exported chrome trace alongside user ``RecordEvent`` scopes —
-  nested correctly, since both record wall-clock ``perf_counter_ns``
-  intervals on the same thread.
+The device side of the same vocabulary is ``jax.named_scope``:
+:data:`SCOPES` names the model components and :data:`LOOPS` the two
+compiled loops, and every reader and test takes the names from here.
+Scopes exist at trace time only — they label the operations of a compiled
+program (the ``op_name`` of each HLO instruction) and change none.
 
-The profiler import is lazy (inside the exit path) to keep this module
-stdlib-only at import time; the tracer no-ops unless a profiler session
-enabled it, so spans cost two clock reads + one histogram observe.
+``jax`` is imported lazily, on the first span entered: importing this
+module stays stdlib-only.
 """
 from __future__ import annotations
 
 import functools
-import threading
-import time
 
-from paddle_tpu.observability.metrics import get_registry
+__all__ = ["span", "chrome_event", "SCOPES", "LOOPS", "SPANS"]
 
-__all__ = ["span", "span_histogram", "chrome_event"]
+# model components, the same names in the serving programs
+# (models/llama_decode.py, ops/decode_attention.py) and the training model
+# (models/llama.py, ops/chunked_ce.py, static/functionalize.py).  Backward
+# and recompute need no names of their own: JAX wraps the forward's scope
+# as transpose(jvp(<scope>)) and puts rematted_computation in the path.
+SCOPES = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
+          "attn.core", "attn.out", "mlp", "lm_head", "sample", "loss",
+          "optimizer")
+# the compiled loops, so that a %while in a device trace can be told: the
+# n_steps scan of the decode program and the cache-chunk loop of the
+# chunked attention read
+LOOPS = ("decode.steps", "attn.core.chunks")
+# host spans: the engine's phases (serving/engine.py::_phase) and the
+# train step's dispatch (static/functionalize.py)
+SPANS = ("serving.submit", "serving.step", "serving.admit",
+         "serving.spend_prefill", "serving.prefill_chunk",
+         "serving.dispatch", "serving.drain", "serving.drain.wait",
+         "serving.emit", "train.step")
 
 SPAN_EVENT_TYPE = "Span"
+
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 def chrome_event(name, start_ns, end_ns, *, tid, event_type=SPAN_EVENT_TYPE,
                  args=None):
-    """One chrome-trace event dict in the profiler's exact shape.
-
-    Built THROUGH the profiler's ``_HostTracer`` (the same plumbing
-    ``span`` forwards into), so consumers that assemble their own
-    ``traceEvents`` lists — the flight recorder's one-track-per-rid dump —
-    stay format-identical to ``Profiler.export`` output by construction,
-    with ``tid`` overridden (the recorder tracks by rid, not by thread)
-    and an optional ``args`` payload attached."""
+    """One chrome-trace event dict in the shape ``paddle.profiler`` exports
+    (``RecordEvent`` scopes): what the flight recorder's one-track-per-rid
+    dump is assembled from."""
     from paddle_tpu.profiler.profiler import _HostTracer
     tracer = _HostTracer()
     tracer.enabled = True
@@ -49,75 +75,35 @@ def chrome_event(name, start_ns, end_ns, *, tid, event_type=SPAN_EVENT_TYPE,
     return ev
 
 
-def span_histogram(registry=None):
-    """The ``span_seconds`` histogram family in ``registry``.
-
-    Labeled ``{name, mesh}``: ``mesh`` is "" for ordinary host spans and
-    the device count for spans wrapping mesh-sharded dispatches
-    (serving/sharding.py), so a single-chip engine and its tensor-parallel
-    twin stay separable in one scrape."""
-    reg = registry if registry is not None else get_registry()
-    return reg.histogram(
-        "span_seconds", "wall seconds spent inside observability spans",
-        labelnames=("name", "mesh"))
-
-
-def _host_tracer():
-    # lazy: profiler is a sibling subsystem, not an import-time dependency
-    from paddle_tpu.profiler.profiler import get_host_tracer
-    return get_host_tracer()
-
-
 class span:
-    """``with span("name"): ...`` or ``@span("name")``.
+    """``with span("name", **detail): ...`` or ``@span("name")``.
 
-    One instance is reusable AND re-entrant: start stamps live on a
-    thread-local stack, so a cached ``span`` object (the instrumentation
-    sites hold them to skip the registry lookup per iteration) nests with
-    itself and across threads correctly.
-    """
+    A span object is one interval: make one per ``with`` (construction is
+    two attribute stores).  The decorator form makes one per call."""
 
-    def __init__(self, name, registry=None, event_type=SPAN_EVENT_TYPE,
-                 mesh=""):
+    __slots__ = ("name", "detail", "_ann")
+
+    def __init__(self, name, **detail):
         self.name = name
-        self.event_type = event_type
-        self._hist = span_histogram(registry).labels(name=name, mesh=mesh)
-        self._local = threading.local()
+        self.detail = detail
+        self._ann = None
 
     def __enter__(self):
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
-            stack = self._local.stack = []
-        stack.append(time.perf_counter_ns())
+        self._ann = _trace_annotation()(self.name, **self.detail)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        end_ns = time.perf_counter_ns()
-        stack = getattr(self._local, "stack", None)
-        if not stack:
-            return False
-        start_ns = stack.pop()
-        self._hist.observe((end_ns - start_ns) / 1e9)
-        tracer = _host_tracer()
-        if tracer.enabled:
-            tracer.add(self.name, start_ns, end_ns,
-                       event_type=self.event_type)
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(*exc)
         return False
 
     def __call__(self, fn):
-        name, registry_hist, event_type = self.name, self._hist, \
-            self.event_type
+        name, detail = self.name, self.detail
 
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            start_ns = time.perf_counter_ns()
-            try:
+            with span(name, **detail):
                 return fn(*args, **kwargs)
-            finally:
-                end_ns = time.perf_counter_ns()
-                registry_hist.observe((end_ns - start_ns) / 1e9)
-                tracer = _host_tracer()
-                if tracer.enabled:
-                    tracer.add(name, start_ns, end_ns,
-                               event_type=event_type)
         return wrapped

@@ -33,6 +33,12 @@ def chunked_token_ce_fn(chunk_size: int, vh_weight: bool = False,
     is masked exactly like user-provided ignore labels)."""
 
     def f(h, lab, w):
+        # the loss's name in a device trace (observability.trace.SCOPES);
+        # the vocabulary matmul inside it is ``lm_head``
+        with jax.named_scope("loss"):
+            return chunked(h, lab, w)
+
+    def chunked(h, lab, w):
         B, L, H = h.shape
         n = B * L
         if n == 0:  # seq_len == 1 next-token case: no targets exist
@@ -49,11 +55,14 @@ def chunked_token_ce_fn(chunk_size: int, vh_weight: bool = False,
         lc = lab2.reshape(-1, c)
 
         def chunk_loss(hx, lx):
-            if vh_weight:
-                logits = jnp.einsum("ch,vh->cv", hx, w.astype(hx.dtype),
-                                    preferred_element_type=jnp.float32)
-            else:
-                logits = jnp.dot(hx, w, preferred_element_type=jnp.float32)
+            with jax.named_scope("lm_head"):
+                if vh_weight:
+                    logits = jnp.einsum(
+                        "ch,vh->cv", hx, w.astype(hx.dtype),
+                        preferred_element_type=jnp.float32)
+                else:
+                    logits = jnp.dot(hx, w,
+                                     preferred_element_type=jnp.float32)
             lse = jax.nn.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(
                 logits, jnp.maximum(lx, 0)[:, None], axis=-1)[:, 0]

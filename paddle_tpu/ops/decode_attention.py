@@ -438,8 +438,11 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
     m0 = jnp.full((b, hkv, g, t), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, t), jnp.float32)
     acc0 = jnp.zeros((b, hkv, g, t, d), jnp.float32)
-    _, _, l, acc = jax.lax.while_loop(
-        lambda carry: carry[0] < trip, body, (z, m0, l0, acc0))
+    # the loop's own name (observability.trace.LOOPS): a %while in a
+    # device trace that carries it is this cache-chunk loop
+    with jax.named_scope("attn.core.chunks"):
+        _, _, l, acc = jax.lax.while_loop(
+            lambda carry: carry[0] < trip, body, (z, m0, l0, acc0))
     # chunk 0 runs unconditionally and position 0 is causally visible to
     # every query (q_pos >= 0), so l > 0 for any FINITE attn_bias — but a
     # bias of -inf over every visible position of a row zeroes its whole
@@ -552,16 +555,20 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, lengths, scale=None,
     scale = float(scale if scale is not None else 1.0 / (d ** 0.5))
     lengths = lengths.astype(jnp.int32)
 
-    k_cache = _append(k_cache, k_new, lengths, layout, block_table)
-    v_cache = _append(v_cache, v_new, lengths, layout, block_table)
+    with jax.named_scope("attn.kv_write"):
+        k_cache = _append(k_cache, k_new, lengths, layout, block_table)
+        v_cache = _append(v_cache, v_new, lengths, layout, block_table)
 
-    qg = q.reshape(b, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
-        .astype(jnp.float32)                                # [B,Hkv,G,T,D]
-    q_pos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B,T]
-    out = _attend_dispatch(qg, k_cache, v_cache, lengths, q_pos, scale,
-                           layout, attn_bias, chunk_size, lmax, block_table,
-                           attn_impl, "decode_attention")
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d).astype(q.dtype)
+    with jax.named_scope("attn.core"):
+        qg = q.reshape(b, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
+            .astype(jnp.float32)                            # [B,Hkv,G,T,D]
+        q_pos = lengths[:, None] \
+            + jnp.arange(t, dtype=jnp.int32)[None, :]       # [B,T]
+        out = _attend_dispatch(qg, k_cache, v_cache, lengths, q_pos, scale,
+                               layout, attn_bias, chunk_size, lmax,
+                               block_table, attn_impl, "decode_attention")
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d) \
+            .astype(q.dtype)
     return out, k_cache, v_cache, lengths + t
 
 
@@ -594,9 +601,12 @@ def _prefill_dispatch(q, k_new, v_new, k_cache, v_cache, slot, offset,
     reason = fused_prefill_supported(chunk_size, lmax,
                                      t, block_table is not None)
     if reason is None:
-        return fused_prefill_attention(
-            q, k_new, v_new, k_cache, v_cache, slot, offset, scale,
-            int(chunk_size), block_table=block_table)
+        # ONE kernel appends the chunk's rows and attends over them: its
+        # time is attn.core's (no separate attn.kv_write exists here)
+        with jax.named_scope("attn.core"):
+            return fused_prefill_attention(
+                q, k_new, v_new, k_cache, v_cache, slot, offset, scale,
+                int(chunk_size), block_table=block_table)
     warn_fallback(where, f"prefill: {reason}", knob="prefill_impl")
     return None
 
@@ -681,17 +691,20 @@ def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
             "slot_prefill_attention")
         if fused is not None:
             return fused
-        k_cache = _append(k_cache, k_new, offset[None], "blhd", trow)
-        v_cache = _append(v_cache, v_new, offset[None], "blhd", trow)
-        qg = q.reshape(1, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
-            .astype(jnp.float32)
-        q_pos = offset[None, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-        out = _attend_dispatch(qg, k_cache, v_cache, offset[None], q_pos,
-                               scale, "blhd", None, int(chunk_size),
-                               w * blk, trow, attn_impl,
-                               "slot_prefill_attention")
-        out = out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d) \
-            .astype(q.dtype)
+        with jax.named_scope("attn.kv_write"):
+            k_cache = _append(k_cache, k_new, offset[None], "blhd", trow)
+            v_cache = _append(v_cache, v_new, offset[None], "blhd", trow)
+        with jax.named_scope("attn.core"):
+            qg = q.reshape(1, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
+                .astype(jnp.float32)
+            q_pos = offset[None, None] \
+                + jnp.arange(t, dtype=jnp.int32)[None, :]
+            out = _attend_dispatch(qg, k_cache, v_cache, offset[None], q_pos,
+                                   scale, "blhd", None, int(chunk_size),
+                                   w * blk, trow, attn_impl,
+                                   "slot_prefill_attention")
+            out = out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d) \
+                .astype(q.dtype)
         return out, k_cache, v_cache
 
     fused = _prefill_dispatch(
@@ -714,8 +727,9 @@ def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
         return cache.at[batch_idx, rows].set(
             new[0].astype(cache.dtype), mode="drop")
 
-    k_cache = scatter(k_cache, k_new)
-    v_cache = scatter(v_cache, v_new)
+    with jax.named_scope("attn.kv_write"):
+        k_cache = scatter(k_cache, k_new)
+        v_cache = scatter(v_cache, v_new)
 
     # the slot's [1, Lmax] view (slot < B: no dynamic_slice clamping)
     def slot_view(cache):
@@ -730,15 +744,16 @@ def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
             cache, (slot, jnp.int32(0), jnp.int32(0), jnp.int32(0)),
             (1, lmax, hkv, d))
 
-    ks = slot_view(k_cache)
-    vs = slot_view(v_cache)
-
-    qg = q.reshape(1, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
-        .astype(jnp.float32)                                # [1,Hkv,G,T,D]
-    q_pos = offset[None, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    lengths = offset[None]                                  # [1]
-    out = _attend_dispatch(qg, ks, vs, lengths, q_pos, scale, "blhd", None,
-                           chunk_size, lmax, None, attn_impl,
-                           "slot_prefill_attention")
-    out = out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d).astype(q.dtype)
+    with jax.named_scope("attn.core"):
+        ks = slot_view(k_cache)
+        vs = slot_view(v_cache)
+        qg = q.reshape(1, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
+            .astype(jnp.float32)                            # [1,Hkv,G,T,D]
+        q_pos = offset[None, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        lengths = offset[None]                              # [1]
+        out = _attend_dispatch(qg, ks, vs, lengths, q_pos, scale, "blhd",
+                               None, chunk_size, lmax, None, attn_impl,
+                               "slot_prefill_attention")
+        out = out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d) \
+            .astype(q.dtype)
     return out, k_cache, v_cache

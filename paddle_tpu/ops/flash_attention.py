@@ -591,6 +591,7 @@ def _flash_fwd_pallas(q, k, v, num_heads, num_kv_heads, causal=False,
                 pltpu.VMEM((8, rows), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_attention_fwd",
         )(*args)
         if segmented:
             out = jnp.where(
@@ -668,6 +669,7 @@ def _flash_fwd_pallas(q, k, v, num_heads, num_kv_heads, causal=False,
             jax.ShapeDtypeStruct((b, num_kv_heads, 8, lq * g), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     if bhld:
         out = jnp.swapaxes(out, 1, 2).reshape(b, lq, num_heads * d)
@@ -723,6 +725,7 @@ def _delta_pallas(do, out, num_kv_heads, g, d, interpret=False):
         out_shape=jax.ShapeDtypeStruct(
             (b, num_kv_heads, 8, lq * g), jnp.float32),
         interpret=interpret,
+        name="flash_attention_bwd_delta",
     )(do, out)
 
 
@@ -1139,6 +1142,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, num_heads, num_kv_heads,
         out_specs=dkv_out_specs,
         out_shape=dkv_out_shape,
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*dkv_args)
     if bhld:
         dk32 = jnp.swapaxes(dk32, 1, 2).reshape(b, lk, num_kv_heads * d)
@@ -1183,6 +1187,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, num_heads, num_kv_heads,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
             interpret=interpret,
+            name="flash_attention_bwd_dq",
         )(*dq_args)
         return dq, dk, dv
     if bhld:
@@ -1250,6 +1255,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, num_heads, num_kv_heads,
         out_specs=dq_out_spec,
         out_shape=dq_out_shape,
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_args)
     if bhld:
         dq = jnp.swapaxes(dq, 1, 2).reshape(b, lq, num_heads * d)

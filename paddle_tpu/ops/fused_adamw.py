@@ -171,6 +171,7 @@ def _fused_adamw_q8(p, g, m_codes, scales, v_bf16, scalars,
                                   has_master=has_master, chunks=chunks),
                 grid=grid, in_specs=in_specs, out_specs=out_specs,
                 out_shape=out_shape, interpret=interpret,
+                name="adamw_q8_update",
             )(*args)
             outs = list(outs)
             s_i = 2 if has_master else 1
@@ -213,6 +214,7 @@ def _fused_adamw_q8(p, g, m_codes, scales, v_bf16, scalars,
                           has_master=has_master),
         grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret,
+        name="adamw_q8_update",
     )(*args)
     if has_master:
         p32_new, p_cast, m_new, s_new, v_new = outs

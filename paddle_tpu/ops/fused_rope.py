@@ -99,6 +99,7 @@ def _rope_pallas(q, k, cos, sin, nh, nkv, neg=False, interpret=False):
             jax.ShapeDtypeStruct(k.shape, k.dtype),
         ],
         interpret=interpret,
+        name="fused_rope",
     )(q, k, cos, sin)
 
 

@@ -385,5 +385,6 @@ def fused_decode_attention(qg, k_cache, v_cache, lengths, scale, chunk,
             ]),
         out_shape=jax.ShapeDtypeStruct((b, hkv, gt, d), jnp.float32),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*scalars, *args)
     return out.reshape(b, hkv, g, t, d)

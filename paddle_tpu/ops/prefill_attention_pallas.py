@@ -442,6 +442,7 @@ def fused_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
         # — without the side-effect flag it would be dead-code eliminated
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
+        name="prefill_attention_append",
     )(off_arr, ptr, *args)
     out = outs[0].reshape(hkv, g, t, d).transpose(2, 0, 1, 3) \
         .reshape(1, t, h, d).astype(q.dtype)

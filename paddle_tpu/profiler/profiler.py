@@ -77,9 +77,10 @@ _tracer = _HostTracer()
 
 
 def get_host_tracer():
-    """The process-wide host event sink — the forwarding target of
-    paddle_tpu.observability.trace.span, so framework spans land in the
-    same chrome-trace export as user RecordEvent scopes."""
+    """The process-wide host event sink of user ``RecordEvent`` scopes
+    (chrome-trace export, ``perf_counter`` clock).  Framework spans
+    (``paddle_tpu.observability.trace.span``) do NOT come here: they are
+    events of the ``jax.profiler`` timeline, beside the device lines."""
     return _tracer
 
 

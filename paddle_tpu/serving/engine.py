@@ -89,7 +89,6 @@ tracks the longest LIVE context instead of ``max_len``.
 from __future__ import annotations
 
 import bisect
-import contextlib
 import dataclasses
 import logging
 import threading
@@ -111,6 +110,7 @@ from paddle_tpu.observability.flightrecorder import (
     FlightRecorder, RequestTrace,
 )
 from paddle_tpu.observability.slo import SLOTracker
+from paddle_tpu.observability.trace import span
 from paddle_tpu.observability.watchdog import DeadlockWatchdog
 from paddle_tpu.ops.decode_attention import _canon_kv_dtype
 from paddle_tpu.serving.faults import InjectedDispatchError
@@ -128,7 +128,8 @@ warnings.filterwarnings(
 __all__ = ["AcceptWindow", "EngineOverloaded", "KVPoolExhausted",
            "Request", "ServingEngine", "SpecConfig"]
 
-_NULL_CTX = contextlib.nullcontext()
+# the request's own transitions carry these details onto its timeline
+_MARK_KEYS = ("slot", "chunk", "final")
 
 _LOG = logging.getLogger(__name__)
 
@@ -395,6 +396,31 @@ class Request:
         if self.t_done is None or self.t_first is None:
             return None
         return (self.t_done - self.t_first) / max(1, len(self.output_ids) - 1)
+
+
+class _Phase:
+    """What ``ServingEngine._phase`` returns: enters the span, and on exit
+    stamps the phase's ``seconds`` on its flight-recorder event and feeds
+    them to ``observe``."""
+
+    __slots__ = ("_span", "_ev", "_observe", "_t0")
+
+    def __init__(self, span_, ev, observe):
+        self._span, self._ev, self._observe = span_, ev, observe
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        seconds = (time.perf_counter_ns() - self._t0) / 1e9
+        if self._ev is not None:
+            self._ev[5]["seconds"] = seconds
+        if self._observe is not None:
+            self._observe.observe(seconds)
+        return False
 
 
 class ServingEngine:
@@ -1105,10 +1131,7 @@ class ServingEngine:
             request.status = "shed"
             if self._m is not None:
                 self._m.terminal("shed")
-            if self._fr is not None:
-                self._fr.record("shed", step=self._step_idx,
-                                rid=request.rid,
-                                queued=len(self._queue))
+            self._event("shed", request, queued=len(self._queue))
             raise EngineOverloaded(
                 f"admission queue full ({len(self._queue)} pending >= "
                 f"max_pending={self._max_pending}); request shed")
@@ -1131,25 +1154,29 @@ class ServingEngine:
         if request.deadline_ms is not None:
             request._t_deadline = request.t_submit \
                 + request.deadline_ms / 1e3
-        # lifecycle trace: born "queued"; bounded rid-keyed index so
-        # /debug/requests can show recent timelines without unbounded
-        # growth (the Request itself keeps its own trace alive regardless).
-        # recorder=False switches off ALL request-scoped recording —
-        # timelines included
-        if self._fr is not None:
-            tr = RequestTrace(request.rid)
-            request._trace = tr
-            with self._trace_lock:
-                self._traces[request.rid] = tr
-                while len(self._traces) > self._trace_cap:
-                    self._traces.popitem(last=False)
-            tr.mark("queued")
-            self._fr.record("submit", step=self._step_idx, rid=request.rid,
-                            prompt_len=p, slo_class=request.slo_class)
-        self._queue.append(request)
-        if self._m is not None:
-            self._m.queue_depth.set(len(self._queue))
+        self._attach_trace(request)
+        # lifecycle trace: born "queued"
+        with self._phase("submit", request, mark="queued", prompt_len=p,
+                         slo_class=request.slo_class):
+            self._queue.append(request)
+            if self._m is not None:
+                self._m.queue_depth.set(len(self._queue))
         return request
+
+    def _attach_trace(self, request):
+        """Give ``request`` its lifecycle trace, kept in a bounded
+        rid-keyed index so /debug/requests can show recent timelines
+        without unbounded growth (the Request itself keeps its own trace
+        alive regardless).  recorder=False switches off ALL
+        request-scoped recording — timelines included."""
+        if self._fr is None:
+            return
+        tr = RequestTrace(request.rid)
+        request._trace = tr
+        with self._trace_lock:
+            self._traces[request.rid] = tr
+            while len(self._traces) > self._trace_cap:
+                self._traces.popitem(last=False)
 
     def _decodable(self, i):
         """Slot ``i`` holds a live request that finished prefilling — the
@@ -1192,12 +1219,8 @@ class ServingEngine:
         r.preempts += 1
         r._adm_ids = None
         self._n_preempted += 1
-        if r._trace is not None:
-            r._trace.mark("preempted", slot=slot)
-        if self._fr is not None:
-            self._fr.record("preempt", step=self._step_idx, rid=r.rid,
-                            slot=slot, cached_tokens=int(cached.size),
-                            n_out=len(r.output_ids))
+        self._event("preempt", r, mark="preempted", slot=slot,
+                    cached_tokens=int(cached.size), n_out=len(r.output_ids))
         self._queue.appendleft(r)
         if self._m is not None:
             self._m.preempted.inc()
@@ -1258,17 +1281,10 @@ class ServingEngine:
         ``poisoned`` (retry exhaustion dumps from ``_retry``).  Pure host
         bookkeeping; the scheduling state machine is untouched."""
         tr = r._trace
-        if tr is not None:
-            if slot is not None:
-                tr.mark(status, slot=slot)
-            else:
-                tr.mark(status)
-        if self._fr is not None:
-            self._fr.record("retire", step=self._step_idx, rid=r.rid,
-                            slot=slot, status=status,
-                            n_out=len(r.output_ids))
-            if status in ("timed_out", "poisoned"):
-                self._fr.auto_dump(status)
+        self._event("retire", r, mark=status, slot=slot, status=status,
+                    n_out=len(r.output_ids))
+        if self._fr is not None and status in ("timed_out", "poisoned"):
+            self._fr.auto_dump(status)
         if self._m is not None and tr is not None:
             self._m.observe_phases(tr.durations())
         if self._slo is not None:
@@ -1322,17 +1338,14 @@ class ServingEngine:
         for r in self._queue:
             if r.rid == rid:
                 self._queue.remove(r)
-                if self._fr is not None:
-                    self._fr.record("cancel", step=self._step_idx, rid=rid)
+                self._event("cancel", r)
                 self._terminal_queued(r, "cancelled")
                 if self._m is not None:
                     self._m.queue_depth.set(len(self._queue))
                 return True
         for slot, r in enumerate(self._kv.reqs):
             if r is not None and r.rid == rid:
-                if self._fr is not None:
-                    self._fr.record("cancel", step=self._step_idx, rid=rid,
-                                    slot=slot)
+                self._event("cancel", r, slot=slot)
                 self._retire(slot, "cancelled")
                 return True
         return False
@@ -1403,9 +1416,7 @@ class ServingEngine:
                 continue   # no rows written yet — defer to a later step
             self._inject_nan(slot)
             f.mark_poisoned(r.rid)
-            if self._fr is not None:
-                self._fr.record("poison", step=self._step_idx, rid=r.rid,
-                                slot=slot)
+            self._event("poison", r, slot=slot)
 
     def _apply_host_corrupt(self):
         """Inject every due ``FaultPlan(host_tier_corrupt=...)`` payload:
@@ -1419,9 +1430,7 @@ class ServingEngine:
             return
         for tokens, mode in f.host_corrupts_due(self._step_idx):
             n = self._kv.corrupt_host(tokens, mode=mode)
-            if self._fr is not None:
-                self._fr.record("host_corrupt", step=self._step_idx,
-                                mode=mode, entries=n)
+            self._event("host_corrupt", mode=mode, entries=n)
 
     def _fault_point(self, kind, attempt):
         if self._faults is not None:
@@ -1446,18 +1455,14 @@ class ServingEngine:
                     # exhaustion: the engine is about to surface a device
                     # error to the caller — snapshot the path that led here
                     if self._fr is not None:
-                        self._fr.record(
-                            "retry", step=self._step_idx, what=what,
-                            attempt=attempt + 1, error=type(e).__name__,
-                            exhausted=True)
+                        self._event("retry", what=what, attempt=attempt + 1,
+                                    error=type(e).__name__, exhausted=True)
                         self._fr.auto_dump("retry_exhausted")
                     raise
                 if self._m is not None:
                     self._m.dispatch_retries.inc()
-                if self._fr is not None:
-                    self._fr.record("retry", step=self._step_idx,
-                                    what=what, attempt=attempt + 1,
-                                    error=type(e).__name__)
+                self._event("retry", what=what, attempt=attempt + 1,
+                            error=type(e).__name__)
                 _LOG.warning(
                     "serving %s failed (%s: %s) — retrying "
                     "(attempt %d/%d) after %.3fs backoff",
@@ -1475,6 +1480,42 @@ class ServingEngine:
             return _host_fetch(*arrays)
         return self._retry(go, kind)
 
+    # ------------------------------------------------------ instrumentation
+    # ONE call per boundary: the flight-recorder event, the request's
+    # timeline mark and (for a phase) the span on the profiler's timeline
+    # all come from the same call, so they cannot drift apart.
+    def _event(self, kind, req=None, mark=None, **detail):
+        """One lifecycle edge: the flight-recorder event ``kind`` at this
+        step and, for one of ``req``'s own transitions, its timeline mark
+        ``mark`` (carrying ``slot`` / ``chunk`` / ``final``).  Nothing with
+        the recorder off.  Host bookkeeping only."""
+        fr = self._fr
+        if fr is None:
+            return None
+        if req is None:
+            rid = detail.pop("rid", None)
+        else:
+            rid = req.rid
+            if mark is not None and req._trace is not None:
+                req._trace.mark(mark, **{
+                    k: detail[k] for k in _MARK_KEYS
+                    if detail.get(k) is not None})
+        return fr.record(kind, step=self._step_idx, rid=rid,
+                         slot=detail.pop("slot", None), **detail)
+
+    def _phase(self, name, req=None, mark=None, observe=None, **detail):
+        """Context manager around one boundary of the step loop: the span
+        ``serving.<name>`` (an event of the profiler's timeline when a
+        session is on, a flag test when none is), the flight-recorder
+        event ``name`` with the same details — stamped with the phase's
+        ``seconds`` when it ends — and ``req``'s timeline mark.
+        ``observe`` (a histogram child) is fed the seconds too."""
+        ev = self._event(name, req, mark, **detail)
+        if req is not None:
+            detail["rid"] = req.rid
+        return _Phase(span("serving." + name, step=self._step_idx, **detail),
+                      ev, observe)
+
     # --------------------------------------------------- program dispatch
     # the four compiled entry points behind ONE seam: mesh=None dispatches
     # the module-level single-device jits (bitwise the pre-mesh engine);
@@ -1487,8 +1528,7 @@ class ServingEngine:
         ``restore`` / ``host_evict`` / ``host_error``) into the flight
         recorder and keep the block-pool and host-tier gauges current.
         Host bookkeeping only — the hook never touches a device value."""
-        if self._fr is not None:
-            self._fr.record(kind, step=self._step_idx, **info)
+        self._event(kind, **info)
         if self._m is not None:
             draft_used = self._kv.draft_blocks_used()
             self._m.set_kv_blocks(
@@ -1710,18 +1750,14 @@ class ServingEngine:
             self._kv.assign(slot, r)
             self._reset_spec_slot(slot)
             p = r.prompt_ids.size
-            if r._trace is not None:
-                r._trace.mark("prefilling", slot=slot)
-            if self._fr is not None:
-                self._fr.record("admit", step=self._step_idx, rid=r.rid,
-                                slot=slot, bucket=r._bucket)
-            if m is not None:
-                m.admitted.inc()
-                m.prefill(r._bucket)
-                m.queue_wait.observe(time.perf_counter() - r.t_submit)
-            tokens = np.zeros((1, r._bucket), np.int32)
-            tokens[0, :p] = r.prompt_ids
-            with m.span_prefill if m is not None else _NULL_CTX:
+            with self._phase("admit", r, mark="prefilling", slot=slot,
+                             bucket=r._bucket):
+                if m is not None:
+                    m.admitted.inc()
+                    m.prefill(r._bucket)
+                    m.queue_wait.observe(time.perf_counter() - r.t_submit)
+                tokens = np.zeros((1, r._bucket), np.int32)
+                tokens[0, :p] = r.prompt_ids
                 first, okf, self._kv.caches, hist, hist_len = \
                     self._call_prefill_slot(
                         jnp.asarray(tokens),
@@ -1827,78 +1863,69 @@ class ServingEngine:
                         dshared = dshared[:doff0 // C]
                     dbudget = -(-need // C) - len(dshared)
                 if not self._kv.can_reserve(budget + dbudget):
-                    if self._fr is not None:
-                        self._fr.record("admit_defer", step=self._step_idx,
-                                        rid=r.rid,
-                                        need_blocks=budget + dbudget)
+                    self._event("admit_defer", r,
+                                need_blocks=budget + dbudget)
                     break
             self._queue.remove(r)
             slot = free.pop(0)
-            self._kv.assign(slot, r)
-            self._reset_spec_slot(slot)
-            p = int(tok.size)
-            if self._paged:
-                self._kv.adopt_prefix(slot, shared)
-                if self._dspec:
-                    self._kv.adopt_draft_prefix(slot, dshared)
-                self._kv.reserve(slot, budget + dbudget)
-                self._need_rows[slot] = need
-                r._adm_ids = tok
-                self._n_prompt_tokens += p
-                self._n_reuse_tokens += off0
-                self._n_host_reuse_tokens += host_tok
-            if r._trace is not None:
-                r._trace.mark("prefilling", slot=slot)
-            if self._fr is not None:
-                self._fr.record("admit", step=self._step_idx, rid=r.rid,
-                                slot=slot, bucket=r._bucket)
-            if r.preempts:
-                # preemption resume: the adopted chain covers [0, off0) —
-                # the suffix is the whole recompute cost
-                self._n_resume_suffix += p - off0
-                self._n_resume_total += p
-                if self._fr is not None:
-                    self._fr.record("resume", step=self._step_idx,
-                                    rid=r.rid, slot=slot,
-                                    suffix_tokens=p - off0, total_tokens=p)
-                if m is not None:
-                    m.preempt_resume_tokens.inc(p - off0)
-            padded = np.zeros((-(-p // P) * P,), np.int32)
-            padded[:p] = tok
-            if off0:
-                # prefix hit: the adopted blocks already hold rows
-                # [0, off0) — prefill starts at the suffix offset
-                if self._fr is not None:
-                    self._fr.record("prefix_hit", step=self._step_idx,
-                                    rid=r.rid, slot=slot, tokens=off0,
-                                    host_tokens=host_tok)
-                if m is not None:
-                    m.prefix_reuse_tokens.inc(off0)
-                    if off0 > host_tok:
-                        m.prefix_hit("device")
-                    if host_tok:
-                        m.prefix_hit("host")
-                if self._mode == "spec":
-                    # the skipped chunks would have written hist rows
-                    # [0, off0); rebuild the slot's whole prompt row
-                    # eagerly.  Draft quality only — emission is always
-                    # the verify forward's own greedy picks (lossless),
-                    # so output bytes never depend on hist contents
-                    row = np.zeros((self._lmax,), np.int32)
-                    w = min(padded.size, self._lmax)
-                    row[:w] = padded[:w]
-                    self._hist = self._hist.at[slot].set(jnp.asarray(row))
-            # device-ready prompt length, built here (outside the chunk
-            # dispatch loop) so _spend_prefill stays sync-free
-            self._pf[slot] = {"req": r, "tok": padded, "p": p, "off": off0,
-                              "doff": doff0, "first": None, "okf": None,
-                              "plen": jnp.asarray(np.array([p], np.int32))}
-            if m is not None:
-                m.admitted.inc()
-                m.prefill(r._bucket)
+            with self._phase("admit", r, mark="prefilling", slot=slot,
+                             bucket=r._bucket):
+                self._kv.assign(slot, r)
+                self._reset_spec_slot(slot)
+                p = int(tok.size)
                 if self._paged:
-                    m.prompt_tokens.inc(p)
-                m.queue_wait.observe(time.perf_counter() - r.t_submit)
+                    self._kv.adopt_prefix(slot, shared)
+                    if self._dspec:
+                        self._kv.adopt_draft_prefix(slot, dshared)
+                    self._kv.reserve(slot, budget + dbudget)
+                    self._need_rows[slot] = need
+                    r._adm_ids = tok
+                    self._n_prompt_tokens += p
+                    self._n_reuse_tokens += off0
+                    self._n_host_reuse_tokens += host_tok
+                if r.preempts:
+                    # preemption resume: the adopted chain covers [0, off0) —
+                    # the suffix is the whole recompute cost
+                    self._n_resume_suffix += p - off0
+                    self._n_resume_total += p
+                    self._event("resume", r, slot=slot,
+                                suffix_tokens=p - off0, total_tokens=p)
+                    if m is not None:
+                        m.preempt_resume_tokens.inc(p - off0)
+                padded = np.zeros((-(-p // P) * P,), np.int32)
+                padded[:p] = tok
+                if off0:
+                    # prefix hit: the adopted blocks already hold rows
+                    # [0, off0) — prefill starts at the suffix offset
+                    self._event("prefix_hit", r, slot=slot, tokens=off0,
+                                host_tokens=host_tok)
+                    if m is not None:
+                        m.prefix_reuse_tokens.inc(off0)
+                        if off0 > host_tok:
+                            m.prefix_hit("device")
+                        if host_tok:
+                            m.prefix_hit("host")
+                    if self._mode == "spec":
+                        # the skipped chunks would have written hist rows
+                        # [0, off0); rebuild the slot's whole prompt row
+                        # eagerly.  Draft quality only — emission is always
+                        # the verify forward's own greedy picks (lossless),
+                        # so output bytes never depend on hist contents
+                        row = np.zeros((self._lmax,), np.int32)
+                        w = min(padded.size, self._lmax)
+                        row[:w] = padded[:w]
+                        self._hist = self._hist.at[slot].set(jnp.asarray(row))
+                # device-ready prompt length, built here (outside the chunk
+                # dispatch loop) so _spend_prefill stays sync-free
+                self._pf[slot] = {"req": r, "tok": padded, "p": p, "off": off0,
+                                  "doff": doff0, "first": None, "okf": None,
+                                  "plen": jnp.asarray(np.array([p], np.int32))}
+                if m is not None:
+                    m.admitted.inc()
+                    m.prefill(r._bucket)
+                    if self._paged:
+                        m.prompt_tokens.inc(p)
+                    m.queue_wait.observe(time.perf_counter() - r.t_submit)
         if m is not None:
             m.queue_depth.set(len(self._queue))
             m.slots_occupied.set(self._kv.occupied())
@@ -2050,14 +2077,9 @@ class ServingEngine:
         # prompts on THIS worker reuse it — prefix reuse survives
         # migration
         self._kv.register_prefix(slot, tok)
-        if self._fr is not None:
-            tr = RequestTrace(request.rid)
-            request._trace = tr
-            with self._trace_lock:
-                self._traces[request.rid] = tr
-                while len(self._traces) > self._trace_cap:
-                    self._traces.popitem(last=False)
-            tr.mark("decoding", slot=slot)
+        self._attach_trace(request)
+        if request._trace is not None:
+            request._trace.mark("decoding", slot=slot)
         if self._m is not None:
             self._m.admitted.inc()
             self._m.prompt_tokens.inc(p)
@@ -2078,6 +2100,10 @@ class ServingEngine:
         next drain.  Returns the number of chunks dispatched."""
         if not self._pf:
             return 0
+        with self._phase("spend_prefill", prefilling=len(self._pf)):
+            return self._spend_chunks()
+
+    def _spend_chunks(self):
         m = self._m
         P = self._pchunk
         budget = self._pbudget
@@ -2088,24 +2114,18 @@ class ServingEngine:
             st = self._pf[slot]
             while budget:
                 if st["off"] < st["p"]:
-                    k = st["off"] // P
-                    if st["req"]._trace is not None:
-                        st["req"]._trace.mark("prefilling", chunk=k,
-                                              slot=slot)
-                    if self._fr is not None:
-                        self._fr.record("prefill_chunk",
-                                        step=self._step_idx,
-                                        rid=st["req"].rid, slot=slot,
-                                        chunk=k)
-                    if self._paged:
-                        # map the chunk's REAL rows before its writes
-                        # dispatch (pad columns past the prompt drop on
-                        # the sentinel); draws down the reservation made
-                        # at admission
-                        self._kv.ensure_rows(
-                            slot, min(st["off"] + P, st["p"]))
-                    chunk = st["tok"][st["off"]:st["off"] + P][None, :]
-                    with m.span_prefill if m is not None else _NULL_CTX:
+                    with self._phase(
+                            "prefill_chunk", st["req"], mark="prefilling",
+                            slot=slot, chunk=st["off"] // P,
+                            final=st["off"] + P >= st["p"]):
+                        if self._paged:
+                            # map the chunk's REAL rows before its writes
+                            # dispatch (pad columns past the prompt drop
+                            # on the sentinel); draws down the
+                            # reservation made at admission
+                            self._kv.ensure_rows(
+                                slot, min(st["off"] + P, st["p"]))
+                        chunk = st["tok"][st["off"]:st["off"] + P][None, :]
                         first, okf, self._kv.caches, hist, hist_len = \
                             self._call_prefill_chunk(
                                 jnp.asarray(chunk),
@@ -2132,9 +2152,8 @@ class ServingEngine:
                         self._kv.ensure_draft_rows(
                             slot, min(st["doff"] + P, st["p"]))
                     dchunk = st["tok"][st["doff"]:st["doff"] + P][None, :]
-                    with m.span_prefill if m is not None else _NULL_CTX:
-                        self._call_draft_prefill_chunk(
-                            dchunk, st["doff"], st["plen"], slot)
+                    self._call_draft_prefill_chunk(
+                        dchunk, st["doff"], st["plen"], slot)
                     st["doff"] += P
                 budget -= 1
                 spent += 1
@@ -2159,8 +2178,9 @@ class ServingEngine:
         if not self._pending_firsts:
             return 0
         pend, self._pending_firsts = self._pending_firsts, []
-        vals = self._fetch(
-            "drain", *(x for _, _, f, o in pend for x in (f, o)))
+        with self._phase("drain.wait", firsts=len(pend)):
+            vals = self._fetch(
+                "drain", *(x for _, _, f, o in pend for x in (f, o)))
         emitted = 0
         for n, (slot, r, _, _) in enumerate(pend):
             fv, ov = vals[2 * n], vals[2 * n + 1]
@@ -2205,8 +2225,7 @@ class ServingEngine:
                 r.t_first = time.perf_counter()
                 if m is not None:
                     m.ttft.observe(r.t_first - r.t_submit)
-                if r._trace is not None:
-                    r._trace.mark("decoding", slot=slot)
+                self._event("first_token", r, mark="decoding", slot=slot)
             if len(r.output_ids) >= r.max_new_tokens or (
                     r.eos_token_id is not None
                     and int(t) == int(r.eos_token_id)):
@@ -2254,20 +2273,18 @@ class ServingEngine:
         dispatch over every live slot.  Returns tokens emitted."""
         self._last_step_unix = time.time()
         m = self._m
-        if m is None:
-            return self._step_impl()
-        m.steps.inc()
-        m.last_step_time.set(self._last_step_unix)
-        with m.span_step:
+        if m is not None:
+            m.steps.inc()
+            m.last_step_time.set(self._last_step_unix)
+        self._step_idx += 1
+        with self._phase("step"):
             return self._step_impl()
 
     def _step_impl(self):
-        self._step_idx += 1
         if self._faults is not None:
             stalled = self._faults.maybe_slow_step(self._step_idx)
-            if stalled and self._fr is not None:
-                self._fr.record("stall", step=self._step_idx,
-                                seconds=stalled, injected=True)
+            if stalled:
+                self._event("stall", seconds=stalled, injected=True)
         self._expire_deadlines()
         self._apply_poison()
         self._apply_host_corrupt()
@@ -2349,66 +2366,56 @@ class ServingEngine:
         self._ensure_decode_rows(live)
         active = np.array([self._decodable(i) for i in range(self._B)])
         dev_len = self._kv.device_lengths(active)
-        if self._fr is not None:
-            self._fr.record("dispatch", step=self._step_idx,
-                            mode=self._mode, n_live=len(live),
-                            kv_quant=self._kvq,
-                            attn_impl=self._attn_label,
-                            prefill_impl=self._prefill_label,
-                            weight_dtype=self._wq_label)
         if self._mode == "greedy":
             def go(attempt):
                 self._fault_point("dispatch", attempt)
                 return self._call_decode(jnp.asarray(self._cur.copy()), dev_len)
-            with m.span_decode if m is not None else _NULL_CTX:
+            with self._phase("dispatch", **self._dispatch_detail(live)):
                 toks, okd, self._kv.caches = self._retry(
                     go, "decode dispatch")
-                toks, okd = self._fetch("drain", toks, okd)
-            if self._fr is not None:
-                self._fr.record("drain", step=self._step_idx,
-                                mode="greedy", n_live=len(live))
-            self._observe_interference(adm_active, self._sync)
-            for i in live:
-                if not bool(okd[i]):
-                    self._retire(i, "poisoned")
-                    continue
-                emitted += self._emit(i, toks[i].tolist())
-                self._kv.lengths[i] += self._sync
-                self._cur[i] = toks[i, -1]
+            with self._phase("drain", mode="greedy", n_live=len(live)):
+                with self._phase("drain.wait"):
+                    toks, okd = self._fetch("drain", toks, okd)
+                with self._phase("emit"):
+                    self._observe_interference(adm_active, self._sync)
+                    for i in live:
+                        if not bool(okd[i]):
+                            self._retire(i, "poisoned")
+                            continue
+                        emitted += self._emit(i, toks[i].tolist())
+                        self._kv.lengths[i] += self._sync
+                        self._cur[i] = toks[i, -1]
         else:
             k = self._next_k(live)
-            if self._fr is not None:
-                self._fr.record("draft", step=self._step_idx,
-                                source=self._spec.source, k=k,
-                                n_live=len(live))
+            self._event("draft", source=self._spec.source, k=k,
+                        n_live=len(live))
 
             def go(attempt):
                 self._fault_point("dispatch", attempt)
                 return self._call_spec(jnp.asarray(self._cur.copy()), dev_len,
                                        jnp.asarray(active), k)
-            with m.span_spec if m is not None else _NULL_CTX:
+            with self._phase("dispatch", **self._dispatch_detail(live)):
                 blk, j, cur, _, oks, self._kv.caches, self._hist, \
                     self._hist_len = self._retry(go, "spec dispatch")
-                blk, j, cur, oks = self._fetch("drain", blk, j, cur, oks)
-            if self._fr is not None:
-                self._fr.record("drain", step=self._step_idx, mode="spec",
-                                n_live=len(live))
-            accepted = 0
-            rounds = []
-            for i in live:
-                if not bool(oks[i]):
-                    self._retire(i, "poisoned")
-                    continue
-                emitted += self._emit(i, blk[i, :int(j[i]) + 1].tolist())
-                self._kv.lengths[i] += int(j[i]) + 1
-                self._cur[i] = cur[i]
-                accepted += int(j[i])
-                rounds.append((i, int(j[i])))
-            if self._fr is not None:
-                self._fr.record("verify", step=self._step_idx, k=k,
-                                drafted=k * len(rounds), accepted=accepted)
-                self._fr.record("rewind", step=self._step_idx,
-                                tokens=k * len(rounds) - accepted)
+            with self._phase("drain", mode="spec", n_live=len(live)):
+                with self._phase("drain.wait"):
+                    blk, j, cur, oks = self._fetch("drain", blk, j, cur, oks)
+                accepted = 0
+                rounds = []
+                with self._phase("emit"):
+                    for i in live:
+                        if not bool(oks[i]):
+                            self._retire(i, "poisoned")
+                            continue
+                        emitted += self._emit(
+                            i, blk[i, :int(j[i]) + 1].tolist())
+                        self._kv.lengths[i] += int(j[i]) + 1
+                        self._cur[i] = cur[i]
+                        accepted += int(j[i])
+                        rounds.append((i, int(j[i])))
+            self._event("verify", k=k, drafted=k * len(rounds),
+                        accepted=accepted)
+            self._event("rewind", tokens=k * len(rounds) - accepted)
             self._adapt_k(rounds, k)
             self._observe_interference(
                 adm_active, 1.0 + accepted / len(live))
@@ -2435,14 +2442,20 @@ class ServingEngine:
         if not live:
             return
         self._ensure_decode_rows(live)
+        with self._phase("dispatch",
+                         **self._dispatch_detail(live, pipelined=True)):
+            self._dispatch_live(live, adm_active)
+
+    def _dispatch_detail(self, live, **extra):
+        """What a ``dispatch`` phase says of itself: the batch it runs
+        over and the engine's storage / kernel knobs."""
+        return dict(mode=self._mode, n_live=len(live), kv_quant=self._kvq,
+                    attn_impl=self._attn_label,
+                    prefill_impl=self._prefill_label,
+                    weight_dtype=self._wq_label, **extra)
+
+    def _dispatch_live(self, live, adm_active):
         m = self._m
-        if self._fr is not None:
-            self._fr.record("dispatch", step=self._step_idx,
-                            mode=self._mode, n_live=len(live),
-                            pipelined=True, kv_quant=self._kvq,
-                            attn_impl=self._attn_label,
-                            prefill_impl=self._prefill_label,
-                            weight_dtype=self._wq_label)
         active = np.array([self._decodable(i) for i in range(self._B)])
         host_len = self._kv.device_lengths(active)
         use_host = ~active
@@ -2467,9 +2480,7 @@ class ServingEngine:
             def go(attempt):
                 self._fault_point("dispatch", attempt)
                 return self._call_decode(cur, host_len)
-            with m.span_decode if m is not None else _NULL_CTX:
-                toks, okd, self._kv.caches = self._retry(
-                    go, "decode dispatch")
+            toks, okd, self._kv.caches = self._retry(go, "decode dispatch")
             self._dev_cur = toks[:, -1]
             for i in live:
                 self._kv.lengths[i] += self._sync
@@ -2488,18 +2499,15 @@ class ServingEngine:
                                     self._dev_len)
 
             k = self._next_k(live)
-            if self._fr is not None:
-                self._fr.record("draft", step=self._step_idx,
-                                source=self._spec.source, k=k,
-                                n_live=len(live))
+            self._event("draft", source=self._spec.source, k=k,
+                        n_live=len(live))
 
             def go(attempt):
                 self._fault_point("dispatch", attempt)
                 return self._call_spec(cur, dev_len, jnp.asarray(active),
                                        k)
-            with m.span_spec if m is not None else _NULL_CTX:
-                blk, j, cur2, new_len, oks, self._kv.caches, self._hist, \
-                    self._hist_len = self._retry(go, "spec dispatch")
+            blk, j, cur2, new_len, oks, self._kv.caches, self._hist, \
+                self._hist_len = self._retry(go, "spec dispatch")
             self._dev_cur, self._dev_len = cur2, new_len
             self._inflight = {"kind": "spec", "blk": blk, "j": j,
                               "ok": oks, "k": k,
@@ -2518,43 +2526,57 @@ class ServingEngine:
         host-visible half of the one-step-late retirement invariant."""
         if rec is None:
             return 0
+        with self._phase("drain", mode=rec["kind"], n_live=len(rec["live"]),
+                         pipelined=True):
+            return self._drain_record(rec)
+
+    def _drain_record(self, rec):
         m = self._m
         # the freshly issued dispatch (if any) stays outstanding through
         # this drain — that overlap is the point; the gauge must not claim
         # the pipe is empty just because THIS record got synced
         still_inflight = 1 if self._inflight is not None else 0
         firsts = rec.get("firsts", [])
-        t0 = time.perf_counter()
-        emitted = 0
+        spec = rec["kind"] != "greedy"
         fo = [x for _, _, f, o in firsts for x in (f, o)]
+        out = (rec["blk"], rec["j"], rec["ok"]) if spec \
+            else (rec["toks"], rec["ok"])
+        # the ONE blocking fetch of the iteration: what the engine thread
+        # waits on the device for (serving_pipeline_stall_seconds)
+        with self._phase("drain.wait",
+                         observe=m.pipeline_stall if m is not None else None):
+            vals = self._fetch("drain", *out, *fo)
+        if m is not None:
+            m.inflight.set(still_inflight)
+        with self._phase("emit"):
+            return self._emit_record(rec, vals[:len(out)], vals[len(out):])
+
+    def _emit_record(self, rec, out, fvals):
+        """Hand a drained record's tokens to their requests."""
+        m = self._m
+        emitted = 0
         if rec["kind"] == "greedy":
-            vals = self._fetch("drain", rec["toks"], rec["ok"], *fo)
-            toks, okd, fvals = vals[0], vals[1], vals[2:]
-            stall = time.perf_counter() - t0
-            if m is not None:
-                m.pipeline_stall.observe(stall)
-                m.inflight.set(still_inflight)
-            if self._fr is not None:
-                self._fr.record("stall", step=self._step_idx, seconds=stall)
-                self._fr.record("drain", step=self._step_idx, mode="greedy",
-                                n_live=len(rec["live"]), pipelined=True)
             self._observe_interference(rec.get("adm", False), self._sync)
-            # the first tokens ride the record they were dispatched before
-            # (program order: final prefill chunk, then this decode step) —
-            # emit them ahead of the slot's decode block
-            for n, (slot, r, _, _) in enumerate(firsts):
-                if self._kv.reqs[slot] is not r:
-                    continue
-                fv, ov = fvals[2 * n], fvals[2 * n + 1]
-                if not bool(ov[0]):
-                    self._retire(slot, "poisoned")
-                    continue
-                if self._paged:
-                    # post-finite-check, pre-_emit (which may release):
-                    # same registration rule as _flush_firsts
-                    self._kv.register_prefix(slot, r._adm_ids)
-                self._cur[slot] = int(fv[0])
-                emitted += self._emit(slot, [int(fv[0])])
+        # the first tokens ride the record they were dispatched before
+        # (program order: final prefill chunk, then this decode step) —
+        # emit them ahead of the slot's decode block
+        for n, (slot, r, _, _) in enumerate(rec.get("firsts", [])):
+            if self._kv.reqs[slot] is not r:
+                continue
+            fv, ov = fvals[2 * n], fvals[2 * n + 1]
+            if not bool(ov[0]):
+                self._retire(slot, "poisoned")
+                continue
+            if self._paged:
+                # post-finite-check, pre-_emit (which may release):
+                # same registration rule as _flush_firsts
+                self._kv.register_prefix(slot, r._adm_ids)
+                if self._dspec:
+                    self._kv.register_draft_prefix(slot, r._adm_ids)
+            self._cur[slot] = int(fv[0])
+            emitted += self._emit(slot, [int(fv[0])])
+        if rec["kind"] == "greedy":
+            toks, okd = out
             for i in rec["live"]:
                 if self._kv.reqs[i] is not rec["reqs"][i]:
                     continue
@@ -2563,58 +2585,30 @@ class ServingEngine:
                     continue
                 emitted += self._emit(i, toks[i].tolist())
                 self._cur[i] = toks[i, -1]
-        else:
-            vals = self._fetch("drain", rec["blk"], rec["j"], rec["ok"],
-                               *fo)
-            blk, j, okd, fvals = vals[0], vals[1], vals[2], vals[3:]
-            stall = time.perf_counter() - t0
-            if m is not None:
-                m.pipeline_stall.observe(stall)
-                m.inflight.set(still_inflight)
-            if self._fr is not None:
-                self._fr.record("stall", step=self._step_idx, seconds=stall)
-                self._fr.record("drain", step=self._step_idx, mode="spec",
-                                n_live=len(rec["live"]), pipelined=True)
-            for n, (slot, r, _, _) in enumerate(firsts):
-                if self._kv.reqs[slot] is not r:
-                    continue
-                fv, ov = fvals[2 * n], fvals[2 * n + 1]
-                if not bool(ov[0]):
-                    self._retire(slot, "poisoned")
-                    continue
-                if self._paged:
-                    # post-finite-check, pre-_emit (which may release):
-                    # same registration rule as _flush_firsts
-                    self._kv.register_prefix(slot, r._adm_ids)
-                    if self._dspec:
-                        self._kv.register_draft_prefix(slot, r._adm_ids)
-                self._cur[slot] = int(fv[0])
-                emitted += self._emit(slot, [int(fv[0])])
-            k = rec.get("k", self._spec_k)
-            accepted = 0
-            drained = 0
-            rounds = []
-            for i in rec["live"]:
-                if self._kv.reqs[i] is not rec["reqs"][i]:
-                    continue
-                if not bool(okd[i]):
-                    self._retire(i, "poisoned")
-                    continue
-                drained += 1
-                emitted += self._emit(i, blk[i, :int(j[i]) + 1].tolist())
-                self._kv.lengths[i] += int(j[i]) + 1
-                accepted += int(j[i])
-                rounds.append((i, int(j[i])))
-            if self._fr is not None:
-                self._fr.record("verify", step=self._step_idx, k=k,
-                                drafted=k * drained, accepted=accepted)
-                self._fr.record("rewind", step=self._step_idx,
-                                tokens=k * drained - accepted)
-            self._adapt_k(rounds, k)
-            self._observe_interference(
-                rec.get("adm", False), 1.0 + accepted / max(1, drained))
-            if m is not None and drained:
-                m.spec_round(k * drained, accepted)
+            return emitted
+        blk, j, okd = out
+        k = rec.get("k", self._spec_k)
+        accepted = 0
+        drained = 0
+        rounds = []
+        for i in rec["live"]:
+            if self._kv.reqs[i] is not rec["reqs"][i]:
+                continue
+            if not bool(okd[i]):
+                self._retire(i, "poisoned")
+                continue
+            drained += 1
+            emitted += self._emit(i, blk[i, :int(j[i]) + 1].tolist())
+            self._kv.lengths[i] += int(j[i]) + 1
+            accepted += int(j[i])
+            rounds.append((i, int(j[i])))
+        self._event("verify", k=k, drafted=k * drained, accepted=accepted)
+        self._event("rewind", tokens=k * drained - accepted)
+        self._adapt_k(rounds, k)
+        self._observe_interference(
+            rec.get("adm", False), 1.0 + accepted / max(1, drained))
+        if m is not None and drained:
+            m.spec_round(k * drained, accepted)
         return emitted
 
     def run(self):

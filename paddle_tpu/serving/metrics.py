@@ -12,7 +12,6 @@ tests/test_observability.py).
 from __future__ import annotations
 
 from paddle_tpu.observability.metrics import get_registry
-from paddle_tpu.observability.trace import span
 
 __all__ = ["EngineMetrics", "DisaggMetrics"]
 
@@ -25,10 +24,6 @@ class EngineMetrics:
         self.registry = reg
         L = ("policy",)
         lbl = {"policy": policy}
-        # sharded engines label their spans with the mesh device count so
-        # a single-chip run ("" — the default every host span gets) and a
-        # TP run stay separable per scrape; the gauge carries the count
-        mesh_label = str(mesh_devices) if mesh_devices > 1 else ""
         self.mesh_devices = reg.gauge(
             "serving_mesh_devices",
             "devices the engine's compiled programs span (1 = single-chip)",
@@ -334,14 +329,6 @@ class EngineMetrics:
             "at int8 storage: every projection element once (1 byte) + "
             "2 f16 scale bytes per output channel; zero when "
             "weight_dtype is unquantized", L).labels(**lbl)
-        self.span_step = span("serving.step", registry=reg,
-                              mesh=mesh_label)
-        self.span_prefill = span("serving.prefill", registry=reg,
-                                 mesh=mesh_label)
-        self.span_decode = span("serving.decode", registry=reg,
-                                mesh=mesh_label)
-        self.span_spec = span("serving.spec_step", registry=reg,
-                              mesh=mesh_label)
 
     def prefill(self, bucket):
         self._prefills.labels(policy=self._policy, bucket=bucket).inc()

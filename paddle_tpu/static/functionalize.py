@@ -13,7 +13,6 @@ and full tp/pp/dp/sharding meshes.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -31,17 +30,14 @@ __all__ = ["TrainStep", "build_train_step", "build_eval_fn"]
 # replaced optimizer) is a recompile storm that only shows as wall-clock
 # without these series.  Dispatches land in compile_cache_{hits,misses}_total
 # {cache="functionalize"} + compile_seconds; every step also counts into
-# train_steps_total / train_step_dispatch_seconds and runs under a
-# "train.step" span (visible in paddle.profiler chrome traces).
+# train_steps_total, and its dispatch runs under a "train.step" span (an
+# event of the profiler's timeline, beside the device lines, when a
+# jax.profiler session is on).  The dispatch is asynchronous: the span
+# times the enqueue, never the step — the step's device time is the XLA
+# module's, and its parts carry the names of observability.trace.SCOPES.
 _mon = CompileCacheMonitor("functionalize")
 _train_steps = get_registry().counter(
     "train_steps_total", "fused train-step dispatches")
-_train_dispatch = get_registry().histogram(
-    "train_step_dispatch_seconds",
-    "wall seconds per TrainStep dispatch (async under jax: includes "
-    "trace+compile on a cache miss, excludes device execution unless a "
-    "readback forces it)")
-_train_span = span("train.step")
 
 
 class _ClipStub:
@@ -215,7 +211,9 @@ class TrainStep:
         prev = optimizer._global_step
         optimizer._global_step = step  # bias-correction uses the traced step counter
         try:
-            new_params, new_states = optimizer.functional_update(params, grads, states, lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_states = optimizer.functional_update(
+                    params, grads, states, lr)
         finally:
             optimizer._global_step = prev
 
@@ -226,19 +224,26 @@ class TrainStep:
             new_params = zero_constrain(new_params)
         return lval, new_params, new_states
 
-    def __call__(self, *datas):
+    def _operands(self, step_count, datas):
         arrs = [d.data if isinstance(d, Tensor) else jnp.asarray(d) for d in datas]
         lr = jnp.asarray(self._optimizer.get_lr(), jnp.float32)
+        step = jnp.asarray(step_count, jnp.int32)
+        return (self._params, self._buffers, self._states, lr, step, *arrs)
+
+    def lower(self, *datas):
+        """The step program lowered from the live operands, exactly as the
+        next ``__call__`` would pass them: ``.compile()`` of the result is
+        the program the steps run (a compile-cache hit once a step has
+        run).  Nothing is donated, run or counted."""
+        return self._jitted.lower(*self._operands(self._step_count + 1, datas))
+
+    def __call__(self, *datas):
         self._step_count += 1
-        step = jnp.asarray(self._step_count, jnp.int32)
+        operands = self._operands(self._step_count, datas)
         _train_steps.inc()
-        t0 = time.perf_counter()
-        with _train_span:
+        with span("train.step", step=self._step_count):
             lval, self._params, self._states = _mon.call(
-                "train_step", self._jitted,
-                self._params, self._buffers, self._states, lr, step, *arrs
-            )
-        _train_dispatch.observe(time.perf_counter() - t0)
+                "train_step", self._jitted, *operands)
         # FLAGS_check_nan_inf on the fused path: one loss readback per step
         # (per-op checking is impossible inside a compiled program; a
         # non-finite loss is the canonical divergence signal the reference's
@@ -266,6 +271,23 @@ class TrainStep:
 
     def state_dict(self):
         return {n: Tensor(a) for n, a in {**self._params, **self._buffers}.items()}
+
+    @property
+    def params(self):
+        """The step's live parameters, ``{name: jax array}`` as of the last
+        step dispatched (read-only view: a copy of the mapping, not of the
+        arrays).  With ``donate=True`` the arrays are consumed by the next
+        step — read them between steps, do not hold them across one."""
+        return dict(self._params)
+
+    @property
+    def optimizer_states(self):
+        """The optimizer's functional state, ``{accumulator: {name: jax
+        array}}`` (``moment1``, ``moment2``, and for quantized moments
+        their ``@scale`` siblings) as of the last step dispatched — same
+        read-only contract as :attr:`params`."""
+        return {k: dict(v) if isinstance(v, dict) else v
+                for k, v in self._states.items()}
 
 
 def amp_args_from_strategy(strategy):

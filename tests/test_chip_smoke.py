@@ -68,14 +68,18 @@ class _Recorder:
         self.updates.append((name, value))
 
 
+# a cache hit must carry the op names of the code that runs (PERF.md, PR 26)
+NAMES_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 def test_compile_cache_placed_from_outside(monkeypatch):
     """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the helper
-    sets no directory in code."""
+    sets no directory in code (only what the key is made of)."""
     rec = _Recorder()
     monkeypatch.setattr(jax.config, "update", rec)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
     assert compile_cache.enable_compile_cache() == "/somewhere/else"
-    assert rec.updates == []
+    assert rec.updates == [NAMES_IN_KEY]
 
 
 def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch):
@@ -85,7 +89,8 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch):
     want = os.path.join(REPO, ".jax_cache")
     assert compile_cache.enable_compile_cache() == want
     assert compile_cache.enable_compile_cache() == want   # never moves
-    assert rec.updates == [("jax_compilation_cache_dir", want)] * 2
+    assert rec.updates == [NAMES_IN_KEY,
+                           ("jax_compilation_cache_dir", want)] * 2
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
 

@@ -3,8 +3,8 @@
 Tier-1 coverage for the three parts — registry, exporter, span tracing —
 plus the cross-cutting guarantees: Prometheus text-format validity for
 every registered series, deterministic exporter shutdown (no leaked
-thread/socket), span events nesting correctly inside profiler chrome-trace
-exports, compile-cache hit/miss accounting, and the overhead guard — the
+thread/socket), span events nesting correctly on the profiler's timeline
+(the xplane of a ``jax.profiler`` session), compile-cache hit/miss accounting, and the overhead guard — the
 instrumented serving engine's token outputs are byte-identical to an
 uninstrumented run.
 """
@@ -24,6 +24,8 @@ from paddle_tpu.observability import (
     MetricsExporter, MetricsRegistry, get_registry, span,
 )
 from paddle_tpu.serving import Request, ServingEngine
+
+from _xplane import host_events, inside, profiled
 
 
 def _tiny_model(seed=0):
@@ -197,73 +199,85 @@ class TestExporter:
 
 # -------------------------------------------------------------------- spans
 class TestSpans:
-    def test_span_records_histogram(self):
-        reg = MetricsRegistry()
-        with span("phase.outer", registry=reg):
-            with span("phase.inner", registry=reg):
-                pass
-        h = reg.get("span_seconds")
-        assert h.labels(name="phase.outer", mesh="").count == 1
-        assert h.labels(name="phase.inner", mesh="").count == 1
-        assert h.labels(name="phase.outer", mesh="").sum >= \
-            h.labels(name="phase.inner", mesh="").sum
+    """``span`` has ONE sink: the profiler's timeline (a
+    ``jax.profiler.TraceAnnotation``).  With a session on, a span is an
+    event of the host plane carrying its details; with none it records
+    nothing anywhere."""
 
-    def test_span_reentrant_single_instance(self):
-        reg = MetricsRegistry()
-        s = span("phase.re", registry=reg)
-        with s:
-            with s:
-                pass
-        assert reg.get("span_seconds").labels(
-            name="phase.re", mesh="").count == 2
+    def test_span_is_an_event_of_the_profilers_timeline(self, tmp_path):
+        with profiled(tmp_path):
+            with span("phase.outer", step=3):
+                with span("phase.inner", rid="r7", slot=1):
+                    pass
+        outer, inner = host_events(tmp_path, ["phase."])
+        assert outer["name"] == "phase.outer" and str(outer["step"]) == "3"
+        assert inner["name"] == "phase.inner"
+        assert str(inner["rid"]) == "r7" and str(inner["slot"]) == "1"
+        assert inside(inner, outer)
 
-    def test_span_decorator(self):
-        reg = MetricsRegistry()
+    def test_span_nests_with_its_own_name(self, tmp_path):
+        with profiled(tmp_path):
+            with span("phase.re"):
+                with span("phase.re"):
+                    pass
+        a, b = host_events(tmp_path, ["phase.re"])
+        assert inside(b, a)
 
-        @span("phase.fn", registry=reg)
+    def test_span_decorator(self, tmp_path):
+        @span("phase.fn", kind="unit")
         def f(x):
             return x + 1
 
-        assert f(1) == 2 and f(2) == 3
-        assert reg.get("span_seconds").labels(
-            name="phase.fn", mesh="").count == 2
+        with profiled(tmp_path):
+            assert f(1) == 2 and f(2) == 3
+        evs = host_events(tmp_path, ["phase.fn"])
+        assert len(evs) == 2 and all(e["kind"] == "unit" for e in evs)
+        assert f.__name__ == "f"
 
-    def test_serving_spans_nest_in_chrome_trace(self, tmp_path):
-        """Satellite: spans emitted during a B2 serving smoke appear in the
-        exported chrome trace JSON, decode/prefill nested inside steps."""
+    def test_span_without_a_session_writes_nowhere(self):
+        """No histogram, no list: the registry gains no series and the
+        ``paddle.profiler`` host tracer no event."""
+        reg = get_registry()
+        before = set(reg.snapshot())
+        tracer = paddle_profiler.get_host_tracer()
+        n0 = len(tracer.events)
+        with span("phase.quiet", step=1):
+            pass
+        assert set(reg.snapshot()) == before
+        assert reg.get("span_seconds") is None
+        assert len(tracer.events) == n0
+
+    def test_serving_spans_nest_in_the_profilers_trace(self, tmp_path):
+        """Spans of a B2 serving smoke are events of the xplane:
+        dispatch / drain nested inside steps, the one blocking fetch
+        inside its drain, chunks inside spend_prefill."""
         model = _tiny_model()
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 256, (p,)) for p in (5, 9, 6)]
-        prof = paddle_profiler.Profiler()  # CPU host tracer, always RECORD
-        with prof:
-            eng = ServingEngine(model, batch_size=2, max_len=64)
+        eng = ServingEngine(model, batch_size=2, max_len=64)
+        with profiled(tmp_path):
             for p, n in zip(prompts, (4, 6, 3)):
                 eng.submit(Request(p, n))
             eng.run()
-        path = str(tmp_path / "serving_trace.json")
-        prof.export(path)
-        with open(path) as f:
-            evs = json.load(f)["traceEvents"]
         by_name = {}
-        for e in evs:
+        for e in host_events(tmp_path, ["serving."]):
             by_name.setdefault(e["name"], []).append(e)
-        steps = by_name.get("serving.step", [])
-        children = by_name.get("serving.decode", []) + \
-            by_name.get("serving.prefill", [])
-        assert steps, "serving.step spans missing from chrome trace"
-        assert by_name.get("serving.decode"), "serving.decode spans missing"
-        assert by_name.get("serving.prefill"), "serving.prefill spans missing"
-        eps = 1e-3  # us; clock quantization guard
-
-        def inside(c, p):
-            return (c["ts"] >= p["ts"] - eps
-                    and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + eps)
-
-        for c in children:  # correct nesting: every child inside SOME step
-            assert any(inside(c, s) for s in steps), \
-                f"span {c['name']} at ts={c['ts']} not nested in a step"
-        for e in evs:
-            assert e["ph"] == "X" and e["dur"] >= 0
+        steps = by_name["serving.step"]
+        assert len(by_name["serving.submit"]) == 3
+        for child, parent in (("serving.dispatch", "serving.step"),
+                              ("serving.drain", "serving.step"),
+                              ("serving.admit", "serving.step"),
+                              ("serving.spend_prefill", "serving.step"),
+                              ("serving.prefill_chunk",
+                               "serving.spend_prefill"),
+                              ("serving.drain.wait", "serving.drain"),
+                              ("serving.emit", "serving.drain")):
+            assert by_name.get(child), f"{child} spans missing"
+            for c in by_name[child]:
+                assert any(inside(c, p) for p in by_name[parent]), \
+                    f"{child} at {c['start']} not nested in a {parent}"
+        assert [int(s["step"]) for s in steps] == sorted(
+            int(s["step"]) for s in steps)
 
 
 # ------------------------------------------------- engine instrumentation
@@ -363,7 +377,7 @@ class TestCompileCacheMetrics:
         plab = dict(cache="llama_decode", program="decode_params")
         assert self._val("compile_cache_hits_total", **plab) >= 1
 
-    def test_train_step_metrics(self):
+    def test_train_step_metrics(self, tmp_path):
         from paddle_tpu import nn
         from paddle_tpu.static.functionalize import build_train_step
         lab = dict(cache="functionalize", program="train_step")
@@ -371,7 +385,6 @@ class TestCompileCacheMetrics:
         s0 = reg.get("train_steps_total").value
         m0 = self._val("compile_cache_misses_total", **lab)
         h0 = self._val("compile_cache_hits_total", **lab)
-        d0 = reg.get("train_step_dispatch_seconds").count
         paddle.seed(0)
         net = nn.Sequential(nn.Linear(4, 4))
         opt = paddle.optimizer.SGD(learning_rate=1e-3,
@@ -379,15 +392,18 @@ class TestCompileCacheMetrics:
         step = build_train_step(net, nn.MSELoss(), opt)
         x = paddle.to_tensor(np.random.randn(2, 4).astype("float32"))
         y = paddle.to_tensor(np.zeros((2, 4), np.float32))
-        step(x, y)
-        step(x, y)
+        with profiled(tmp_path):
+            step(x, y)
+            step(x, y)
         assert reg.get("train_steps_total").value == s0 + 2
-        assert reg.get("train_step_dispatch_seconds").count == d0 + 2
         assert self._val("compile_cache_misses_total", **lab) == m0 + 1
         assert self._val("compile_cache_hits_total", **lab) == h0 + 1
-        # train.step spans recorded in the default registry
-        sp = reg.get("span_seconds").labels(name="train.step", mesh="")
-        assert sp.count >= 2
+        # the dispatch is asynchronous: no series times it (the step's
+        # time is the device's); each is a train.step span of the
+        # profiler's timeline, carrying its step number
+        assert reg.get("train_step_dispatch_seconds") is None
+        spans = host_events(tmp_path, ["train.step"])
+        assert [int(e["step"]) for e in spans] == [1, 2]
 
 
 # ------------------------------------------------- analysis.runtime guard

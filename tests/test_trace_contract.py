@@ -1,0 +1,321 @@
+"""The names the measurement reads, held by tests (CPU, toy sizes).
+
+The benchmark's per-layer readers find the device work of the three compiled
+programs by name: the XLA module names derived from the jitted functions
+(``serving_decode_steps``, ``serving_prefill_chunk``, ``_step_fn``), the
+``jax.named_scope`` vocabulary of ``observability.trace`` in each operation's
+``op_name``, and the host spans of the engine's phases on the profiler's
+timeline.  A rename that silences a reader fails here first.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.llama_decode import (
+    serving_decode_steps, serving_prefill_chunk,
+)
+from paddle_tpu.observability.trace import LOOPS, SCOPES, SPANS
+from paddle_tpu.serving import Request, ServingEngine
+from paddle_tpu.static.functionalize import build_train_step
+
+from _xplane import host_events, inside, profiled
+
+# substrings the benchmark's existing readers match device events by
+# (benchmark/layer_metrics/*.py: MODULE / KERNEL patterns)
+READER_PATTERNS = ("flash", "_step_fn", "serving_decode_steps",
+                   "serving_prefill_chunk")
+MODULE_NAMES = {"decode": "serving_decode_steps",
+                "prefill": "serving_prefill_chunk", "train": "_step_fn"}
+SERVING = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
+           "attn.core", "attn.out", "mlp", "lm_head", "sample",
+           "attn.core.chunks")
+APPLIES = {
+    "decode": SERVING + ("decode.steps",),
+    "prefill": SERVING,
+    "train": ("embed", "norm", "attn.qkv", "attn.rope", "attn.core",
+              "attn.out", "mlp", "lm_head", "loss", "optimizer"),
+}
+PROMPTS = [np.arange(1, 1 + n, dtype=np.int32) % 250 + 1 for n in (21, 9, 30)]
+NEW = (5, 7, 4)
+
+
+def tiny_model(seed=0, **kw):
+    paddle.seed(seed)
+    model = LlamaForCausalLM(LlamaConfig.tiny(dtype="float32", **kw))
+    model.eval()
+    return model
+
+
+def tiny_engine(**kw):
+    # chunks narrower than the cache, so the chunked read (and its loop)
+    # is the path taken, as at the benchmark's sizes
+    return ServingEngine(tiny_model(), batch_size=2, max_len=64,
+                         prefill_chunk=16, decode_chunk=16, **kw)
+
+
+def tiny_train_step(seed=0):
+    paddle.seed(seed)
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        dtype="float32", recompute=True, loss_chunk_size=32))
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    ids = paddle.to_tensor(
+        np.random.default_rng(seed).integers(0, 256, (2, 32)), dtype="int64")
+    return build_train_step(model, None, opt), ids
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """The three programs, lowered as their callers run them."""
+    eng = tiny_engine()
+    rows, scalar = jnp.zeros((2,), jnp.int32), jnp.int32(0)
+    step, ids = tiny_train_step()
+    return {
+        "decode": serving_decode_steps.__wrapped__.lower(
+            eng._params, eng._cfg, rows, eng._kv.caches, rows,
+            n_steps=eng._sync, chunk_size=eng._chunk, block_tables=None,
+            program_key=eng._pk),
+        "prefill": serving_prefill_chunk.__wrapped__.lower(
+            eng._params, eng._cfg, jnp.zeros((1, 16), jnp.int32), scalar,
+            jnp.zeros((1,), jnp.int32), eng._kv.caches, scalar, hist=None,
+            hist_len=None, with_hist=False, chunk_size=eng._chunk,
+            block_tables=None, program_key=eng._pk),
+        "train": step.lower(ids, ids),
+    }
+
+
+@pytest.fixture(scope="module")
+def op_names(lowered):
+    """Every ``op_name`` of each COMPILED program's HLO."""
+    return {k: set(re.findall(r'op_name="([^"]+)"',
+                              lo.compile().as_text()))
+            for k, lo in lowered.items()}
+
+
+def components(path):
+    """The scope names in an operation's path, outermost first: each
+    ``/``-separated part stripped of the transforms JAX wraps it in
+    (``transpose(jvp(attn.core))`` -> ``attn.core``)."""
+    return [re.sub(r"^(?:\w+\()*|\)*$", "", part) for part in path.split("/")]
+
+
+# (a) the module names the readers match
+@pytest.mark.parametrize("program", sorted(MODULE_NAMES))
+def test_module_name_holds_the_readers_pattern(lowered, program):
+    head = lowered[program].as_text().split("\n", 1)[0]
+    assert MODULE_NAMES[program] in re.search(r"module @(\S+)", head).group(1)
+
+
+# (b) the vocabulary reaches the compiled operations
+@pytest.mark.parametrize("program,name", [
+    (p, n) for p in sorted(APPLIES) for n in APPLIES[p]])
+def test_scope_names_the_compiled_operations(op_names, program, name):
+    assert any(name in components(path) for path in op_names[program]), \
+        f"no operation of the {program} program carries {name!r}"
+
+
+@pytest.mark.parametrize("program", sorted(APPLIES))
+def test_program_carries_no_name_outside_its_list(op_names, program):
+    found = {c for path in op_names[program] for c in components(path)
+             if c in SCOPES + LOOPS}
+    assert found == set(APPLIES[program])
+
+
+def test_loops_are_named(op_names):
+    """A %while of a device trace can be told: the cache-chunk loop's own
+    ``while`` sits under its name, and the step's operations under the
+    scan's (at ``sync_every=1`` XLA takes the one-trip loop itself away)."""
+    assert any("decode.steps/while/body/" in path
+               for path in op_names["decode"])
+    for program in ("decode", "prefill"):
+        assert any(path.endswith("attn.core.chunks/while")
+                   for path in op_names[program])
+
+
+def test_backward_and_recompute_keep_the_forwards_names(op_names):
+    paths = op_names["train"]
+    for name in ("attn.core", "mlp", "loss"):
+        assert any("transpose(" in p and name in components(p)
+                   for p in paths), f"no backward operation under {name!r}"
+    assert any("rematted_computation" in p and "mlp" in components(p)
+               for p in paths)
+
+
+@pytest.mark.parametrize("name", SCOPES + LOOPS + SPANS)
+def test_vocabulary_avoids_the_readers_patterns(name):
+    assert not any(pat in name or name in pat for pat in READER_PATTERNS)
+    assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)*", name)
+
+
+def test_applies_covers_the_vocabulary():
+    assert set().union(*map(set, APPLIES.values())) == set(SCOPES + LOOPS)
+
+
+# (c) the host spans, on the profiler's timeline
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """A few engine steps and two train steps under ONE profiler session:
+    (spans of the xplane, the engine's flight-recorder events)."""
+    trace_dir = tmp_path_factory.mktemp("trace")
+    eng = tiny_engine()
+    step, ids = tiny_train_step()
+    with profiled(trace_dir):
+        for p, n in zip(PROMPTS, NEW):
+            eng.submit(Request(p, n))
+        eng.run()
+        step(ids, ids)
+        step(ids, ids)
+    return (host_events(trace_dir, ["serving.", "train."]),
+            eng.recorder.events())
+
+
+@pytest.mark.parametrize("name", SPANS)
+def test_span_is_an_event_of_the_xplane(traced, name):
+    assert [e for e in traced[0] if e["name"] == name]
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("serving.admit", "serving.step"),
+    ("serving.spend_prefill", "serving.step"),
+    ("serving.prefill_chunk", "serving.spend_prefill"),
+    ("serving.dispatch", "serving.step"),
+    ("serving.drain", "serving.step"),
+    ("serving.drain.wait", "serving.drain"),
+    ("serving.emit", "serving.drain"),
+])
+def test_spans_nest_and_share_their_step(traced, child, parent):
+    parents = [e for e in traced[0] if e["name"] == parent]
+    for c in (e for e in traced[0] if e["name"] == child):
+        mine = [p for p in parents if inside(c, p)]
+        assert len(mine) == 1, f"{child} at {c['start']} not in one {parent}"
+        assert str(c["step"]) == str(mine[0]["step"])
+
+
+def test_spans_of_one_request_share_its_rid(traced):
+    spans = traced[0]
+    for rid in range(len(PROMPTS)):
+        mine = [e["name"] for e in spans if str(e.get("rid")) == str(rid)]
+        assert mine[0] == "serving.submit" and mine[1] == "serving.admit"
+        assert set(mine[2:]) == {"serving.prefill_chunk"}
+    chunks = [e for e in spans if e["name"] == "serving.prefill_chunk"]
+    # 21, 9 and 30 tokens in chunks of 16: 2 + 1 + 2, the last of each final
+    assert len(chunks) == 5
+    assert sum(int(e["final"]) for e in chunks) == 3
+
+
+def test_train_spans_carry_their_step(traced):
+    steps = [e for e in traced[0] if e["name"] == "train.step"]
+    assert [int(e["step"]) for e in steps] == [1, 2]
+
+
+def test_every_serving_span_has_its_flight_recorder_event(traced):
+    """One call marks both, so they cannot drift: the same boundaries, the
+    same steps, the same count."""
+    spans, events = traced
+    want = sorted((e["name"][len("serving."):], int(e["step"]))
+                  for e in spans if e["name"].startswith("serving."))
+    kinds = {name[len("serving."):] for name in SPANS
+             if name.startswith("serving.")}
+    got = sorted((e["kind"], e["step"]) for e in events
+                 if e["kind"] in kinds)
+    assert got == want
+    assert all(e["seconds"] >= 0 for e in events if e["kind"] in kinds)
+
+
+def test_final_chunk_is_marked_on_the_timeline():
+    eng = tiny_engine()
+    r = eng.submit(Request(PROMPTS[0], 3))
+    eng.run()
+    chunks = [m for m in r.timeline() if "chunk" in m]
+    assert [m["chunk"] for m in chunks] == [0, 1]
+    assert [m["final"] for m in chunks] == [False, True]
+    first = [m["t"] for m in r.timeline() if m["phase"] == "decoding"][0]
+    assert chunks[-1]["t"] <= r.t_first <= first
+
+
+# (d) tracing changes nothing that is served or learned
+def served(**kw):
+    eng = tiny_engine(**kw)
+    reqs = [eng.submit(Request(p, n)) for p, n in zip(PROMPTS, NEW)]
+    eng.run()
+    return [list(r.output_ids) for r in reqs]
+
+
+def losses(n=3):
+    step, ids = tiny_train_step()
+    return [float(step(ids, ids).numpy()) for _ in range(n)]
+
+
+@pytest.mark.parametrize("pipeline", [True, False])
+def test_served_tokens_do_not_depend_on_instrumentation(pipeline, tmp_path):
+    plain = served(instrument=False, recorder=False, pipeline=pipeline)
+    assert served(pipeline=pipeline) == plain
+    with profiled(tmp_path):
+        assert served(pipeline=pipeline) == plain
+
+
+def test_losses_do_not_depend_on_a_profiler_session(tmp_path):
+    plain = losses()
+    with profiled(tmp_path):
+        assert losses() == plain
+    assert len(set(plain)) == 3
+
+
+def test_scopes_leave_the_programs_operations_unchanged():
+    """Scopes exist at trace time only: with them made no-ops the lowered
+    decode program is the same text, debug locations aside."""
+    import contextlib
+    import unittest.mock
+
+    def text():
+        eng = tiny_engine()
+        rows = jnp.zeros((2,), jnp.int32)
+        # a fresh jit: the module-level one would answer from its cache
+        return jax.jit(
+            serving_decode_steps.__wrapped__.__wrapped__,
+            static_argnames=("cfg", "n_steps", "chunk_size", "program_key"),
+        ).lower(eng._params, eng._cfg, rows, eng._kv.caches, rows,
+                n_steps=1, chunk_size=eng._chunk, block_tables=None,
+                program_key=eng._pk).as_text()
+
+    scoped = text()
+    with unittest.mock.patch.object(
+            jax, "named_scope", lambda name: contextlib.nullcontext()):
+        assert text() == scoped
+
+
+# what the benchmark's train driver and chip_smoke.py used to read through
+# ``step._params`` / ``step._states`` / ``step._jitted``
+def test_train_step_accessors_show_the_live_state():
+    step, ids = tiny_train_step()
+    before = {k: np.asarray(v) for k, v in step.params.items()}
+    assert set(step.optimizer_states) >= {"moment1", "moment2"}
+    assert all(not np.asarray(m).any()
+               for m in step.optimizer_states["moment1"].values())
+    step(ids, ids)
+    after = step.params
+    assert set(after) == set(before)
+    assert any((np.asarray(after[k]) != before[k]).any() for k in before)
+    assert any(np.asarray(m).any()
+               for m in step.optimizer_states["moment1"].values())
+    # read-only views: the mappings are copies, the step keeps its own
+    after.clear()
+    step.optimizer_states["moment1"].clear()
+    assert step.params and step.optimizer_states["moment1"]
+    assert not hasattr(type(step).params, "fset") \
+        or type(step).params.fset is None
+
+
+def test_train_step_lower_is_the_program_the_steps_run():
+    step, ids = tiny_train_step()
+    n0 = step._step_count
+    lowered = step.lower(ids, ids)
+    assert step._step_count == n0          # nothing ran, nothing counted
+    assert "_step_fn" in lowered.as_text().split("\n", 1)[0]
+    loss = float(step(ids, ids).numpy())
+    assert np.isfinite(loss)
