@@ -251,9 +251,14 @@ def _qkv(lp, cfg, h):
     with jax.named_scope("norm"):
         x = _rmsnorm(h, lp["ln1"], eps)
     with jax.named_scope("attn.qkv"):
-        return (_mm(x, lp, "wq").reshape(b, t, nh, hd),
-                _mm(x, lp, "wk").reshape(b, t, nkv, hd),
-                _mm(x, lp, "wv").reshape(b, t, nkv, hd))
+        # the barrier keeps the products 2-D past the dots: with the head
+        # reshape directly behind a dot the TPU compiler folds it in and
+        # then copies wq/wk/wv into the other order on every run of the
+        # program (tests/test_chip_compile.py holds the count at zero)
+        q, k, v = jax.lax.optimization_barrier(
+            (_mm(x, lp, "wq"), _mm(x, lp, "wk"), _mm(x, lp, "wv")))
+        return (q.reshape(b, t, nh, hd), k.reshape(b, t, nkv, hd),
+                v.reshape(b, t, nkv, hd))
 
 
 def _attn_out_mlp(lp, cfg, h, out, tp_overlap):

@@ -16,6 +16,7 @@ only one process may load the TPU library, and every xdist worker imports
 every test file), in this ONE file, in the test's own process.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -184,3 +185,56 @@ def test_fused_adamw_q8_compiles(one_chip):
             sds(shape, jnp.int8), sds((n // 256,), jnp.float32),
             sds(shape, jnp.bfloat16), sds((16,), jnp.float32))
     _compile(functools.partial(fused_adamw_q8, interpret=False), *args)
+
+
+# Mistral-7B-v0.3's widths (the benchmark's serving cells), 2 of its layers
+M_HID, M_INTER, M_NH, M_NKV, M_VOCAB, M_LAYERS = 4096, 14336, 32, 8, 32768, 2
+_COPY = re.compile(r"= \w+\[(\d+),(\d+)\]\S* (?:copy|transpose)\(")
+
+
+def _weight_copies(text):
+    """The ``copy`` / ``transpose`` instructions of a compiled program whose
+    result is a matrix of 2^22 elements or more: a decode weight (``wk``
+    [4096, 1024] is the smallest) brought into another order."""
+    return [m.group(0) for m in _COPY.finditer(text)
+            if int(m.group(1)) * int(m.group(2)) >= 2 ** 22]
+
+
+@pytest.mark.parametrize("batch,lmax", [(32, 2048), (16, 4096)],
+                         ids=["32x2048", "16x4096"])
+@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
+def test_serving_programs_read_weights_as_stored(one_chip, program, batch,
+                                                 lmax):
+    """The decode-steps and prefill-chunk programs at both serving cells'
+    geometries consume every weight in the order it is stored.  A reshape
+    to ``[.., heads, head_dim]`` directly behind the Q/K/V dots made the
+    compiler copy ``wq``, ``wk`` and ``wv`` of every layer into the other
+    order on every run (2.6 ms of a 16.5 ms decode step: PERF.md, PR 27).
+    The K/V cache chunks' own re-layout inside ``attn.core.chunks``
+    (rank 4) is not a weight's and is not counted here."""
+    from paddle_tpu.models import llama_decode as ld
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bf16 = functools.partial(sds, dtype=jnp.bfloat16)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    layer = {"ln1": bf16((M_HID,)), "ln2": bf16((M_HID,)),
+             "wq": bf16((M_HID, M_NH * D)), "wk": bf16((M_HID, M_NKV * D)),
+             "wv": bf16((M_HID, M_NKV * D)), "wo": bf16((M_NH * D, M_HID)),
+             "gate": bf16((M_HID, M_INTER)), "up": bf16((M_HID, M_INTER)),
+             "down": bf16((M_INTER, M_HID))}
+    params = {"embed": bf16((M_VOCAB, M_HID)), "norm": bf16((M_HID,)),
+              "lm_head": bf16((M_HID, M_VOCAB)),
+              "layers": [dict(layer) for _ in range(M_LAYERS)],
+              "_rope": (bf16((lmax, D)), bf16((lmax, D)))}
+    caches = [(bf16((batch, lmax, M_NKV, D)), bf16((batch, lmax, M_NKV, D)))
+              for _ in range(M_LAYERS)]
+    cfg = (M_NH, M_NKV, D, 1e-5)
+    if program == "decode_steps":
+        lowered = ld.serving_decode_steps.__wrapped__.lower(
+            params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
+            chunk_size=256)
+    else:
+        lowered = ld.serving_prefill_chunk.__wrapped__.lower(
+            params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+            i32(()), chunk_size=256)
+    assert _weight_copies(lowered.compile().as_text()) == []

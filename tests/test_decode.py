@@ -556,3 +556,59 @@ class TestSpeculativeDecode:
             decode_speculative(target, ids)
         with _pytest.raises(ValueError, match="input_ids is required"):
             decode_speculative(target, None)
+
+
+class TestQkvProjection:
+    """``_qkv`` (every serving program's Q/K/V projection) is the three
+    plain matmuls reshaped to heads — whatever form it takes to keep the
+    compiler from re-laying-out the weights (tests/test_chip_compile.py)."""
+
+    @pytest.mark.parametrize("leaves", ["bf16", "int8", "bf16-mp4"])
+    def test_equals_three_plain_matmuls_bitwise(self, leaves):
+        from paddle_tpu.models import llama_decode as ld
+
+        hidden, inter, nh, nkv, hd, b, t = 64, 96, 8, 4, 16, 3, 5
+        cfg = (nh, nkv, hd, 1e-5)
+        ks = iter(jax.random.split(jax.random.PRNGKey(3), 9))
+
+        def w(shape):
+            return (jax.random.normal(next(ks), shape, jnp.float32)
+                    * shape[0] ** -0.5).astype(jnp.bfloat16)
+
+        # all seven matmul weights: quantize_decode_weights wants them
+        lp = {"ln1": 1 + w((hidden,)), "wq": w((hidden, nh * hd)),
+              "wk": w((hidden, nkv * hd)), "wv": w((hidden, nkv * hd)),
+              "wo": w((nh * hd, hidden)),
+              "gate": w((hidden, inter)), "up": w((hidden, inter)),
+              "down": w((inter, hidden))}
+        h = jax.random.normal(next(ks), (b, t, hidden),
+                              jnp.float32).astype(jnp.bfloat16)
+        params = {"layers": [lp]}
+        if leaves == "int8":
+            params = ld.quantize_decode_weights(params)
+            assert params["layers"][0]["wq"].dtype == jnp.int8
+        if leaves == "bf16-mp4":
+            from jax.sharding import Mesh
+            from paddle_tpu.serving.sharding import shard_decode_params
+            mesh = Mesh(np.array(jax.devices()[:4]), ("mp",))
+            params, _ = shard_decode_params(params, mesh)
+            assert len(params["layers"][0]["wq"].sharding.device_set) == 4
+
+        def plain(lp, h):
+            x = ld._rmsnorm(h, lp["ln1"], cfg[3])
+            out = []
+            for name, heads in (("wq", nh), ("wk", nkv), ("wv", nkv)):
+                y = x @ lp[name].astype(x.dtype)
+                if name + "_scale" in lp:
+                    y = y * lp[name + "_scale"].astype(x.dtype)
+                out.append(y.reshape(b, t, heads, hd))
+            return tuple(out)
+
+        got = jax.jit(lambda lp, h: ld._qkv(lp, cfg, h))(
+            params["layers"][0], h)
+        want = jax.jit(plain)(params["layers"][0], h)
+        for g, e in zip(got, want):
+            assert g.shape == e.shape and g.dtype == e.dtype
+            np.testing.assert_array_equal(
+                np.asarray(g.astype(jnp.float32)),
+                np.asarray(e.astype(jnp.float32)))
