@@ -19,3 +19,6 @@ from paddle_tpu.models.bert import (  # noqa: F401
     ErnieConfig, ErnieForMaskedLM, ErnieForSequenceClassification, ErnieModel,
 )
 from paddle_tpu.models.moe_llm import MoEConfig, MoEForCausalLM  # noqa: F401
+from paddle_tpu.models.falcon_h1 import (  # noqa: F401
+    FalconH1Config, FalconH1ForCausalLM,
+)

@@ -356,6 +356,12 @@ class LlamaForCausalLM(Layer):
             if config.dtype != "float32":
                 self.lm_head.to(dtype=config.dtype)
 
+    def serving_family(self):
+        """The model seam of ``paddle_tpu.serving.ServingEngine``."""
+        from paddle_tpu.models.llama_decode import LLAMA_FAMILY
+
+        return LLAMA_FAMILY
+
     def forward(self, input_ids, labels=None, attn_mask=None):
         h = self.llama(input_ids, attn_mask)
         if labels is not None and self.config.loss_chunk_size > 0:

@@ -30,6 +30,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.models.serving_family import ServingFamily
 from paddle_tpu.observability.compilecache import CompileCacheMonitor
 from paddle_tpu.ops.decode_attention import (
     _Q8_MAX, _Q8_SCALE_DTYPE, _canon_dtype, _kv_data, decode_attention,
@@ -1064,6 +1065,32 @@ def _decode_params_of(model, lmax):
         _mon.miss("decode_params", seconds=time.perf_counter() - t0)
     return params, (cfg.num_attention_heads, cfg.num_key_value_heads, hd,
                     cfg.rms_norm_eps)
+
+
+def _llama_tp_rules(axis):
+    # serving/sharding.py imports this module: resolve at call time
+    from paddle_tpu.serving.sharding import llama_tp_rules
+
+    return llama_tp_rules(axis)
+
+
+# the model seam's first implementation (models/serving_family.py): the
+# Llama-shaped programs of this module, handed to the engine as a record.
+# One layer's cache is the (k, v) rows pair and nothing else.
+LLAMA_FAMILY = ServingFamily(
+    name="llama",
+    decode_params=_decode_params_of,
+    kv_geometry=lambda cfg: cfg[:3],
+    init_layer_cache=lambda cfg, batch, max_len, kv_dtype: init_kv_cache(
+        batch, max_len, cfg[1], cfg[2], kv_dtype),
+    decode_steps=serving_decode_steps,
+    prefill_chunk=serving_prefill_chunk,
+    prefill_slot=serving_prefill_slot,
+    spec_step=serving_spec_step,
+    spec_draft_step=serving_spec_draft_step,
+    quantize_weights=quantize_decode_weights,
+    tp_rules=_llama_tp_rules,
+)
 
 
 def decode_speculative(model, draft_model=None, input_ids=None,

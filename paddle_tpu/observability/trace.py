@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["span", "chrome_event", "SCOPES", "LOOPS", "SPANS"]
+__all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "LOOPS", "SPANS"]
 
 # model components, the same names in the serving programs
 # (models/llama_decode.py, ops/decode_attention.py) and the training model
@@ -35,6 +35,15 @@ __all__ = ["span", "chrome_event", "SCOPES", "LOOPS", "SPANS"]
 SCOPES = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
           "attn.core", "attn.out", "mlp", "lm_head", "sample", "loss",
           "optimizer")
+# the state-space branch that runs beside the attention branch in a hybrid
+# block (models/falcon_h1.py, ops/ssm.py): ssm.scan is the chunked scan of
+# prefill and of the whole-sequence forward, ssm.state_update the one-token
+# update of decode.  A tuple of its own: ``SCOPES + LOOPS`` is the list the
+# accepted benchmark's reduction holds a copy of (benchmark/lib/
+# span_reduce.NAMES); the cell that runs these reduces with both
+# (benchmark/lib/falcon_h1_reduce.py).
+STATE_SCOPES = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.state_update",
+                "ssm.norm_gate", "ssm.out")
 # the compiled loops, so that a %while in a device trace can be told: the
 # n_steps scan of the decode program and the cache-chunk loop of the
 # chunked attention read

@@ -101,11 +101,8 @@ import numpy as np
 import jax.numpy as jnp
 from jax.errors import JaxRuntimeError
 
-from paddle_tpu.models.llama_decode import (
-    _canon_weight_dtype, _decode_params_of, quantize_decode_weights,
-    serving_decode_steps, serving_prefill_chunk, serving_prefill_slot,
-    serving_spec_draft_step, serving_spec_step,
-)
+from paddle_tpu.models.llama_decode import _canon_weight_dtype
+from paddle_tpu.models.serving_family import family_of
 from paddle_tpu.observability.flightrecorder import (
     FlightRecorder, RequestTrace,
 )
@@ -713,8 +710,22 @@ class ServingEngine:
             self._chunk = kv_block
         elif max_live_tokens is not None:
             raise ValueError("max_live_tokens requires kv_block (paged KV)")
-        self._params, self._cfg = _decode_params_of(model, self._lmax)
-        nh, nkv, hd, eps = self._cfg
+        # the model seam (models/serving_family.py): everything the engine
+        # knows of the architecture — its weights pytree, its compiled
+        # programs, its cache leaves, its partition rules — is read off
+        # this one record; options the family cannot serve raise here
+        fam = self._fam = family_of(model)
+        self._params, self._cfg = fam.decode_params(model, self._lmax)
+        nh, nkv, hd = fam.kv_geometry(self._cfg)
+        fam.check_options(dict(
+            cfg=self._cfg, mode=mode, kv_block=kv_block, mesh=mesh,
+            kv_dtype=kv_dtype, weight_dtype=weight_dtype,
+            attn_impl=attn_impl, prefill_impl=prefill_impl,
+            tp_overlap=tp_overlap, prefill_chunk=self._pchunk))
+        if self._pchunk is None and fam.prefill_slot is None:
+            raise ValueError(
+                f"ServingEngine: the {fam.name} family has no monolithic "
+                "prefill program — prefill_chunk= is required")
         # kv_dtype: cache STORAGE dtype override.  None keeps the model
         # dtype (bitwise the pre-quantization engine — kv_dtype simply
         # never enters the program identity as a non-None static).
@@ -776,10 +787,14 @@ class ServingEngine:
         self._w8 = self._weight_dtype == "int8"
         self._wq_label = "int8" if self._w8 else "off"
         if self._w8:
+            if fam.quantize_weights is None:
+                raise ValueError(
+                    f"ServingEngine: the {fam.name} family has no int8 "
+                    "weight quantizer (weight_dtype=)")
             # quantize AFTER the model cache handed us its pytree (a fresh
             # dict — the cache entry itself is never mutated) and BEFORE
             # any mesh placement so the int8 leaves shard directly
-            self._params = quantize_decode_weights(
+            self._params = fam.quantize_weights(
                 self._params, self._weight_dtype)
         # resident draft model (SpecConfig source="draft_model"): its
         # decode pytree lives alongside the target's and rides the same
@@ -791,9 +806,15 @@ class ServingEngine:
         self._dparams = self._dcfg = None
         self._dcaches = None
         if self._dspec:
-            self._dparams, self._dcfg = _decode_params_of(
+            if family_of(spec.draft_model) is not fam \
+                    or fam.spec_draft_step is None:
+                raise ValueError(
+                    "SpecConfig(source='draft_model'): the draft model "
+                    f"must be of the target's serving family ({fam.name}) "
+                    "and the family must have a draft-step program")
+            self._dparams, self._dcfg = fam.decode_params(
                 spec.draft_model, self._lmax)
-            dnh, dnkv, dhd, _ = self._dcfg
+            dnh, dnkv, dhd = fam.kv_geometry(self._dcfg)
             if int(self._dparams["embed"].shape[0]) \
                     != int(self._params["embed"].shape[0]):
                 raise ValueError(
@@ -824,7 +845,7 @@ class ServingEngine:
                         f"{self._dparams['embed'].dtype} != target "
                         f"{self._params['embed'].dtype}")
             if self._w8:
-                self._dparams = quantize_decode_weights(
+                self._dparams = fam.quantize_weights(
                     self._dparams, self._weight_dtype)
         # the declarative program identity: every static kernel/precision
         # knob flows through this ONE frozen registry value — the four
@@ -875,16 +896,21 @@ class ServingEngine:
             from paddle_tpu.serving.sharding import (
                 shard_decode_params, serving_tp_programs)
             n = mesh_devices
+            if fam.tp_rules is None:
+                raise ValueError(
+                    f"ServingEngine: the {fam.name} family has no "
+                    "tensor-parallel rule set (mesh=)")
             if nkv % n or nh % n:
                 raise ValueError(
                     f"heads not shardable {n}-way along {tp_axis!r}: "
                     f"num_attention_heads={nh}, num_key_value_heads={nkv} "
                     f"(the KV cache shards along heads)")
             self._params, pspecs = shard_decode_params(
-                self._params, mesh, axis=tp_axis)
+                self._params, mesh, axis=tp_axis,
+                rules=fam.tp_rules(tp_axis))
             dspecs = None
             if self._dspec:
-                dnh, dnkv, _, _ = self._dcfg
+                dnh, dnkv, _ = fam.kv_geometry(self._dcfg)
                 if dnkv % n or dnh % n:
                     raise ValueError(
                         f"draft heads not shardable {n}-way along "
@@ -892,7 +918,8 @@ class ServingEngine:
                         f"num_key_value_heads={dnkv} (the draft KV "
                         "shards along heads like the target's)")
                 self._dparams, dspecs = shard_decode_params(
-                    self._dparams, mesh, axis=tp_axis)
+                    self._dparams, mesh, axis=tp_axis,
+                    rules=fam.tp_rules(tp_axis))
             d_layers = (len(self._dparams["layers"]) if self._dspec
                         else 0)
             self._tp = serving_tp_programs(
@@ -956,24 +983,31 @@ class ServingEngine:
             self._kv = KVCacheManager(
                 len(self._params["layers"]), self._B, self._lmax, nkv, hd,
                 dtype, sharding=cache_sharding,
-                scale_sharding=scale_sharding)
+                scale_sharding=scale_sharding,
+                init_layer=lambda: fam.init_layer_cache(
+                    self._cfg, self._B, self._lmax, dtype))
             if self._dspec:
                 # dense draft tenancy: a SEPARATE per-draft-layer cache
                 # list (dense rows are slot-indexed — cohabitation in the
                 # target's arrays would clobber it), same storage dtype
                 # rules and head sharding as the target's
-                from paddle_tpu.ops.decode_attention import init_kv_cache
                 from paddle_tpu.serving.kv_cache import _place_caches
-                _, dnkv, dhd, _ = self._dcfg
                 ddtype = (self._kv_dtype if self._kv_dtype is not None
                           else self._dparams["embed"].dtype)
                 self._dcaches = [
-                    init_kv_cache(self._B, self._lmax, dnkv, dhd, ddtype)
+                    fam.init_layer_cache(self._dcfg, self._B, self._lmax,
+                                         ddtype)
                     for _ in range(len(self._dparams["layers"]))]
                 if cache_sharding is not None:
                     self._dcaches = _place_caches(
                         self._dcaches, cache_sharding, scale_sharding)
+        # recurrent state beside the K/V rows (a family's state_leaves):
+        # resident bytes for the serving_state_bytes gauge
+        self._state_idx = tuple(leaf.index for leaf in fam.state_leaves)
         if self._m is not None:
+            self._m.state_bytes.set(sum(
+                int(layer[i].nbytes) for layer in self._kv.caches
+                for i in self._state_idx))
             self._m.set_kv_quant(self._kvq)
             self._m.set_decode_kernel(self._attn_label)
             self._m.set_prefill_kernel(self._prefill_label)
@@ -1384,6 +1418,16 @@ class ServingEngine:
         in the SCALE leaf instead (same row indices minus the trailing
         ``D`` axis): a NaN scale dequantizes the row to NaN, which
         reaches the logits exactly like a NaN float row."""
+        if self._state_idx:
+            # a model with recurrent state beside its K/V rows: poison the
+            # slot's first state leaf instead — every later token of the
+            # slot reads it, and the quarantine has to cover it too
+            layer = list(self._kv.caches[0])
+            i = self._state_idx[0]
+            layer[i] = layer[i].at[(slot,) + (0,) * (layer[i].ndim - 1)] \
+                .set(jnp.nan)
+            self._kv.caches[0] = tuple(layer)
+            return
         k, v = self._kv.caches[0]
 
         def poison(leaf, *idx):
@@ -1559,7 +1603,7 @@ class ServingEngine:
                                              self._tables())
             return self._tp.decode_steps(self._params, cur,
                                          self._kv.caches, dev_len)
-        return serving_decode_steps(
+        return self._fam.decode_steps(
             self._params, self._cfg, cur, self._kv.caches, dev_len,
             n_steps=self._sync, chunk_size=self._chunk,
             block_tables=self._tables() if self._paged else None,
@@ -1588,7 +1632,7 @@ class ServingEngine:
                         self._params, self._dparams, cur, self._kv.caches,
                         self._dcaches, dev_len, active)
             else:
-                out = serving_spec_draft_step(
+                out = self._fam.spec_draft_step(
                     self._params, self._dparams, self._cfg, self._dcfg,
                     cur, self._kv.caches,
                     None if self._paged else self._dcaches, dev_len,
@@ -1612,7 +1656,7 @@ class ServingEngine:
             return tp.spec_step(self._params, cur, self._kv.caches,
                                 dev_len, self._hist, self._hist_len,
                                 active)
-        return serving_spec_step(
+        return self._fam.spec_step(
             self._params, self._cfg, cur, self._kv.caches, dev_len,
             self._hist, self._hist_len, active, spec_k=k,
             chunk_size=self._chunk,
@@ -1624,7 +1668,7 @@ class ServingEngine:
             return self._tp.prefill_slot(self._params, tokens, prompt_len,
                                          self._kv.caches, slot,
                                          self._hist, self._hist_len)
-        return serving_prefill_slot(
+        return self._fam.prefill_slot(
             self._params, self._cfg, tokens, prompt_len, self._kv.caches,
             slot, hist=self._hist, hist_len=self._hist_len,
             with_hist=self._mode == "spec", chunk_size=self._chunk,
@@ -1641,7 +1685,7 @@ class ServingEngine:
             return self._tp.prefill_chunk(self._params, tokens, offset,
                                           prompt_len, self._kv.caches,
                                           slot, self._hist, self._hist_len)
-        return serving_prefill_chunk(
+        return self._fam.prefill_chunk(
             self._params, self._cfg, tokens, offset, prompt_len,
             self._kv.caches, slot, hist=self._hist,
             hist_len=self._hist_len, with_hist=self._mode == "spec",
@@ -1673,7 +1717,7 @@ class ServingEngine:
                     jnp.asarray(off, jnp.int32), plen,
                     self._dcaches, jnp.asarray(slot, jnp.int32))
         else:
-            _, _, new_dc, _, _ = serving_prefill_chunk(
+            _, _, new_dc, _, _ = self._fam.prefill_chunk(
                 self._dparams, self._dcfg, jnp.asarray(chunk),
                 jnp.asarray(off, jnp.int32), plen,
                 self._kv.caches[:d] if self._paged else self._dcaches,
@@ -2126,6 +2170,11 @@ class ServingEngine:
                             self._kv.ensure_rows(
                                 slot, min(st["off"] + P, st["p"]))
                         chunk = st["tok"][st["off"]:st["off"] + P][None, :]
+                        if (m is not None and self._state_idx
+                                and st["off"] == 0):
+                            # the family's program resets the slot's
+                            # recurrent state inside this chunk
+                            m.state_resets.inc()
                         first, okf, self._kv.caches, hist, hist_len = \
                             self._call_prefill_chunk(
                                 jnp.asarray(chunk),

@@ -354,12 +354,18 @@ class KVCacheManager:
     """Slot allocator + KV-cache owner for one fixed-batch engine."""
 
     def __init__(self, n_layers, batch_size, max_len, num_kv_heads,
-                 head_dim, dtype, sharding=None, scale_sharding=None):
+                 head_dim, dtype, sharding=None, scale_sharding=None,
+                 init_layer=None):
+        """``init_layer()`` makes ONE layer's cache leaves (a serving
+        family's ``init_layer_cache``: the (k, v) rows pair first, then
+        whatever per-slot state the architecture carries beside them);
+        ``None`` is the rows pair alone."""
         self.batch_size = int(batch_size)
         self.max_len = int(max_len)
-        caches = [init_kv_cache(self.batch_size, self.max_len,
-                                num_kv_heads, head_dim, dtype)
-                  for _ in range(n_layers)]
+        if init_layer is None:
+            init_layer = lambda: init_kv_cache(
+                self.batch_size, self.max_len, num_kv_heads, head_dim, dtype)
+        caches = [init_layer() for _ in range(n_layers)]
         if sharding is not None:
             caches = _place_caches(caches, sharding, scale_sharding)
         self.caches = caches

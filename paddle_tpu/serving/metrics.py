@@ -164,6 +164,16 @@ class EngineMetrics:
             "serving_prefill_chunks_total",
             "prompt chunks dispatched by the chunked-prefill path",
             L).labels(**lbl)
+        # recurrent state beside the K/V rows (a serving family's
+        # state_leaves; 0 and never bumped for a model that has none)
+        self.state_bytes = reg.gauge(
+            "serving_state_bytes",
+            "bytes of per-slot recurrent state (SSM state, conv tails) "
+            "resident beside the K/V cache", L).labels(**lbl)
+        self.state_resets = reg.counter(
+            "serving_state_resets_total",
+            "slot admissions whose first prefill chunk zeroed the slot's "
+            "recurrent state", L).labels(**lbl)
         self.prefill_backlog = reg.gauge(
             "serving_prefill_backlog",
             "prompt chunks still to dispatch across slots mid-prefill",

@@ -152,12 +152,13 @@ def kv_transfer_shardings(mesh, axis="mp"):
             NamedSharding(mesh, kv_scale_pspec(axis)))
 
 
-def _tp_geometry_check(params, mesh, axis):
+def _tp_geometry_check(params, mesh, axis, rules=None):
     """Every sharded dimension must divide by the mesh axis size — an
     indivisible placement would silently pad on some backends and raise on
     others; fail loudly at engine construction instead."""
     n = int(mesh.shape[axis])
-    specs = match_partition_rules(llama_tp_rules(axis), params)
+    specs = match_partition_rules(
+        rules if rules is not None else llama_tp_rules(axis), params)
     bad = []
 
     def chk(path, leaf, spec):
@@ -175,13 +176,14 @@ def _tp_geometry_check(params, mesh, axis):
     return specs
 
 
-def shard_decode_params(params, mesh, axis="mp"):
-    """Place the decode params pytree onto ``mesh`` under the llama TP
-    rules (validated for divisibility).  Returns ``(sharded_params,
+def shard_decode_params(params, mesh, axis="mp", rules=None):
+    """Place the decode params pytree onto ``mesh`` under ``rules`` (a
+    serving family's ``tp_rules(axis)``; default: the llama TP rules),
+    validated for divisibility.  Returns ``(sharded_params,
     specs)`` — a one-time placement at engine construction; after it the
     sharded jits consume the weights in place with zero per-step
     transfers."""
-    specs = _tp_geometry_check(params, mesh, axis)
+    specs = _tp_geometry_check(params, mesh, axis, rules)
     sharded = jax.tree_util.tree_map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         params, specs)

@@ -238,3 +238,62 @@ def test_serving_programs_read_weights_as_stored(one_chip, program, batch,
             params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
             i32(()), chunk_size=256)
     assert _weight_copies(lowered.compile().as_text()) == []
+
+
+# Falcon-H1-34B's widths (the benchmark's falconh1_chat_short cell), 2 of
+# its blocks at the cell's geometry
+@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
+def test_state_space_serving_programs_compile_in_place(one_chip, program):
+    """The two Falcon-H1 serving programs compile for the chip at published
+    widths, read every weight in the order it is stored (the Q/K/V barrier
+    of ``falcon_h1.attn_qkv``), and update the float32 recurrent state
+    ``[64, 32, 128, 256]`` in place: no copy of a state leaf (4 of them
+    would be the cell's 1.6 GB again)."""
+    from paddle_tpu.models import falcon_h1_decode as fd
+    from paddle_tpu.models.falcon_h1 import FalconH1Config, statics_of
+
+    batch, lmax, layers = 64, 1024, 2
+    c = FalconH1Config(num_hidden_layers=layers)
+    cfg = statics_of(c)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bf16 = functools.partial(sds, dtype=jnp.bfloat16)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    h, inter = c.hidden_size, c.intermediate_size
+    qd, kvd = cfg.heads * D, cfg.kv_heads * D
+    layer = {"ln1": bf16((h,)), "ln2": bf16((h,)), "wq": bf16((h, qd)),
+             "wk": bf16((h, kvd)), "wv": bf16((h, kvd)), "wo": bf16((qd, h)),
+             "w_in": bf16((h, sum(cfg.segments))),
+             "conv_w": bf16((cfg.d_conv, cfg.conv_channels)),
+             "conv_b": bf16((cfg.conv_channels,)),
+             "dt_bias": bf16((cfg.ssm_heads,)),
+             "a_log": bf16((cfg.ssm_heads,)), "d": bf16((cfg.ssm_heads,)),
+             "norm_w": bf16((cfg.d_ssm,)), "w_out": bf16((cfg.d_ssm, h)),
+             "gate": bf16((h, inter)), "up": bf16((h, inter)),
+             "down": bf16((inter, h))}
+    params = {"embed": bf16((c.vocab_size, h)), "norm": bf16((h,)),
+              "lm_head": bf16((h, c.vocab_size)),
+              "layers": [dict(layer) for _ in range(layers)],
+              "_rope": (bf16((lmax, D)), bf16((lmax, D)))}
+    state = (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state)
+    caches = [(bf16((batch, lmax, cfg.kv_heads, D)),
+               bf16((batch, lmax, cfg.kv_heads, D)),
+               sds(state, jnp.float32),
+               bf16((batch, cfg.d_conv - 1, cfg.conv_channels)))
+              for _ in range(layers)]
+    if program == "decode_steps":
+        lowered = fd.serving_decode_steps.__wrapped__.lower(
+            params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
+            chunk_size=256)
+    else:
+        lowered = fd.serving_prefill_chunk.__wrapped__.lower(
+            params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+            i32(()), chunk_size=256)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert _weight_copies(text) == []
+    leaf = "f32[%d,%d,%d,%d]" % state
+    assert [ln for ln in text.split("\n")
+            if leaf in ln.split("=")[-1][:60] and " copy(" in ln] == []
+    # donated caches are updated in place: the temporaries stay far under
+    # one state leaf (268 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20

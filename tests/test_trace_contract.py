@@ -15,11 +15,15 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.models import falcon_h1_decode
+from paddle_tpu.models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.llama_decode import (
     serving_decode_steps, serving_prefill_chunk,
 )
-from paddle_tpu.observability.trace import LOOPS, SCOPES, SPANS
+from paddle_tpu.observability.trace import (
+    LOOPS, SCOPES, SPANS, STATE_SCOPES,
+)
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.static.functionalize import build_train_step
 
@@ -30,13 +34,21 @@ from _xplane import host_events, inside, profiled
 READER_PATTERNS = ("flash", "_step_fn", "serving_decode_steps",
                    "serving_prefill_chunk")
 MODULE_NAMES = {"decode": "serving_decode_steps",
-                "prefill": "serving_prefill_chunk", "train": "_step_fn"}
+                "prefill": "serving_prefill_chunk", "train": "_step_fn",
+                "ssm_decode": "serving_decode_steps",
+                "ssm_prefill": "serving_prefill_chunk"}
 SERVING = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
            "attn.core", "attn.out", "mlp", "lm_head", "sample",
            "attn.core.chunks")
+# the state-space branch beside the attention branch (models/falcon_h1.py):
+# the one-token update in the decode program, the chunked scan in prefill
+SSM = tuple(n for n in STATE_SCOPES
+            if n not in ("ssm.scan", "ssm.state_update"))
 APPLIES = {
     "decode": SERVING + ("decode.steps",),
     "prefill": SERVING,
+    "ssm_decode": SERVING + SSM + ("decode.steps", "ssm.state_update"),
+    "ssm_prefill": SERVING + SSM + ("ssm.scan",),
     "train": ("embed", "norm", "attn.qkv", "attn.rope", "attn.core",
               "attn.out", "mlp", "lm_head", "loss", "optimizer"),
 }
@@ -58,6 +70,14 @@ def tiny_engine(**kw):
                          prefill_chunk=16, decode_chunk=16, **kw)
 
 
+def tiny_ssm_engine(**kw):
+    paddle.seed(0)
+    model = FalconH1ForCausalLM(FalconH1Config.tiny())
+    model.eval()
+    return ServingEngine(model, batch_size=2, max_len=64, prefill_chunk=16,
+                         decode_chunk=16, **kw)
+
+
 def tiny_train_step(seed=0):
     paddle.seed(seed)
     model = LlamaForCausalLM(LlamaConfig.tiny(
@@ -75,7 +95,19 @@ def lowered():
     eng = tiny_engine()
     rows, scalar = jnp.zeros((2,), jnp.int32), jnp.int32(0)
     step, ids = tiny_train_step()
+    ssm = tiny_ssm_engine()
     return {
+        "ssm_decode": falcon_h1_decode.serving_decode_steps.__wrapped__.lower(
+            ssm._params, ssm._cfg, rows, ssm._kv.caches, rows,
+            n_steps=ssm._sync, chunk_size=ssm._chunk, block_tables=None,
+            program_key=ssm._pk),
+        "ssm_prefill":
+            falcon_h1_decode.serving_prefill_chunk.__wrapped__.lower(
+                ssm._params, ssm._cfg, jnp.zeros((1, 16), jnp.int32), scalar,
+                jnp.zeros((1,), jnp.int32), ssm._kv.caches, scalar,
+                hist=None, hist_len=None, with_hist=False,
+                chunk_size=ssm._chunk, block_tables=None,
+                program_key=ssm._pk),
         "decode": serving_decode_steps.__wrapped__.lower(
             eng._params, eng._cfg, rows, eng._kv.caches, rows,
             n_steps=eng._sync, chunk_size=eng._chunk, block_tables=None,
@@ -122,7 +154,7 @@ def test_scope_names_the_compiled_operations(op_names, program, name):
 @pytest.mark.parametrize("program", sorted(APPLIES))
 def test_program_carries_no_name_outside_its_list(op_names, program):
     found = {c for path in op_names[program] for c in components(path)
-             if c in SCOPES + LOOPS}
+             if c in SCOPES + STATE_SCOPES + LOOPS}
     assert found == set(APPLIES[program])
 
 
@@ -130,9 +162,10 @@ def test_loops_are_named(op_names):
     """A %while of a device trace can be told: the cache-chunk loop's own
     ``while`` sits under its name, and the step's operations under the
     scan's (at ``sync_every=1`` XLA takes the one-trip loop itself away)."""
-    assert any("decode.steps/while/body/" in path
-               for path in op_names["decode"])
-    for program in ("decode", "prefill"):
+    for program in ("decode", "ssm_decode"):
+        assert any("decode.steps/while/body/" in path
+                   for path in op_names[program])
+    for program in ("decode", "prefill", "ssm_decode", "ssm_prefill"):
         assert any(path.endswith("attn.core.chunks/while")
                    for path in op_names[program])
 
@@ -146,14 +179,29 @@ def test_backward_and_recompute_keep_the_forwards_names(op_names):
                for p in paths)
 
 
-@pytest.mark.parametrize("name", SCOPES + LOOPS + SPANS)
+@pytest.mark.parametrize("name", SCOPES + STATE_SCOPES + LOOPS + SPANS)
 def test_vocabulary_avoids_the_readers_patterns(name):
     assert not any(pat in name or name in pat for pat in READER_PATTERNS)
     assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)*", name)
 
 
 def test_applies_covers_the_vocabulary():
-    assert set().union(*map(set, APPLIES.values())) == set(SCOPES + LOOPS)
+    assert set().union(*map(set, APPLIES.values())) \
+        == set(SCOPES + STATE_SCOPES + LOOPS)
+
+
+def test_state_counters_are_the_names_the_readers_ask_for():
+    """``serving_state_bytes`` / ``serving_state_resets_total``: what a
+    model with recurrent state beside its K/V rows adds to the registry."""
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = tiny_ssm_engine(registry=reg)
+    eng.submit(Request(PROMPTS[0], 3))
+    eng.run()
+    lbl = dict(policy="continuous")
+    assert reg.get("serving_state_bytes").labels(**lbl).value > 0
+    assert reg.get("serving_state_resets_total").labels(**lbl).value == 1
 
 
 # (c) the host spans, on the profiler's timeline
