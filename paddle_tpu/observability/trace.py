@@ -25,7 +25,8 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "LOOPS", "SPANS"]
+__all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "LOOPS", "SPANS",
+           "COUNTERS"]
 
 # model components, the same names in the serving programs
 # (models/llama_decode.py, ops/decode_attention.py) and the training model
@@ -54,6 +55,17 @@ SPANS = ("serving.submit", "serving.step", "serving.admit",
          "serving.spend_prefill", "serving.prefill_chunk",
          "serving.dispatch", "serving.drain", "serving.drain.wait",
          "serving.emit", "train.step")
+
+# the engine's counters and gauges that a measurement reads
+# (serving/metrics.py): steps, tokens and prefill chunks (the benchmark's
+# decode_batch_mean and window record), the recurrent state beside the K/V
+# rows, and the decode cache read against the rows it needs — one layer's
+# count at each decode dispatch's host lengths by the read's own rule
+# (ops.decode_attention.kv_rows_read); read / live is the over-read
+COUNTERS = ("serving_steps_total", "serving_tokens_emitted_total",
+            "serving_prefill_chunks_total", "serving_state_bytes",
+            "serving_state_resets_total", "serving_kv_rows_read_total",
+            "serving_kv_rows_live_total")
 
 SPAN_EVENT_TYPE = "Span"
 

@@ -15,21 +15,33 @@ TPU-first design choices:
   block table — ``init_kv_pool``).  The block table is a TRACED int32
   operand, so appending a block mid-stream or remapping a slot to shared
   prefix blocks changes only operand VALUES, never shapes: zero retraces.
-* **Length-adaptive chunked reads.**  Decode is HBM-bandwidth-bound (a GEMV
-  per head against the cache), so KV bytes ARE the step time — and a masked
-  full-length read pays ``Lmax`` bytes for a request at context 200 in an
-  ``Lmax=4096`` engine: 20× the traffic it needs.  ``chunk_size`` switches
-  the attention read to an online-softmax (flash-style running max /
-  denominator) ``lax.while_loop`` over ``[C]``-sized cache chunks whose trip
-  count is ``ceil((max(live lengths) + T) / C)`` computed ON DEVICE — the
-  compiled program is still traced exactly once (the trip count is a traced
-  scalar, not a shape), but fully-masked tail chunks are never read, so HBM
-  traffic per step is proportional to the longest LIVE context in the
-  batch, not ``Lmax``.  Retired serving slots (parked at offset ``lmax`` by
-  ``masked_lengths``) are excluded from the trip-count max, so one parked
-  slot never forces full-length reads.  ``chunk_size=None`` (default) keeps
-  the single fused full-length read — still optimal when contexts sit near
-  ``Lmax`` or the cache is small.
+* **Chunked reads that follow each slot's own length.**  Decode is
+  HBM-bandwidth-bound (a GEMV per head against the cache), so KV bytes ARE
+  the step time — and a masked full-length read pays ``Lmax`` bytes for a
+  request at context 200 in an ``Lmax=4096`` engine: 20× the traffic it
+  needs.  ``chunk_size`` switches the attention read to an online-softmax
+  (flash-style running max / denominator) ``lax.while_loop`` over
+  ``[C]``-sized cache chunks whose trips are computed ON DEVICE from
+  ``lengths`` — the compiled program is still traced exactly once (a trip
+  count is a traced scalar, not a shape), and there is ONE loop a call.
+  A serving batch is ragged twice over: half its slots are parked
+  (``masked_lengths``) and the live contexts spread over a factor of ten.
+  For the dense ``blhd`` float cache without a bias — what the serving
+  engine runs by default — a trip therefore gathers chunk ``i`` of one
+  block of 4-8 slots only, taken in descending order of length, and a
+  chunk index gets only as many trips as hold a slot whose context
+  reaches it (``_attend_chunked``): HBM traffic per step follows
+  ``ceil((lengths[b] + T) / C)`` chunks for a live slot and none for a
+  parked one, rounded up to the block — 1.2-1.7 × the live rows where one
+  trip over all ``B`` slots up to the batch's longest live context read
+  4-5 × (``kv_rows_read`` is the host-side count of the same rule; the
+  engine's ``serving_kv_rows_*_total``).  Per row the recurrence is the
+  same chunks in the same order, and a chunk past a row's length is
+  exactly a no-op, so live rows are BITWISE those of the batch-wide loop —
+  which paged and int8 caches, ``bhld``, a bias and batches of a block or
+  less (a prefill chunk's one-slot view) still run, unchanged.
+  ``chunk_size=None`` (default) keeps the single fused full-length read —
+  still optimal when contexts sit near ``Lmax`` or the cache is small.
 * **int8 cache, float math.**  ``dtype="int8"`` in ``init_kv_cache`` /
   ``init_kv_pool`` stores KV quantized (symmetric absmax over ``D``, one
   float16 scale per (position, head) row in a parallel pytree leaf) —
@@ -63,9 +75,10 @@ TPU-first design choices:
   pool ``[N, C, Hkv, D]``), so ``kv_cache_pspec`` covers either one
   unchanged — and these reads partition cleanly: the chunked
   online-softmax running max/denominator reduce over the per-head chunk
-  axis, never across heads; the trip count reduces over the (replicated)
-  ``lengths``; and the paged block-table gather indexes only the
-  unsharded pool axis 0 with a replicated table — so GSPMD runs the
+  axis, never across heads; the slot order and the trip counts come from
+  the (replicated) ``lengths``; and the per-slot row gather (dense) and
+  the paged block-table gather index only the unsharded slot / pool axis
+  0 with replicated indices — so GSPMD runs the
   identical program per shard on ``Hkv/N`` heads with zero cross-chip
   collectives inside the attention read.  Keep it that way: any future
   reduction ACROSS the head axis (head-mixing, cross-head norm) breaks
@@ -79,9 +92,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["init_kv_cache", "init_kv_pool", "decode_attention",
-           "masked_lengths", "slot_prefill_attention"]
+           "masked_lengths", "slot_prefill_attention", "kv_rows_read"]
 
 _NEG_INF = -1e30
 
@@ -310,6 +324,82 @@ def _attend_full(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
         preferred_element_type=jnp.float32)
 
 
+def _slot_block(batch):
+    """Slots a trip of the per-slot read covers (the geometry the serving
+    cells run: a dense ``blhd`` float cache and no bias): a block of 8
+    slots, or a quarter of a batch under 32 and never fewer than 4,
+    whenever the block divides a larger batch.  A function of the static
+    batch alone (one compiled program a geometry).  ``None``: the whole
+    batch — one trip over every slot, the batch-wide loop.
+
+    Why these sizes (the read alone on a v5e, PERF.md PR 30): a trip costs
+    about 4 us plus the bytes it gathers, and a gather of fewer slot
+    windows moves its bytes more slowly (545 GB/s for the whole batch's
+    slice, 450 / 345 / 263 for blocks of 8 / 4 / 2), while a larger block
+    fetches more chunks that are dead for some of its slots.  32 x 2048:
+    blocks of 8 read in 1.5-2.1 ms a step what 4 read in 1.9-2.1 and the
+    batch-wide loop in 4.0-4.9; 16 x 4096: 4 in 1.4-1.6, 8 in 2.1-2.4,
+    batch-wide 3.6-4.1; 64 x 1024 (4 KV heads): 8 in 0.8-0.9, 16 in
+    0.9-1.1, batch-wide 1.2."""
+    rows = min(8, max(4, batch // 4))
+    return rows if batch > rows and batch % rows == 0 else None
+
+
+def _chunks_needed(lengths, t, c, lmax, xp=jnp):
+    """Cache chunks each slot's read needs: ``ceil((length + T) / C)`` for
+    a live slot, none for one parked by ``masked_lengths`` (offset >=
+    lmax).  ``xp`` is ``jax.numpy`` inside the program and ``numpy`` for
+    the host's count of the same rule (``kv_rows_read``)."""
+    n_chunks = -(-lmax // c)
+    need = xp.minimum((lengths + (t + c - 1)) // c, n_chunks)
+    return xp.where(lengths < lmax, need, 0)
+
+
+def _slot_order(need, n_chunks):
+    """The per-slot read's order of the slots: descending by the chunks
+    they need, ties by slot index, so that chunk ``i`` is needed by the
+    first ``cnt[i]`` places and by no other.  Returns ``(order [B], place
+    [B], cnt [n_chunks])``: the slot at each place, each slot's place, and
+    those counts.  Two small sorts and a compare of ``lengths``, the same
+    for every layer of a step."""
+    order = jnp.argsort(-need, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)
+    chunk = jnp.arange(n_chunks, dtype=jnp.int32)
+    cnt = jnp.sum(need[None, :] > chunk[:, None], axis=1, dtype=jnp.int32)
+    return order, place, cnt
+
+
+def _permute(x, index):
+    """``x[index]`` along axis 0 for a permutation ``index``: in bounds by
+    construction, so the gather carries no clamp and no fill."""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+def kv_rows_read(lengths, t, chunk, lmax, plain=True):
+    """Host-side count (numpy) of the cache rows ONE layer's read touches
+    for these pre-append ``lengths`` (parked slots at ``>= lmax``), by the
+    rule the read itself runs (``_attend_dispatch``): the full read
+    (``chunk`` None or ``>= lmax``) touches every row; the batch-wide
+    chunked loop every slot up to the longest live context; the per-slot
+    read (``plain``: a dense ``blhd`` float cache, no bias) blocks of
+    ``_slot_block`` slots in descending order of need, only as many as
+    hold a slot that needs the chunk.  Returns ``(rows read, live rows)``;
+    the serving engine feeds both to its counters at every decode
+    dispatch."""
+    lengths = np.asarray(lengths, np.int64)
+    b = lengths.shape[0]
+    live = int(np.sum(np.where(lengths < lmax, lengths + t, 0)))
+    if chunk is None or int(chunk) >= lmax:
+        return b * lmax, live
+    c = int(chunk)
+    need = _chunks_needed(lengths, t, c, lmax, xp=np)
+    rows = _slot_block(b) if plain else None
+    if rows is None:
+        return max(int(need.max(initial=0)), 1) * b * c, live
+    cnt = (need[None, :] > np.arange(-(-lmax // c))[:, None]).sum(axis=1)
+    return int(np.sum(-(-cnt // rows))) * rows * c, live
+
+
 def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
                     attn_bias, chunk, block_table=None):
     """Online-softmax ``lax.while_loop`` over [C]-sized cache chunks.
@@ -317,15 +407,30 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
     Flash-style running (max, denominator, accumulator) carry; exact (not
     approximate) — the recurrence rescales previous partial sums by
     ``exp(m_old - m_new)`` so the result equals the full-read softmax up to
-    float reassociation.  The trip count is a TRACED scalar
-    ``ceil((max(live lengths) + T) / C)``: same compiled program every step
-    (no retraces), but chunks past the longest live context are never
-    read — HBM traffic tracks the batch's real context, not Lmax.  Slots
-    parked by ``masked_lengths`` (offset >= lmax) are excluded from the
-    trip-count max; their rows compute garbage (ignored by the scheduler)
-    over whatever chunks DO run, which keeps every row's softmax finite.
-    ``lmax % C != 0`` is handled by clamping the tail chunk's start to
-    ``lmax - C`` and masking the re-read overlap out of the tail pass.
+    float reassociation.  The trip count is a TRACED scalar: same compiled
+    program every step (no retraces), but chunks past a context are never
+    read — HBM traffic tracks the real contexts, not Lmax.  Slots parked by
+    ``masked_lengths`` (offset >= lmax) need no chunk; their rows compute
+    garbage (ignored by the scheduler) over whatever chunks they DO ride
+    in, or come back 0 — finite either way.  ``lmax % C != 0`` is handled
+    by clamping the tail chunk's start to ``lmax - C`` and masking the
+    re-read overlap out of the tail pass.
+
+    **Which slots a trip reads** (``_slot_block``).  A chunk that lies
+    wholly past a slot's length folds in ``p = 0``, ``corr = exp(m - m) =
+    1``: bit for bit nothing.  So a slot's result depends only on ITS
+    chunks ``0 .. ceil((length + T) / C) - 1`` folded in order, and a trip
+    is free to leave out any slot the chunk is dead for.  The per-slot
+    read (dense ``blhd`` float cache, no bias) does: a trip gathers chunk
+    ``i`` of one block of slots (``_slot_block``) — consecutive places of
+    the descending-need order of ``_slot_order`` — folds it into those
+    slots' carry rows and writes them back; a chunk index gets only as
+    many trips as hold a slot that needs it.  The bytes read follow each
+    slot's own length (rounded up to the chunk and the block), and every
+    live row is bitwise the batch-wide loop's.  Everything else — paged,
+    int8, ``bhld``, a bias, a batch the block does not divide — runs the
+    SAME fold over all ``B`` slots for ``ceil((max live length + T) / C)``
+    trips, as before.
 
     With ``block_table [B, W]`` the caches are a paged pool
     ``[N, C, Hkv, D]`` (``C == chunk``, "blhd" only): iteration ``i``
@@ -368,14 +473,13 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
     if attn_bias is not None:
         bias = jnp.broadcast_to(jnp.asarray(attn_bias, jnp.float32),
                                 (b, 1, t, lmax))
-    # highest live position + 1 this step: parked slots (>= lmax) excluded
-    eff = jnp.where(lengths < lmax, lengths, 0)
-    trip = jnp.clip((jnp.max(eff) + t + c - 1) // c, 1, n_chunks)
+    plain = layout == "blhd" and attn_bias is None and not quant \
+        and block_table is None
+    rows = _slot_block(b) if plain else None
     z = jnp.int32(0)
 
-    def body(carry):
-        i, m, l, acc = carry
-        start = jnp.minimum(i * c, lmax - c)  # clamped tail start
+    def read_chunk(cache, i, start, slots):
+        """Chunk ``i`` ([R, Hkv, C, D]) of ``slots`` (None: every slot)."""
         if block_table is not None:
             idx = jax.lax.dynamic_slice_in_dim(block_table, i, 1,
                                                axis=1)[:, 0]        # [B]
@@ -383,36 +487,47 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
             # sentinel/unmapped entry, and the masked softmax weight times
             # NaN is NaN — clipping reads an arbitrary REAL block whose
             # rows the causal mask zeroes exactly like dense garbage rows
-
-            def read(cache):
-                if isinstance(cache, tuple):
-                    db = jnp.take(cache[0], idx, axis=0, mode="clip")
-                    sb = jnp.take(cache[1], idx, axis=0, mode="clip")
-                    return _q8_dequant(db, sb)
-                return jnp.take(cache, idx, axis=0, mode="clip")
-
-            kb, vb = read(k_cache), read(v_cache)
-            kb, vb = jnp.swapaxes(kb, 1, 2), jnp.swapaxes(vb, 1, 2)
-        elif layout == "blhd":
-            def read(cache):
-                if isinstance(cache, tuple):
-                    db = jax.lax.dynamic_slice(cache[0], (z, start, z, z),
-                                               (b, c, hkv, d))
-                    sb = jax.lax.dynamic_slice(cache[1], (z, start, z),
-                                               (b, c, hkv))
-                    return _q8_dequant(db, sb)
-                return jax.lax.dynamic_slice(cache, (z, start, z, z),
-                                             (b, c, hkv, d))
-
-            kb, vb = read(k_cache), read(v_cache)
-            kb, vb = jnp.swapaxes(kb, 1, 2), jnp.swapaxes(vb, 1, 2)
+            if isinstance(cache, tuple):
+                blk = _q8_dequant(
+                    jnp.take(cache[0], idx, axis=0, mode="clip"),
+                    jnp.take(cache[1], idx, axis=0, mode="clip"))
+            else:
+                blk = jnp.take(cache, idx, axis=0, mode="clip")
+            return jnp.swapaxes(blk, 1, 2)
+        if layout != "blhd":
+            return jax.lax.dynamic_slice(cache, (z, z, start, z),
+                                         (b, hkv, c, d))
+        if slots is not None:
+            # ONE gather of the slots' windows of the [B * Lmax, Hkv, D]
+            # view, which is the leaf's own order: indexing slot and
+            # position apart (or slicing slot by slot) makes the TPU
+            # compiler copy the whole cache into another order before the
+            # loop (PERF.md, PR 29 and PR 30)
+            blk = jax.lax.gather(
+                cache.reshape(b * lmax, hkv, d),
+                (slots * lmax + start)[:, None],
+                jax.lax.GatherDimensionNumbers(
+                    offset_dims=(1, 2, 3), collapsed_slice_dims=(),
+                    start_index_map=(0,)),
+                (c, hkv, d), mode="promise_in_bounds")
+        elif isinstance(cache, tuple):
+            blk = _q8_dequant(
+                jax.lax.dynamic_slice(cache[0], (z, start, z, z),
+                                      (b, c, hkv, d)),
+                jax.lax.dynamic_slice(cache[1], (z, start, z),
+                                      (b, c, hkv)))
         else:
-            kb = jax.lax.dynamic_slice(k_cache, (z, z, start, z),
-                                       (b, hkv, c, d))
-            vb = jax.lax.dynamic_slice(v_cache, (z, z, start, z),
-                                       (b, hkv, c, d))
+            blk = jax.lax.dynamic_slice(cache, (z, start, z, z),
+                                        (b, c, hkv, d))
+        return jnp.swapaxes(blk, 1, 2)
+
+    def fold(i, slots, q, pos, m, l, acc):
+        """Fold chunk ``i`` into the carry rows of ``slots``."""
+        start = jnp.minimum(i * c, lmax - c)  # clamped tail start
+        kb = read_chunk(k_cache, i, start, slots)
+        vb = read_chunk(v_cache, i, start, slots)
         s = jnp.einsum(
-            "bkgtd,bkcd->bkgtc", qg, kb.astype(jnp.float32),
+            "bkgtd,bkcd->bkgtc", q, kb.astype(jnp.float32),
             preferred_element_type=jnp.float32) * scale
         if bias is not None:
             bb = jax.lax.dynamic_slice(bias, (z, z, z, start), (b, 1, t, c))
@@ -420,8 +535,8 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
         k_idx = start + jnp.arange(c, dtype=jnp.int32)            # [C] global
         # causal AND not already processed (the clamped tail re-reads
         # [start, i*c) — those positions belong to the previous chunk)
-        live = (k_idx[None, None, :] <= q_pos[:, :, None]) \
-            & (k_idx >= i * c)[None, None, :]                     # [B,T,C]
+        live = (k_idx[None, None, :] <= pos[:, :, None]) \
+            & (k_idx >= i * c)[None, None, :]                     # [R,T,C]
         s = jnp.where(live[:, None, None], s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         # explicit zero on masked lanes: a fully-masked row in an executed
@@ -433,23 +548,70 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
         acc = acc * corr[..., None] + jnp.einsum(
             "bkgtc,bkcd->bkgtd", p, vb.astype(jnp.float32),
             preferred_element_type=jnp.float32)
-        return i + jnp.int32(1), m_new, l, acc
+        return m_new, l, acc
 
     m0 = jnp.full((b, hkv, g, t), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, t), jnp.float32)
     acc0 = jnp.zeros((b, hkv, g, t, d), jnp.float32)
+
+    if rows is None:
+        # highest live position + 1 this step: parked slots (>= lmax) excluded
+        eff = jnp.where(lengths < lmax, lengths, 0)
+        total = jnp.clip((jnp.max(eff) + t + c - 1) // c, 1, n_chunks)
+
+        def cond(carry):
+            return carry[0] < total
+
+        def body(carry):
+            i, m, l, acc = carry
+            return (i + jnp.int32(1),) + fold(i, None, qg, q_pos, m, l, acc)
+    else:
+        order, place, cnt = _slot_order(
+            _chunks_needed(lengths, t, c, lmax), n_chunks)
+        # chunk indices some slot needs: cnt is non-increasing
+        total = jnp.sum(cnt > 0, dtype=jnp.int32)
+        # queries and carry in the order's places: a trip's rows are one
+        # contiguous block of them
+        q_at = _permute(qg, order)
+        pos_at = _permute(q_pos, order)
+
+        def block(x, at):
+            return jax.lax.dynamic_slice_in_dim(x, at, rows, axis=0)
+
+        def cond(carry):
+            return carry[0][0] < total
+
+        def body(carry):
+            (i, at), m, l, acc = carry
+            new = fold(i, block(order, at), block(q_at, at),
+                       block(pos_at, at), block(m, at), block(l, at),
+                       block(acc, at))
+            # next block of places that holds a slot needing chunk i, else
+            # the first block of chunk i + 1
+            more = at + rows < jax.lax.dynamic_index_in_dim(
+                cnt, i, keepdims=False)
+            nxt = (jnp.where(more, i, i + 1), jnp.where(more, at + rows, z))
+            return (nxt,) + tuple(
+                jax.lax.dynamic_update_slice_in_dim(x, n, at, axis=0)
+                for x, n in zip((m, l, acc), new))
+
     # the loop's own name (observability.trace.LOOPS): a %while in a
     # device trace that carries it is this cache-chunk loop
     with jax.named_scope("attn.core.chunks"):
         _, _, l, acc = jax.lax.while_loop(
-            lambda carry: carry[0] < trip, body, (z, m0, l0, acc0))
-    # chunk 0 runs unconditionally and position 0 is causally visible to
-    # every query (q_pos >= 0), so l > 0 for any FINITE attn_bias — but a
-    # bias of -inf over every visible position of a row zeroes its whole
-    # denominator.  Guard the division so that row comes back 0 (finite
-    # garbage, like the full path's softmax over all-masked scores) instead
-    # of NaN.
-    return acc / jnp.maximum(l, 1e-30)[..., None]
+            cond, body, (z if rows is None else (z, z), m0, l0, acc0))
+    # a live slot's chunk 0 is always folded and position 0 is causally
+    # visible to every query (q_pos >= 0), so l > 0 for any FINITE
+    # attn_bias — but a bias of -inf over every visible position of a row
+    # zeroes its whole denominator, and the per-slot read folds nothing
+    # into a parked slot.  Guard the division so such a row comes back 0
+    # (finite garbage, like the full path's softmax over all-masked
+    # scores) instead of NaN.
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    if rows is not None:
+        # back from the order's places to the slots
+        out = _permute(out, place)
+    return out
 
 
 def _attend_dispatch(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
@@ -507,9 +669,9 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, lengths, scale=None,
     broadcastable to [B, 1, T, Lmax] fp) is added to the scores (the
     reference's src_mask).  ``chunk_size`` (static) selects the
     length-adaptive chunked read (see the module docstring): HBM traffic
-    proportional to the longest live context instead of Lmax, allclose-
-    identical to the full read; ``None`` (or >= Lmax) keeps the single
-    fused full-length pass.  Returns (out [B, T, H, D], k_cache',
+    follows each slot's own context instead of Lmax, allclose-identical
+    to the full read; ``None`` (or >= Lmax) keeps the single fused
+    full-length pass.  Returns (out [B, T, H, D], k_cache',
     v_cache', lengths + T).
 
     ``block_table [B, W]`` (traced int32) switches to the PAGED geometry:

@@ -109,7 +109,7 @@ from paddle_tpu.observability.flightrecorder import (
 from paddle_tpu.observability.slo import SLOTracker
 from paddle_tpu.observability.trace import span
 from paddle_tpu.observability.watchdog import DeadlockWatchdog
-from paddle_tpu.ops.decode_attention import _canon_kv_dtype
+from paddle_tpu.ops.decode_attention import _canon_kv_dtype, kv_rows_read
 from paddle_tpu.serving.faults import InjectedDispatchError
 from paddle_tpu.serving.kv_cache import (
     BlockStore, KVCacheManager, KVPoolExhausted, PagedKVCacheManager,
@@ -1595,6 +1595,29 @@ class ServingEngine:
         list — tpu-lint PTL010 polices the difference)."""
         return self._kv.device_tables()
 
+    def _decode_lengths(self, active):
+        """The lengths operand of one decode dispatch (every slot that is
+        not ``active`` parked), counted on the way: the rows one layer's
+        cache read touches at these lengths against the rows its live
+        slots attend to (``serving_kv_rows_*_total``) — a few integer
+        operations on the host's mirror, nothing on the device.  Spec
+        rounds count their ``k + 1`` verify tokens at the mirror's
+        lengths, which trail the device's by the round in flight."""
+        m = self._m
+        if m is not None:
+            spec = self._mode == "spec"
+            # the tree verify of a draft model attends under a bias
+            plain = not (self._paged or self._q8 or (spec and self._dspec))
+            for step in range(1 if spec else self._sync):
+                read, live = kv_rows_read(
+                    np.where(active, self._kv.lengths + step,
+                             self._kv.max_len),
+                    self._spec_k + 1 if spec else 1, self._chunk,
+                    self._kv.max_len, plain)
+                m.kv_rows_read.inc(read)
+                m.kv_rows_live.inc(live)
+        return self._kv.device_lengths(active)
+
     def _call_decode(self, cur, dev_len):
         if self._tp is not None:
             if self._paged:
@@ -2414,7 +2437,7 @@ class ServingEngine:
                 "resident request survived its first-token flush")
         self._ensure_decode_rows(live)
         active = np.array([self._decodable(i) for i in range(self._B)])
-        dev_len = self._kv.device_lengths(active)
+        dev_len = self._decode_lengths(active)
         if self._mode == "greedy":
             def go(attempt):
                 self._fault_point("dispatch", attempt)
@@ -2506,7 +2529,7 @@ class ServingEngine:
     def _dispatch_live(self, live, adm_active):
         m = self._m
         active = np.array([self._decodable(i) for i in range(self._B)])
-        host_len = self._kv.device_lengths(active)
+        host_len = self._decode_lengths(active)
         use_host = ~active
         use_host[list(self._adm_pending)] = True
         # freshly prefilled slots: length is host-known (the prompt length,

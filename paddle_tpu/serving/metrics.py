@@ -174,6 +174,18 @@ class EngineMetrics:
             "serving_state_resets_total",
             "slot admissions whose first prefill chunk zeroed the slot's "
             "recurrent state", L).labels(**lbl)
+        # the decode cache read against the rows it needs (one layer's
+        # count at each decode dispatch's host lengths, by the read's own
+        # rule: ops.decode_attention.kv_rows_read); read / live is the
+        # over-read
+        self.kv_rows_read = reg.counter(
+            "serving_kv_rows_read_total",
+            "cache rows one layer's decode read touches, summed over "
+            "decode dispatches", L).labels(**lbl)
+        self.kv_rows_live = reg.counter(
+            "serving_kv_rows_live_total",
+            "cache rows some live slot attends to, summed over decode "
+            "dispatches", L).labels(**lbl)
         self.prefill_backlog = reg.gauge(
             "serving_prefill_backlog",
             "prompt chunks still to dispatch across slots mid-prefill",

@@ -200,18 +200,9 @@ def _weight_copies(text):
             if int(m.group(1)) * int(m.group(2)) >= 2 ** 22]
 
 
-@pytest.mark.parametrize("batch,lmax", [(32, 2048), (16, 4096)],
-                         ids=["32x2048", "16x4096"])
-@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
-def test_serving_programs_read_weights_as_stored(one_chip, program, batch,
-                                                 lmax):
-    """The decode-steps and prefill-chunk programs at both serving cells'
-    geometries consume every weight in the order it is stored.  A reshape
-    to ``[.., heads, head_dim]`` directly behind the Q/K/V dots made the
-    compiler copy ``wq``, ``wk`` and ``wv`` of every layer into the other
-    order on every run (2.6 ms of a 16.5 ms decode step: PERF.md, PR 27).
-    The K/V cache chunks' own re-layout inside ``attn.core.chunks``
-    (rank 4) is not a weight's and is not counted here."""
+def _mistral_program(one_chip, program, batch, lmax):
+    """The decode-steps or prefill-chunk program of the Mistral serving
+    cells (2 layers, abstract operands), lowered for the described chip."""
     from paddle_tpu.models import llama_decode as ld
 
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
@@ -230,29 +221,38 @@ def test_serving_programs_read_weights_as_stored(one_chip, program, batch,
               for _ in range(M_LAYERS)]
     cfg = (M_NH, M_NKV, D, 1e-5)
     if program == "decode_steps":
-        lowered = ld.serving_decode_steps.__wrapped__.lower(
+        return ld.serving_decode_steps.__wrapped__.lower(
             params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
             chunk_size=256)
-    else:
-        lowered = ld.serving_prefill_chunk.__wrapped__.lower(
-            params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
-            i32(()), chunk_size=256)
+    return ld.serving_prefill_chunk.__wrapped__.lower(
+        params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+        i32(()), chunk_size=256)
+
+
+@pytest.mark.parametrize("batch,lmax", [(32, 2048), (16, 4096)],
+                         ids=["32x2048", "16x4096"])
+@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
+def test_serving_programs_read_weights_as_stored(one_chip, program, batch,
+                                                 lmax):
+    """The decode-steps and prefill-chunk programs at both serving cells'
+    geometries consume every weight in the order it is stored.  A reshape
+    to ``[.., heads, head_dim]`` directly behind the Q/K/V dots made the
+    compiler copy ``wq``, ``wk`` and ``wv`` of every layer into the other
+    order on every run (2.6 ms of a 16.5 ms decode step: PERF.md, PR 27).
+    The K/V cache chunks' own re-layout inside ``attn.core.chunks``
+    (rank 4) is not a weight's and is not counted here."""
+    lowered = _mistral_program(one_chip, program, batch, lmax)
     assert _weight_copies(lowered.compile().as_text()) == []
 
 
 # Falcon-H1-34B's widths (the benchmark's falconh1_chat_short cell), 2 of
 # its blocks at the cell's geometry
-@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
-def test_state_space_serving_programs_compile_in_place(one_chip, program):
-    """The two Falcon-H1 serving programs compile for the chip at published
-    widths, read every weight in the order it is stored (the Q/K/V barrier
-    of ``falcon_h1.attn_qkv``), and update the float32 recurrent state
-    ``[64, 32, 128, 256]`` in place: no copy of a state leaf (4 of them
-    would be the cell's 1.6 GB again)."""
+def _falcon_program(one_chip, program, batch=64, lmax=1024, layers=2):
+    """The same two programs of the Falcon-H1 cell; returns ``(lowered,
+    state leaf's shape)``."""
     from paddle_tpu.models import falcon_h1_decode as fd
     from paddle_tpu.models.falcon_h1 import FalconH1Config, statics_of
 
-    batch, lmax, layers = 64, 1024, 2
     c = FalconH1Config(num_hidden_layers=layers)
     cfg = statics_of(c)
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
@@ -281,13 +281,22 @@ def test_state_space_serving_programs_compile_in_place(one_chip, program):
                bf16((batch, cfg.d_conv - 1, cfg.conv_channels)))
               for _ in range(layers)]
     if program == "decode_steps":
-        lowered = fd.serving_decode_steps.__wrapped__.lower(
+        return fd.serving_decode_steps.__wrapped__.lower(
             params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
-            chunk_size=256)
-    else:
-        lowered = fd.serving_prefill_chunk.__wrapped__.lower(
-            params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
-            i32(()), chunk_size=256)
+            chunk_size=256), state
+    return fd.serving_prefill_chunk.__wrapped__.lower(
+        params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+        i32(()), chunk_size=256), state
+
+
+@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
+def test_state_space_serving_programs_compile_in_place(one_chip, program):
+    """The two Falcon-H1 serving programs compile for the chip at published
+    widths, read every weight in the order it is stored (the Q/K/V barrier
+    of ``falcon_h1.attn_qkv``), and update the float32 recurrent state
+    ``[64, 32, 128, 256]`` in place: no copy of a state leaf (4 of them
+    would be the cell's 1.6 GB again)."""
+    lowered, state = _falcon_program(one_chip, program)
     compiled = lowered.compile()
     text = compiled.as_text()
     assert _weight_copies(text) == []
@@ -297,3 +306,78 @@ def test_state_space_serving_programs_compile_in_place(one_chip, program):
     # donated caches are updated in place: the temporaries stay far under
     # one state leaf (268 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
+# The decode program's cache read (PERF.md, PR 30).  Counts of the whole
+# compiled text at 2 layers: (fusion, while, custom-call).  PARENT: commit
+# 16e570b (PR 28's tree: one batch-wide chunk loop a layer), read by this
+# same test's rule; CEILING: what PR 30's per-slot read compiles to.
+_DECODE_SIZE = {
+    "32x2048": dict(parent=(105, 3, 12), ceiling=(122, 3, 18)),
+    "16x4096": dict(parent=(105, 3, 9), ceiling=(122, 3, 18)),
+    "64x1024": dict(parent=(149, 3, 14), ceiling=(168, 3, 25)),
+}
+_RELAYOUT = re.compile(
+    r"= bf16\[([\d,]+)\]\S* (?:copy|transpose)\(")
+
+
+def _computations(text):
+    """``[(name, is a fused computation, its lines)]`` of a compiled text."""
+    out = []
+    for m in re.finditer(r"\n(%?[\w.\-]+) \([^\n]*\) -> [^\n]* \{\n(.*?)\n\}",
+                         text, re.S):
+        out.append((m.group(1), "fused_computation" in m.group(1),
+                    m.group(2).split("\n")))
+    return out
+
+
+@pytest.mark.parametrize("geometry", sorted(_DECODE_SIZE))
+def test_decode_program_reads_the_cache_in_place_and_stays_small(
+        one_chip, geometry):
+    """The set-up budget's guard off the chip (ISSUE 30), at the three
+    serving geometries.
+
+    (a) No ``copy`` / ``transpose`` of a whole K/V cache leaf anywhere: a
+    gather that indexes slot and position apart, or slot-by-slot slices,
+    makes the compiler copy every layer's cache into another order before
+    the loop (seen chipless, PR 29 and PR 30; 268 MB a leaf at 32 x 2048).
+    The gathered CHUNK is still transposed to ``[slots, Hkv, C, D]`` for
+    the two dots — the MXU wants a head's ``[C, D]`` matrix and the cache
+    stores a position's ``[Hkv, D]`` tile — but only inside fused
+    computations (the gather's and the dots': fast memory, no pass over
+    HBM of its own), at most 4 a layer (the parent: 2 a layer, over the
+    whole batch's chunk).
+    (b) Size: ONE ``while`` a layer, as the parent (PR 29 had four and
+    cost 0.8 s of set-up); fusions and custom calls within the recorded
+    ceiling — above the parent's by 5 fusions and one gather marker a
+    layer and 7 fusions a step (the slot order): +12% at 16 layers.
+    Whether that fits the set-up budget is the chip's to say (PERF.md)."""
+    if geometry == "64x1024":
+        lowered, _ = _falcon_program(one_chip, "decode_steps")
+        batch, lmax, hkv = 64, 1024, 4
+    else:
+        batch, lmax = (int(x) for x in geometry.split("x"))
+        lowered = _mistral_program(one_chip, "decode_steps", batch, lmax)
+        hkv = M_NKV
+    text = lowered.compile().as_text()
+    leaf = batch * lmax * hkv * D
+    chunks = 0
+    for name, fused, lines in _computations(text):
+        for ln in lines:
+            m = _RELAYOUT.search(ln)
+            if m is None:
+                continue
+            dims = [int(x) for x in m.group(1).split(",")]
+            size = 1
+            for x in dims:
+                size *= x
+            assert size < leaf, f"a whole cache leaf is re-laid-out: {ln[:120]}"
+            if 256 in dims and hkv in dims and D in dims:
+                chunks += 1
+                assert fused, f"a K/V chunk re-laid-out on its own: {ln[:120]}"
+    assert 0 < chunks <= 4 * M_LAYERS
+    size = tuple(len(re.findall(r" %s\(" % k, text))
+                 for k in ("fusion", "while", "custom-call"))
+    want = _DECODE_SIZE[geometry]
+    assert size[1] == want["parent"][1]
+    assert all(a <= b for a, b in zip(size, want["ceiling"])), size

@@ -23,9 +23,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_phase_runs_at_toy_size(phase, capsys, monkeypatch):
     """Each phase function, imported and driven end to end: every check
     it makes off the chip passes, and it reports the device it ran on.
-    (The persistent cache stays off: this process is a test worker.)"""
+    (The persistent cache stays off: this process is a test worker, and
+    the once-a-process record of kernel fallbacks starts empty: the script
+    reads it, and an earlier test file of this worker may have filled it.)"""
+    from paddle_tpu.ops import paged_attention_pallas as pap
+
     monkeypatch.setattr(compile_cache, "enable_compile_cache",
                         lambda: "(off under test)")
+    monkeypatch.setattr(pap, "_warned", set())
     device, failed = chip_smoke.PHASES[phase](chip_smoke.TOY, chip=False)
     out = capsys.readouterr().out
     assert failed == [], out

@@ -263,6 +263,258 @@ class TestChunkedDecodeAttention:
                                           np.asarray(full[0]))
 
 
+def _ragged(c, lmax):
+    return [0, 1, c - 1, c, c + 1, lmax - 1, 7, 2 * c + 3]
+
+
+class TestPerSlotRead:
+    """The per-slot read of the chunked loop (``_attend_chunked`` with
+    ``_slot_block`` engaged): a trip gathers one block of slots in
+    descending order of need, and a chunk index gets only as many trips
+    as hold a slot that needs it.  Per row the recurrence is the same
+    chunks in the same order, and a chunk past a row's length is a no-op
+    bit for bit — so every LIVE row is bitwise the batch-wide loop's.
+    Parked rows (offset >= lmax) are garbage on either path, finite on
+    both."""
+
+    C = 16
+
+    def _operands(self, lens, lmax, t=1, hkv=2, g=2, d=16, seed=0,
+                  dtype=jnp.float32):
+        b = len(lens)
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        qg = jax.random.normal(ks[0], (b, hkv, g, t, d), jnp.float32)
+        kc = jax.random.normal(ks[1], (b, lmax, hkv, d)).astype(dtype)
+        vc = jax.random.normal(ks[2], (b, lmax, hkv, d)).astype(dtype)
+        lengths = jnp.asarray(lens, jnp.int32)
+        q_pos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+        return qg, kc, vc, lengths, q_pos
+
+    def _chunked(self, ops, monkeypatch, block):
+        """One compile of the chunked read with the block rule replaced:
+        ``None`` is the batch-wide loop."""
+        from paddle_tpu.ops import decode_attention as da
+
+        if block != "rule":
+            monkeypatch.setattr(da, "_slot_block", lambda batch: block)
+        return np.asarray(jax.jit(
+            lambda *a: da._attend_chunked(*a, 0.25, "blhd", None, self.C))(
+                *ops))
+
+    CASES = {
+        "ragged": dict(lens=_ragged(16, 64), lmax=64),
+        "ragged-T5": dict(lens=_ragged(16, 64), lmax=64, t=5),
+        "parked-mixed": dict(lens=[64, 3, 64, 40, 64, 64, 17, 64], lmax=64),
+        "parked-past-lmax": dict(lens=[70, 3, 64, 40, 99, 64, 17, 16],
+                                 lmax=64),
+        "tail-chunk": dict(lens=_ragged(16, 60), lmax=60),
+        "tail-chunk-T5": dict(lens=_ragged(16, 60), lmax=60, t=5),
+        "every-slot-full": dict(lens=[63] * 8, lmax=64),
+        "every-slot-short": dict(lens=[2] * 8, lmax=64),
+        "block-of-8": dict(lens=list(range(0, 64, 2)), lmax=64, seed=3),
+        "block-of-2": dict(lens=_ragged(16, 64), lmax=64, block=2),
+        "bf16": dict(lens=_ragged(16, 64), lmax=64, dtype=jnp.bfloat16),
+        "gqa-5": dict(lens=_ragged(16, 64), lmax=64, hkv=4, g=5, seed=5),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_live_rows_are_bitwise_the_batch_wide_loops(self, case,
+                                                        monkeypatch):
+        from paddle_tpu.ops import decode_attention as da
+
+        kw = dict(self.CASES[case])
+        lens, lmax = kw.pop("lens"), kw.pop("lmax")
+        block = kw.pop("block", "rule")
+        assert da._slot_block(len(lens)) is not None
+        ops = self._operands(lens, lmax, **kw)
+        per_slot = self._chunked(ops, monkeypatch, block)
+        wide = self._chunked(ops, monkeypatch, None)
+        live = np.asarray(lens) < lmax
+        np.testing.assert_array_equal(per_slot[live], wide[live])
+        assert np.isfinite(per_slot).all()
+        full = np.asarray(da._attend_full(*ops, 0.25, "blhd", None))
+        np.testing.assert_allclose(per_slot[live], full[live], rtol=2e-5,
+                                   atol=2e-5)
+
+    def test_all_slots_parked_read_nothing(self, monkeypatch):
+        """No slot needs a chunk: no trip runs and every row comes back 0
+        (the batch-wide loop folds chunk 0 of every slot: garbage)."""
+        from paddle_tpu.ops import decode_attention as da
+
+        ops = self._operands([64] * 8, 64)
+        assert not self._chunked(ops, monkeypatch, "rule").any()
+        assert da.kv_rows_read([64] * 8, 1, self.C, 64) == (0, 0)
+        assert da.kv_rows_read([64] * 8, 1, self.C, 64, plain=False) \
+            == (8 * self.C, 0)
+
+    @pytest.mark.parametrize("batch", [1, 2, 4, 6])
+    def test_a_batch_of_a_block_or_less_is_the_batch_wide_program(
+            self, batch, monkeypatch):
+        """B = 1 is the prefill chunk's one-slot view: the rule leaves such
+        batches (and one the block does not divide) on the batch-wide
+        loop, so the slot order is never built."""
+        from paddle_tpu.ops import decode_attention as da
+
+        assert da._slot_block(batch) is None
+
+        def never(*a, **k):
+            raise AssertionError("the per-slot read engaged")
+
+        monkeypatch.setattr(da, "_slot_order", never)
+        lens = [0, 17, 40, 64, 3, 63][:batch]
+        ops = self._operands(lens, 64)
+        out = self._chunked(ops, monkeypatch, "rule")
+        full = np.asarray(da._attend_full(*ops, 0.25, "blhd", None))
+        live = np.asarray(lens) < 64
+        np.testing.assert_allclose(out[live], full[live], rtol=2e-5,
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("kind", ["paged", "int8", "bhld", "bias"])
+    def test_other_geometries_keep_the_batch_wide_loop(self, kind,
+                                                       monkeypatch):
+        """Paged, int8, ``bhld`` and biased reads at a batch the block
+        divides never build the slot order: their loop is the batch-wide
+        one, unchanged.  The paged read (batch-wide, through the table) is
+        bitwise the dense read of the same rows (per-slot): the pin the
+        serving parity matrices rest on."""
+        from paddle_tpu.ops import decode_attention as da
+
+        B, lmax, c, hkv, h, d = 8, 64, self.C, 2, 4, 16
+        lens = _ragged(c, lmax)
+        ks = jax.random.split(jax.random.PRNGKey(11), 6)
+        q = jax.random.normal(ks[0], (B, 1, h, d), jnp.float32)
+        kn = jax.random.normal(ks[1], (B, 1, hkv, d), jnp.float32)
+        vn = jax.random.normal(ks[2], (B, 1, hkv, d), jnp.float32)
+        kc = jax.random.normal(ks[3], (B, lmax, hkv, d), jnp.float32)
+        vc = jax.random.normal(ks[4], (B, lmax, hkv, d), jnp.float32)
+        lengths = jnp.asarray(lens, jnp.int32)
+        dense = da.decode_attention(q, kn, vn, kc, vc, lengths,
+                                    chunk_size=c)[0]
+        built = []
+        order = da._slot_order
+        monkeypatch.setattr(
+            da, "_slot_order",
+            lambda *a, **k: built.append(1) or order(*a, **k))
+        kw = dict(chunk_size=c)
+        if kind == "paged":
+            w = lmax // c
+            kw["block_table"] = jnp.arange(B * w, dtype=jnp.int32) \
+                .reshape(B, w)
+            kc, vc = (x.reshape(B * w, c, hkv, d) for x in (kc, vc))
+        elif kind == "int8":
+            kc, vc = (da._q8_quantize(x) for x in (kc, vc))
+        elif kind == "bhld":
+            kc, vc = (jnp.swapaxes(x, 1, 2) for x in (kc, vc))
+            kw["layout"] = "bhld"
+        else:
+            kw["attn_bias"] = jnp.zeros((B, 1, 1, lmax), jnp.float32)
+        # unjitted, so that the trace runs under the patch
+        out = da.decode_attention.__wrapped__(q, kn, vn, kc, vc, lengths,
+                                              **kw)[0]
+        assert not built
+        if kind == "int8":
+            np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                                       rtol=0.1, atol=0.1)
+        else:
+            np.testing.assert_array_equal(np.asarray(out),
+                                          np.asarray(dense))
+
+    def test_rows_read_follow_the_slots_not_the_longest(self):
+        """``kv_rows_read`` by hand: 8 slots, 16-row chunks, blocks of 4.
+        Needs (chunks) 1, 1, 1, 2, 2, 4, 1, 3 in descending order 4, 3, 2,
+        2 | 1, 1, 1, 1: chunk 0 is needed by 8 slots (2 blocks), chunks 1,
+        2 and 3 by 4, 2 and 1 (1 block each): 5 trips x 4 slots x 16 rows.
+        The batch-wide rule: 4 chunks x 8 slots x 16 rows."""
+        from paddle_tpu.ops.decode_attention import kv_rows_read
+
+        lens = _ragged(16, 64)
+        live = sum(n + 1 for n in lens)
+        assert kv_rows_read(lens, 1, 16, 64) == (5 * 4 * 16, live)
+        assert kv_rows_read(lens, 1, 16, 64, plain=False) \
+            == (4 * 8 * 16, live)
+        # the full read touches every row
+        assert kv_rows_read(lens, 1, None, 64) == (8 * 64, live)
+
+
+class TestPerSlotReadInTheEngine:
+    """The same seeded chat-like stream through ``ServingEngine``: the
+    tokens of a request do not depend on which other slots are live, and
+    the read's counters say how far it follows the live rows."""
+
+    def _engine(self, **kw):
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.serving import ServingEngine
+
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig.tiny(dtype="float32"))
+        model.eval()
+        return ServingEngine(model, batch_size=16, max_len=128,
+                             prefill_chunk=16, decode_chunk=16, **kw)
+
+    def _stream(self, n):
+        """Prompts log-uniform 8-64, outputs 4-16: chat's shape at a
+        sixteenth of its lengths."""
+        from paddle_tpu.serving import Request
+
+        rng = np.random.default_rng(7)
+        out = []
+        for _ in range(n):
+            p = int(np.exp(rng.uniform(np.log(8), np.log(64))))
+            out.append(Request(rng.integers(1, 250, p).astype(np.int32),
+                               int(rng.integers(4, 17))))
+        return out
+
+    def _served(self, n, **kw):
+        eng = self._engine(**kw)
+        reqs = self._stream(16)[:n]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        return [list(r.output_ids) for r in reqs]
+
+    def test_tokens_do_not_depend_on_the_other_slots(self):
+        """Every slot live against half the slots empty: the first eight
+        requests' token streams are identical."""
+        full = self._served(16)
+        half = self._served(8)
+        assert all(len(t) >= 4 for t in full)
+        assert full[:8] == half
+
+    def test_read_ratio_with_half_the_slots_empty(self):
+        """A steady stream that keeps 8 of the 16 slots live: read / live
+        under 2 where the batch-wide rule, computed in the same run from
+        the same host lengths, reads over 3."""
+        from paddle_tpu.observability.metrics import MetricsRegistry
+        from paddle_tpu.ops.decode_attention import kv_rows_read
+
+        reg = MetricsRegistry()
+        eng = self._engine(registry=reg)
+        seen = {"wide": 0, "read": 0, "live": 0}
+        inner = eng._decode_lengths
+
+        def counted(active):
+            lens = np.where(active, eng._kv.lengths, 128)
+            seen["wide"] += kv_rows_read(lens, 1, 16, 128, plain=False)[0]
+            read, live = kv_rows_read(lens, 1, 16, 128)
+            seen["read"] += read
+            seen["live"] += live
+            return inner(active)
+
+        eng._decode_lengths = counted
+        waiting, flying = self._stream(24), []
+        while waiting or flying:
+            flying = [r for r in flying if not r.done]
+            while waiting and len(flying) < 8:
+                flying.append(waiting.pop(0))
+                eng.submit(flying[-1])
+            eng.step()
+        lbl = dict(policy="continuous")
+        read = reg.get("serving_kv_rows_read_total").labels(**lbl).value
+        live = reg.get("serving_kv_rows_live_total").labels(**lbl).value
+        assert (read, live) == (seen["read"], seen["live"])
+        assert read / live < 2 < 3 < seen["wide"] / live
+
+
 class TestSlotPrefillAttention:
     """The chunked-prefill attention op (ops.slot_prefill_attention):
     chaining [1, P] chunks at offsets 0, P, 2P, ... against one slot of

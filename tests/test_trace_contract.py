@@ -22,7 +22,7 @@ from paddle_tpu.models.llama_decode import (
     serving_decode_steps, serving_prefill_chunk,
 )
 from paddle_tpu.observability.trace import (
-    LOOPS, SCOPES, SPANS, STATE_SCOPES,
+    COUNTERS, LOOPS, SCOPES, SPANS, STATE_SCOPES,
 )
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.static.functionalize import build_train_step
@@ -202,6 +202,42 @@ def test_state_counters_are_the_names_the_readers_ask_for():
     lbl = dict(policy="continuous")
     assert reg.get("serving_state_bytes").labels(**lbl).value > 0
     assert reg.get("serving_state_resets_total").labels(**lbl).value == 1
+
+
+@pytest.mark.parametrize("name", COUNTERS)
+def test_engine_registers_every_counter_of_the_list(name):
+    """Every name of ``trace.COUNTERS`` is a series an instrumented engine
+    registers at construction, before a request has run."""
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    tiny_engine(registry=reg)
+    assert reg.get(name) is not None
+
+
+@pytest.mark.parametrize("pipeline,read,live", [(False, 256, 94),
+                                                (True, 320, 120)])
+def test_kv_rows_counters_read_the_numbers_computed_by_hand(pipeline, read,
+                                                            live):
+    """``serving_kv_rows_read_total`` / ``serving_kv_rows_live_total`` on a
+    stream with known lengths: one request of 21 prompt tokens and 5 new
+    ones in a 2-slot engine with 16-row chunks.  The first token comes from
+    the prefill; the four decode dispatches run at lengths 21..24 (the
+    other slot parked), each attending to length + 1 rows and — two slots
+    being one block, the batch-wide rule — reading ``ceil((length + 1) /
+    16) = 2`` chunks of both slots: 4 x 2 x 2 x 16 rows read, 22 + 23 + 24
+    + 25 live.  The pipelined engine has dispatched a fifth step (length
+    25) before the fourth's tokens tell it the request is done: 64 and 26
+    more."""
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = tiny_engine(registry=reg, pipeline=pipeline)
+    eng.submit(Request(PROMPTS[0], NEW[0]))
+    eng.run()
+    lbl = dict(policy="continuous")
+    assert reg.get("serving_kv_rows_read_total").labels(**lbl).value == read
+    assert reg.get("serving_kv_rows_live_total").labels(**lbl).value == live
 
 
 # (c) the host spans, on the profiler's timeline
