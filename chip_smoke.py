@@ -5,15 +5,16 @@
     python3 chip_smoke.py --multichip  # four chips: tensor-parallel serve only
 
 Drives the two main paths once through their public entry points, at the
-full width (and depth) of the model ``bench.py`` uses, with random weights
-from a fixed seed:
+full width (and depth) of a 0.95 B-parameter Llama, with random weights
+from a fixed seed (the benchmark's cells measure other, published widths:
+``benchmark/configs/``):
 
 * **serve** — ``LlamaForCausalLM`` (vocab 32000, hidden 2048, 16 layers,
   16 heads / 4 kv heads, bf16, max_len 2048) behind
   ``paddle_tpu.serving.ServingEngine`` via ``submit``/``run``: once on the
   engine's default options, once with a paged int8 pool and both fused
   Pallas kernels (``attn_impl="pallas"``, ``prefill_impl="pallas"``).
-* **train** — the ``bench_llama`` configuration (0.95 B parameters, batch
+* **train** — the same model (0.95 B parameters, batch
   16 x seq 2048, int8/bf16 Adam moments, ``recompute_layers=7``, chunked
   CE) through ``static.functionalize.build_train_step``, three steps.
 * **multichip** (``--multichip`` only) — the serve path on
@@ -390,7 +391,7 @@ def serve_phase(size=FULL, chip=True):
 
 
 def train_phase(size=FULL, chip=True):
-    """Three fused train steps of the bench_llama configuration on one
+    """Three fused train steps of the 0.95 B configuration on one
     repeated batch.  Returns (device, failed_checks)."""
     import jax
     import numpy as np

@@ -8,8 +8,9 @@ The whole-tree gate lints three kinds of code with different contracts:
   ``time.sleep`` to provoke timing paths, so the hot-loop pipelining
   rules (PTL004/PTL008) and the label-cardinality rule (PTL009) are off;
   trace hygiene, cache-key completeness and thread safety stay on.
-* ``bench*.py`` — measurement drivers whose loops sync once per
-  iteration by design (that is the measurement); same relaxations.
+* ``bench*.py`` (``benchmark/`` matches: ``*`` spans ``/``) — measurement
+  drivers whose loops sync once per iteration by design (that is the
+  measurement); same relaxations.
 
 The table below is the single source of truth, shaped like the
 ``[tool.tpu-lint.profiles]`` table it would be in a pyproject config;

@@ -65,7 +65,7 @@ class Place:
             # API parity: "the accelerator" is whatever non-CPU backend jax
             # runs on; on a CPU-only host (the test platform) accelerator
             # places name CPU devices.  Nothing that measures relies on
-            # this — chip_smoke.py and bench.py assert the platform.
+            # this — chip_smoke.py and benchmark/run.py assert the platform.
             if plat != "cpu":
                 devices = jax.devices()
             else:

@@ -297,17 +297,14 @@ def check_options(options):
     """Refuse, at the engine's construction, every option under which the
     recurrent state would be silently wrong or has not been made to work.
     What the record itself says is the engine's to refuse: no ``tp_rules``
-    (``mesh=``), no ``quantize_weights`` (``weight_dtype=``), no
-    ``prefill_slot`` (``prefill_chunk=None``: the state is carried from
-    chunk to chunk inside the prefill-chunk program, and a monolithic
-    per-bucket prefill has no state path)."""
+    (``mesh=``), no ``quantize_weights`` (``weight_dtype=``)."""
     for name, default in _DEFAULTS.items():
         if options.get(name, default) != default:
             raise ValueError(
                 f"ServingEngine: {name}={options[name]!r} is not supported "
                 f"for a falcon_h1 model — missing: {_MISSING[name]}")
-    pchunk, chunk = options.get("prefill_chunk"), options["cfg"].chunk
-    if pchunk is not None and pchunk % chunk:
+    pchunk, chunk = options["prefill_chunk"], options["cfg"].chunk
+    if pchunk % chunk:
         raise ValueError(
             f"ServingEngine: prefill_chunk ({pchunk}, after the clamp to "
             f"max_len) must be a multiple of mamba_chunk_size ({chunk}): "

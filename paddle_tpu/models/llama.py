@@ -168,7 +168,7 @@ class LlamaAttention(Layer):
         # ~11 ms/step SLOWER than the standalone rope kernel + flash —
         # the attention kernels are VPU-bound, so in-kernel rotation
         # extends their critical path by more than the bandwidth-bound
-        # standalone pass costs (2x A/B, BENCH_NOTES r5).
+        # standalone pass costs (2x A/B, round 5, on an older stack).
         v = M.reshape(vp, [b, l, nkv, hd])
 
         def rope_fn(qa, ka):

@@ -38,9 +38,9 @@ from paddle_tpu.ops.decode_attention import (
 )
 
 __all__ = ["extract_decode_params", "decode_greedy", "decode_speculative",
-           "quantize_decode_weights", "serving_prefill_slot",
-           "serving_prefill_chunk", "serving_decode_steps",
-           "serving_spec_step", "serving_spec_draft_step"]
+           "quantize_decode_weights", "serving_prefill_chunk",
+           "serving_decode_steps", "serving_spec_step",
+           "serving_spec_draft_step"]
 
 # compile-cache visibility (paddle_tpu/observability): each jitted program
 # marks its traces from inside the traced body (host python there runs once
@@ -291,12 +291,12 @@ def _forward(params, cfg, tokens, caches, lengths, last_only, last_idx=None,
     lengths + T).  ``last_only`` projects just the final position
     ([B, V], the scan/greedy path); otherwise every position ([B, T, V],
     speculative verification).  ``last_idx`` [B] projects one PER-BATCH
-    position instead ([B, V]) — the ragged-prefill path, where each
-    slot's prompt ends at a different column of the padded block.  One
-    ``block_tables`` operand serves every layer — block id ``i`` names
-    row ``i`` of EVERY layer's pool (the tables are geometry, the pools
-    are content).  ``pos_offsets`` / ``attn_bias`` thread the tree-
-    speculation ROPE override and tree attention mask into every layer
+    position instead ([B, V]) — a right-padded prompt block, where each
+    prompt ends at a different column (``chip_smoke.py``'s reference
+    logits).  One ``block_tables`` operand serves every layer — block id
+    ``i`` names row ``i`` of EVERY layer's pool (the tables are geometry,
+    the pools are content).  ``pos_offsets`` / ``attn_bias`` thread the
+    tree-speculation ROPE override and tree attention mask into every layer
     (see ``_layer_step``); None keeps the linear path bitwise unchanged."""
     with jax.named_scope("embed"):
         h = params["embed"][tokens]  # [B, T, hidden]
@@ -600,13 +600,12 @@ _spec_ngram_jit = _mon.wrap("spec_ngram_decode", _spec_ngram_jit)
 # knob object: a frozen serving/program_key.py ``ProgramKey`` carrying
 # every registry axis — attn_impl (the fused decode cache read),
 # prefill_impl (the fused prefill attention + append), kv_dtype (cache
-# storage; only the prefill-slot program consumes the value, for its
-# mini-cache allocation — elsewhere the cache pytree structure already
-# carries it and the axis is program identity), weight_dtype (identity-
-# only: the params pytree's sibling "_scale" leaves carry the actual
-# quantization) and tp_overlap (row-parallel psum segmentation).  The
-# impls read the axes by attribute (duck-typed, so this module never
-# imports the serving package); validation lives in ProgramKey itself.
+# storage: the cache pytree structure already carries it and the axis is
+# program identity), weight_dtype (identity-only: the params pytree's
+# sibling "_scale" leaves carry the actual quantization) and tp_overlap
+# (row-parallel psum segmentation).  The impls read the axes by attribute
+# (duck-typed, so this module never imports the serving package);
+# validation lives in ProgramKey itself.
 # Adding a static knob = adding one registry axis — never editing these
 # static_argnames lists again (tpu-lint PTL014 polices the consumers).
 
@@ -617,80 +616,6 @@ def _pk_axis(program_key, name):
     axes; ProgramKey validates them at construction)."""
     return getattr(program_key, name, None) if program_key is not None \
         else None
-
-
-def _serving_prefill_slot_impl(params, cfg, tokens, prompt_len, caches, slot,
-                               hist=None, hist_len=None, with_hist=False,
-                               chunk_size=None, program_key=None):
-    """Admit ONE request: prefill its prompt, insert into the batch cache.
-
-    ``tokens [1, Tpad]`` is the right-padded prompt (Tpad = the engine's
-    bucket), ``prompt_len [1]`` its true length, ``slot`` a traced scalar
-    (one compile per bucket, not per slot).  The forward runs against
-    fresh [1, Tpad] mini caches, so admission costs the PROMPT's tokens —
-    independent of the serving batch B (a batched-prefill admission would
-    burn B×Tpad token-forwards to fill one slot, swamping the scheduling
-    win).  Each layer's rows are then inserted into the batch cache at
-    ``slot`` — the ragged cache's per-slot reset: rows past the prompt are
-    stale pads, invisible to decode_attention's position masking and
-    overwritten as the slot decodes.  Returns the slot's first greedy
-    token (logit at its last prompt column; pad columns are causally
-    invisible to it), a ``[1]`` bool finite-logits flag (the poison-
-    quarantine input — an all-finite reduction adds no output tokens and
-    no program identity, so the clean path stays byte-identical and
-    retrace-free) and the updated caches; with ``with_hist`` the slot's
-    prompt-lookup history row is rebuilt in the same program.
-
-    ``program_key.kv_dtype`` selects the cache storage dtype — "int8"
-    makes the mini caches quantized ``(data, scale)`` pairs matching the
-    batch cache's structure, so insertion moves both leaves."""
-    _mon.mark_trace("serving_prefill_slot")
-    t = tokens.shape[1]
-    nh, nkv, hd, eps = cfg
-    kv_dtype = _pk_axis(program_key, "kv_dtype")
-    dtype = kv_dtype if kv_dtype is not None else params["embed"].dtype
-    mini = [init_kv_cache(1, t, nkv, hd, dtype)
-            for _ in params["layers"]]
-    logits, mini, _ = _forward(
-        params, cfg, tokens, mini, jnp.zeros((1,), jnp.int32),
-        last_only=True, last_idx=jnp.clip(prompt_len - 1, 0, t - 1),
-        chunk_size=chunk_size, attn_impl=_pk_axis(program_key, "attn_impl"),
-        tp_overlap=_pk_axis(program_key, "tp_overlap"))
-    first, ok = _greedy_pick(logits)                            # [1], [1]
-    slot = slot.astype(jnp.int32)
-    zero = jnp.int32(0)
-
-    def insert(c, m):
-        if isinstance(c, tuple):
-            return (jax.lax.dynamic_update_slice(
-                        c[0], m[0], (slot, zero, zero, zero)),
-                    jax.lax.dynamic_update_slice(
-                        c[1], m[1], (slot, zero, zero)))
-        return jax.lax.dynamic_update_slice(c, m.astype(c.dtype),
-                                            (slot, zero, zero, zero))
-
-    with jax.named_scope("attn.kv_write"):
-        new_caches = [(insert(kc, mk), insert(vc, mv))
-                      for (kc, vc), (mk, mv) in zip(caches, mini)]
-    if with_hist:
-        lmax = hist.shape[1]
-        row = jax.lax.dynamic_update_slice(
-            jnp.zeros((1, lmax), jnp.int32), tokens.astype(jnp.int32),
-            (0, 0))
-        row = row.at[0, jnp.clip(prompt_len[0], 0, lmax - 1)].set(first[0])
-        hist = jax.lax.dynamic_update_slice(hist, row, (slot, zero))
-        hist_len = hist_len.at[slot].set(prompt_len[0] + 1)
-    return first, ok, new_caches, hist, hist_len
-
-
-# the serving entry points ship as RAW impls plus module-level jitted
-# exports: the single-device engine dispatches the exports below, while
-# serving/sharding.py re-jits the same impls with explicit mesh in/out
-# shardings — one body, one ``mark_trace`` name, two placement strategies.
-serving_prefill_slot = _mon.wrap("serving_prefill_slot", jax.jit(
-    _serving_prefill_slot_impl,
-    static_argnames=("cfg", "with_hist", "chunk_size", "program_key"),
-    donate_argnames=("caches", "hist")))
 
 
 def _layer_prefill_chunk(lp, cfg, h, k_cache, v_cache, slot, offset,
@@ -722,19 +647,18 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
     """Process the next ``[1, P]`` chunk of an admitted prompt against the
     slot's rows of the batch cache — ONE compiled program for every prompt
     length (``P`` is the only shape; ``offset``, ``prompt_len`` and
-    ``slot`` are traced operands), replacing the per-bucket
-    ``serving_prefill_slot`` program family.
+    ``slot`` are traced operands).
 
     ``tokens [1, P]`` is the chunk, right-padded past the prompt tail;
     ``offset`` (traced scalar) is the device-carried write cursor — chunk
     rows land at cache positions ``offset + i`` and attend causally over
     every previously written row plus the intra-chunk prefix
     (ops.slot_prefill_attention), so chaining chunks at offsets 0, P,
-    2P, ... reproduces the monolithic prefill's mask exactly.  Tail pads
+    2P, ... reproduces a whole-prompt prefill's mask exactly.  Tail pads
     write garbage rows at positions ``>= prompt_len`` — causally invisible
-    and overwritten by decode appends (the monolithic bucket-pad
-    invariant).  Every chunk computes the greedy pick at the prompt's last
-    column RELATIVE to itself (``clip(prompt_len - 1 - offset, 0, P-1)``)
+    and overwritten by decode appends.  Every chunk computes the greedy
+    pick at the prompt's last column RELATIVE to itself
+    (``clip(prompt_len - 1 - offset, 0, P-1)``)
     — only the final chunk's pick is meaningful (the request's first
     token); earlier chunks return garbage the scheduler ignores, which
     keeps the program count at one instead of a final-chunk variant.
@@ -794,6 +718,10 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
     return first, ok, new_caches, hist, hist_len
 
 
+# the serving entry points ship as RAW impls plus module-level jitted
+# exports: the single-device engine dispatches the exports below, while
+# serving/sharding.py re-jits the same impls with explicit mesh in/out
+# shardings — one body, one ``mark_trace`` name, two placement strategies.
 serving_prefill_chunk = _mon.wrap("serving_prefill_chunk", jax.jit(
     _serving_prefill_chunk_impl,
     static_argnames=("cfg", "with_hist", "chunk_size", "program_key"),
@@ -1085,7 +1013,6 @@ LLAMA_FAMILY = ServingFamily(
         batch, max_len, cfg[1], cfg[2], kv_dtype),
     decode_steps=serving_decode_steps,
     prefill_chunk=serving_prefill_chunk,
-    prefill_slot=serving_prefill_slot,
     spec_step=serving_spec_step,
     spec_draft_step=serving_spec_draft_step,
     quantize_weights=quantize_decode_weights,

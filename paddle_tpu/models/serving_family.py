@@ -16,8 +16,8 @@ class to inherit, no registry.  A model names its family through
   (``ops.decode_attention.init_kv_cache``), made harmless by a slot's
   length; ``state_leaves`` describes what follows them.
 - the compiled programs, all with ``models/llama_decode.py``'s signatures and
-  names: ``decode_steps``, ``prefill_chunk`` (every family), ``prefill_slot``,
-  ``spec_step``, ``spec_draft_step`` (``None`` where the family has none).
+  names: ``decode_steps``, ``prefill_chunk`` (every family), ``spec_step``,
+  ``spec_draft_step`` (``None`` where the family has none).
 - ``quantize_weights`` (``None``: no int8 weights) and ``tp_rules`` (the
   partition rules of ``serving/sharding.py``; ``None``: no mesh).
 - ``check_options(options)``: raises ``ValueError`` for an engine option the
@@ -51,7 +51,6 @@ class ServingFamily:
     init_layer_cache: Callable
     decode_steps: Callable
     prefill_chunk: Callable
-    prefill_slot: Optional[Callable] = None
     spec_step: Optional[Callable] = None
     spec_draft_step: Optional[Callable] = None
     quantize_weights: Optional[Callable] = None

@@ -419,9 +419,9 @@ def _pick_block(n: int, preferred: int, kind: str = "") -> int:
     """Largest power-of-two-ish divisor of ``n`` at most ``preferred``.
 
     When ``kind`` is given ("q"/"k"), PADDLE_TPU_FLASH_BLOCK[_Q|_K] overrides
-    ``preferred`` for perf sweeps (bench_sweep.py).  NOTE: the enclosing
-    kernels are jax.jit'd, so the env is read at TRACE time — sweep in
-    separate processes (as bench_sweep does), not by mutating os.environ
+    ``preferred`` for perf sweeps.  NOTE: the enclosing kernels are
+    jax.jit'd, so the env is read at TRACE time — sweep in separate
+    processes (one sweep point a process), not by mutating os.environ
     between calls.  Callers passing explicit blocking (kind="") are never
     overridden."""
     if kind:

@@ -20,21 +20,16 @@ program keeps ONE static compiled shape:
   retraces in steady state), and each scheduler step spends at most
   ``prefill_budget`` chunks before dispatching the decode step, so a
   long prompt never stalls resident decode for its full prefill
-  (Sarathi-style stall-free admission; the TPOT spike the monolithic
-  path takes at admission is bounded by the budget).  The final chunk's
-  program also returns the first sampled token — it stays device-
-  resident and feeds the slot's first decode dispatch without a host
-  round-trip; the host copy is synced at the next drain.
-  ``prefill_chunk=None`` falls back to the bitwise-compatible monolithic
-  path: the whole prompt against fresh [1, bucket] mini caches — cost
-  proportional to the PROMPT, not B×bucket — inserted into the batch
-  cache at the freed slot (one compiled program per power-of-two
-  bucket).  Either way retired slots stay parked via
+  (Sarathi-style stall-free admission: the TPOT spike at admission is
+  bounded by the budget).  The final chunk's program also returns the
+  first sampled token — it stays device-resident and feeds the slot's
+  first decode dispatch without a host round-trip; the host copy is
+  synced at the next drain.  Chunked prefill is the only prefill: a
+  request is admissible when its rows (prompt + max_new + headroom) fit
+  ``max_len``.  Retired slots stay parked via
   ``ops.decode_attention.masked_lengths``: their write offset is lmax so
   every decode-step cache write DROPS — recycling needs no reshape,
-  copy-out, or recompile.  Prompts validate against the bucket set in
-  both modes (buckets bound the admissible prompt length and label the
-  per-bucket prefill counter); the slot's first token is picked from the
+  copy-out, or recompile.  The slot's first token is picked from the
   logit at its own last prompt column (pad columns are causally
   invisible to it).
 * Decode runs either mode behind one ``ServingEngine.step()``: greedy
@@ -44,16 +39,12 @@ program keeps ONE static compiled shape:
   speculation composes with mixed-length slots and emits exactly the
   verify forward's greedy picks; agreement with the 1-token-step program
   holds up to floating-point near-ties between the two program shapes).
-* ``policy="gang"`` disables mid-run admission (a batch is admitted only
-  when every slot is free and runs to completion) — the sequential
-  baseline for the bench A/B, sharing the exact same compiled programs so
-  the measured win is pure scheduling.
-* **Pipelined (double-buffered) dispatch** (``pipeline=True``, default):
-  step N+1 depends only on device-resident state — the carried ``cur``
-  tokens, caches, and lengths — so the engine dispatches it BEFORE
-  syncing step N's tokens to the host.  Host-side emit/detokenize/
-  stream-callback work and admission bookkeeping then overlap device
-  compute; the drain-side block is measured by
+* **Pipelined (double-buffered) dispatch**: step N+1 depends only on
+  device-resident state — the carried ``cur`` tokens, caches, and
+  lengths — so the engine dispatches it BEFORE syncing step N's tokens
+  to the host.  Host-side emit/detokenize/stream-callback work and
+  admission bookkeeping then overlap device compute; the drain-side
+  block is measured by
   ``serving_pipeline_stall_seconds`` and the outstanding dispatch by the
   ``serving_inflight_steps`` gauge.  The ONE device→host sync per
   iteration goes through ``_host_fetch`` (the sanctioned sync point the
@@ -66,9 +57,9 @@ program keeps ONE static compiled shape:
   they overwrite its rows, rows past a new prompt's length are invisible
   to decode_attention's position masking, and the drain discards tokens
   whose slot no longer holds the same Request object.  The extra
-  inflight dispatch is why ``_headroom`` doubles under pipelining.
-  ``pipeline=False`` restores the fully synchronous loop (the A/B
-  baseline) — token streams are byte-identical either way (tested).
+  inflight dispatch is why ``_headroom`` is doubled.  A ``prefill_only``
+  engine never decodes, so it has nothing to double-buffer: its step
+  ends in the blocking first-token flush (``_flush_firsts``).
 
 * **Paged KV cache** (``kv_block=``): the dense per-slot ``[B, Lmax]``
   cache rows become a global block pool indirected through per-slot
@@ -88,7 +79,6 @@ tracks the longest LIVE context instead of ``max_len``.
 """
 from __future__ import annotations
 
-import bisect
 import dataclasses
 import logging
 import threading
@@ -124,6 +114,10 @@ warnings.filterwarnings(
 
 __all__ = ["AcceptWindow", "EngineOverloaded", "KVPoolExhausted",
            "Request", "ServingEngine", "SpecConfig"]
+
+# the ``policy`` label of every engine metric, recorder event and SLO
+# gauge: iteration-level admission is the one scheduling policy
+_POLICY = "continuous"
 
 # the request's own transitions carry these details onto its timeline
 _MARK_KEYS = ("slot", "chunk", "final")
@@ -426,26 +420,17 @@ class ServingEngine:
     ``mode``: "greedy" or "spec" (model-free prompt-lookup speculative
     drafting, lossless — per-slot outputs byte-identical to greedy).
     ``sync_every``: greedy tokens decoded per host dispatch (inner scan);
-    retirement/admission latency is bounded by it.  ``policy``:
-    "continuous" (admit into any free slot between steps) or "gang"
-    (run-to-completion baseline).  ``prompt_buckets``: padded prefill
-    widths (default: powers of two up to ``max_len``).
+    retirement/admission latency is bounded by it.
     ``detokenizer``: optional ``ids -> str`` for streamed ``.text``.
-    ``pipeline``: double-buffer the decode loop — dispatch step N+1 before
-    syncing step N's tokens (module docstring has the one-step-late
-    retirement invariant); ``False`` is the synchronous A/B baseline with
-    byte-identical token streams.  ``decode_chunk``: KV chunk size for the
-    length-adaptive cache read (ops/decode_attention.py); ``None`` reads
-    the full ``[B, max_len]`` cache every step.  The default (256) falls
-    back to the full read automatically when ``max_len <= 256``.
+    ``decode_chunk``: KV chunk size for the length-adaptive cache read
+    (ops/decode_attention.py); ``None`` reads the full ``[B, max_len]``
+    cache every step.  The default (256) falls back to the full read
+    automatically when ``max_len <= 256``.
     ``prefill_chunk``: prompt tokens per chunked-prefill dispatch (one
-    compiled program for every prompt length; ``None`` restores the
-    monolithic per-bucket prefill — token streams byte-identical when
-    both sides resolve to the same attention read, which the default
-    ``decode_chunk`` does for every bucket <= 256).  ``prefill_budget``:
-    max prefill chunks dispatched per scheduler step before the decode
-    step goes out — bounds how long resident decode can stall on an
-    admission (both knobs tuned via ``bench_sweep.py prefill_chunk``).
+    compiled program for every prompt length; clamped to ``max_len``).
+    ``prefill_budget``: max prefill chunks dispatched per scheduler step
+    before the decode step goes out — bounds how long resident decode can
+    stall on an admission.
     ``kv_block``: paged KV cache — the per-layer cache becomes a global
     ``[num_blocks, kv_block, Hkv, D]`` pool indirected through per-slot
     block tables (serving/kv_cache.PagedKVCacheManager), with
@@ -453,8 +438,8 @@ class ServingEngine:
     pool: admission budgets total live TOKENS instead of slots, defers
     the queue head when the pool can't cover a request's worst case, and
     radix prefix hits adopt already-cached blocks so chunked prefill
-    runs only the unmatched suffix.  Requires ``prefill_chunk``; forces
-    ``decode_chunk = kv_block`` (the paged read IS the chunked loop).
+    runs only the unmatched suffix.  Forces ``decode_chunk = kv_block``
+    (the paged read IS the chunked loop).
     Token streams are byte-identical to the dense engine at f32
     (tested), and the block tables are traced operands — zero retraces
     across appends, prefix hits and evictions.
@@ -483,8 +468,8 @@ class ServingEngine:
     the float engine within a small bounded rate (quantization error can
     flip near-tied argmaxes — the tested drift budget); every
     NON-quantized invariant (parking, poison quarantine, prefix
-    adoption/accounting, pipeline drain identity, paged-vs-dense and
-    TP-vs-single-device parity WITHIN q8) stays byte-identical.
+    adoption/accounting, paged-vs-dense and TP-vs-single-device parity
+    WITHIN q8) stays byte-identical.
     ``mesh``: a ``jax.sharding.Mesh`` to tensor-parallel the compiled
     hot path across (``None`` = single-device, bitwise the pre-mesh
     engine).  Params are shard-placed once at construction under the
@@ -538,10 +523,9 @@ class ServingEngine:
     """
 
     def __init__(self, model, batch_size=8, max_len=2048, mode="greedy",
-                 spec_k=8, sync_every=1, policy="continuous",
-                 prompt_buckets=None, detokenizer=None, registry=None,
-                 instrument=True, pipeline=True, decode_chunk=256,
-                 prefill_chunk=256, prefill_budget=2, kv_block=None,
+                 spec_k=8, sync_every=1, detokenizer=None, registry=None,
+                 instrument=True, decode_chunk=256, prefill_chunk=256,
+                 prefill_budget=2, kv_block=None,
                  max_live_tokens=None, kv_dtype=None, mesh=None,
                  tp_axis="mp", max_pending=None, retry_attempts=3,
                  retry_backoff=0.05, faults=None, recorder=True,
@@ -552,8 +536,6 @@ class ServingEngine:
                  host_tier_min_blocks=1, spec=None):
         if mode not in ("greedy", "spec"):
             raise ValueError(f"unknown mode {mode!r}")
-        if policy not in ("continuous", "gang"):
-            raise ValueError(f"unknown policy {policy!r}")
         # ONE validated config for every drafting knob (SpecConfig): the
         # engine's legacy ``spec_k`` kwarg survives as the default depth,
         # everything else — draft source, draft model, adaptive window,
@@ -592,10 +574,9 @@ class ServingEngine:
         # prefill/decode disaggregation seams (serving/disagg.py).  A
         # prefill-only engine owns admission + chunked prefill and NEVER
         # dispatches a decode program: every request carries max_new=1
-        # (the first token is the prefill's own pick), pipelining is
-        # forced off so the synchronous first-token flush retires each
-        # slot before any decode dispatch could include it, and the
-        # paged admission budget shrinks to the prompt's own blocks.
+        # (the first token is the prefill's own pick), each step's
+        # blocking first-token flush retires the slot, and the paged
+        # admission budget shrinks to the prompt's own blocks.
         # ``on_prefilled(request, slot, first)`` fires after the finite
         # check + radix registration and BEFORE the slot is released —
         # the window where the block chain is still mapped and
@@ -609,7 +590,6 @@ class ServingEngine:
                 raise ValueError(
                     "prefill_only engines never decode — spec drafting "
                     "belongs to the decode worker")
-            pipeline = False
         elif on_prefilled is not None:
             raise ValueError(
                 "on_prefilled is the prefill_only completion hook — "
@@ -621,11 +601,11 @@ class ServingEngine:
                 f"mesh has no axis {tp_axis!r} (axes: {mesh.axis_names})")
         mesh_devices = int(mesh.shape[tp_axis]) if mesh is not None else 1
         # observability: purely host-side counters/gauges/histograms/spans
-        # keyed by policy (paddle_tpu/observability).  ``registry=None``
-        # feeds the process-wide registry; benches pass private registries
-        # for isolated readings.  ``instrument=False`` removes every metric
+        # (paddle_tpu/observability).  ``registry=None`` feeds the
+        # process-wide registry; the benchmark passes a private one for
+        # isolated readings.  ``instrument=False`` removes every metric
         # touch — token outputs are byte-identical either way (tested).
-        self._m = (EngineMetrics(registry, policy, int(batch_size),
+        self._m = (EngineMetrics(registry, _POLICY, int(batch_size),
                                   mesh_devices=mesh_devices)
                    if instrument else None)
         # request-scoped observability: the flight-recorder event ring,
@@ -633,7 +613,7 @@ class ServingEngine:
         # and the sliding-window SLO tracker fed at retirement — all host
         # bookkeeping riding the existing drain, never a device value
         if recorder is True:
-            recorder = FlightRecorder(policy=policy)
+            recorder = FlightRecorder(policy=_POLICY)
         elif recorder is False:
             recorder = None
         self._fr = recorder
@@ -644,7 +624,7 @@ class ServingEngine:
             self._slo = slo
         else:
             self._slo = SLOTracker(
-                objectives=slo, policy=policy,
+                objectives=slo, policy=_POLICY,
                 registry=self._m.registry if self._m is not None else None)
         self._traces = OrderedDict()   # rid -> RequestTrace, newest last
         self._trace_cap = 1024
@@ -662,46 +642,33 @@ class ServingEngine:
                 self._watchdog_probe, stall_after=float(watchdog),
                 recorder=self._fr,
                 registry=self._m.registry if self._m is not None else None,
-                component=policy).start()
+                component=_POLICY).start()
         self._B = int(batch_size)
         self._lmax = int(max_len)
         self._mode = mode
         self._spec_k = int(spec_k)
         self._sync = max(1, int(sync_every))
-        self._policy = policy
         self._detok = detokenizer
-        self._pipeline = bool(pipeline)
         self._chunk = int(decode_chunk) if decode_chunk else None
+        if not prefill_chunk or int(prefill_chunk) < 1:
+            raise ValueError(
+                f"prefill_chunk must be an int >= 1, got {prefill_chunk!r} "
+                "(chunked prefill is the only prefill)")
         # a chunk wider than the cache would only pad — clamp so small
         # max_len engines don't pay a [1, 256] forward per tiny prompt
-        self._pchunk = (min(int(prefill_chunk), self._lmax)
-                        if prefill_chunk else None)
-        if self._pchunk is not None and self._pchunk < 1:
-            raise ValueError("prefill_chunk must be >= 1 or None")
+        self._pchunk = min(int(prefill_chunk), self._lmax)
         self._pbudget = max(1, int(prefill_budget))
-        if self._dspec and self._pchunk is None:
-            raise ValueError(
-                "SpecConfig(source='draft_model') requires chunked "
-                "prefill (prefill_chunk=): the draft model's prompt KV "
-                "is built by per-chunk draft prefill dispatches riding "
-                "the admission path")
         # paged KV geometry: ``kv_block`` switches the cache to a global
         # block pool + per-slot block tables with radix prefix reuse, and
         # admission to total-live-TOKEN budgeting (``max_live_tokens``).
         # The paged read IS the chunked attention loop (one gather per
-        # chunk), so decode_chunk is forced to the block size; chunked
-        # prefill is required (the monolithic mini-cache path has no slot
-        # rows to insert into a pool), and the block/chunk sizes must
-        # divide one another so a prefix hit's suffix chunks start on the
-        # same chunk boundaries a miss would prefill — the byte-identity
-        # condition across hit/miss admission.
+        # chunk), so decode_chunk is forced to the block size, and the
+        # block/chunk sizes must divide one another so a prefix hit's
+        # suffix chunks start on the same chunk boundaries a miss would
+        # prefill — the byte-identity condition across hit/miss admission.
         self._paged = kv_block is not None
         if self._paged:
             kv_block = int(kv_block)
-            if self._pchunk is None:
-                raise ValueError(
-                    "paged KV (kv_block=) requires chunked prefill "
-                    "(prefill_chunk=)")
             if self._pchunk % kv_block and kv_block % self._pchunk:
                 raise ValueError(
                     f"prefill_chunk ({self._pchunk}) and kv_block "
@@ -722,10 +689,6 @@ class ServingEngine:
             kv_dtype=kv_dtype, weight_dtype=weight_dtype,
             attn_impl=attn_impl, prefill_impl=prefill_impl,
             tp_overlap=tp_overlap, prefill_chunk=self._pchunk))
-        if self._pchunk is None and fam.prefill_slot is None:
-            raise ValueError(
-                f"ServingEngine: the {fam.name} family has no monolithic "
-                "prefill program — prefill_chunk= is required")
         # kv_dtype: cache STORAGE dtype override.  None keeps the model
         # dtype (bitwise the pre-quantization engine — kv_dtype simply
         # never enters the program identity as a non-None static).
@@ -965,7 +928,6 @@ class ServingEngine:
                 "host_tier requires paged KV (kv_block=): only a block "
                 "pool has demotable prefix chains")
         self._host_min_blocks = max(1, int(host_tier_min_blocks))
-        self._restore_s = []   # per-admission restore wall times (bench)
         if self._paged:
             # a resident draft model is a second pool tenant: its chains
             # grow in lockstep with the target's, so the default pool
@@ -1038,19 +1000,6 @@ class ServingEngine:
         # clamped to lmax) — the mirror _spend/_dispatch draw ensure_rows
         # against
         self._need_rows = np.zeros((self._B,), np.int64)
-        if prompt_buckets is None:
-            prompt_buckets = []
-            b = 16
-            while b < self._lmax:
-                prompt_buckets.append(b)
-                b *= 2
-        self._buckets = [int(b) for b in prompt_buckets]
-        if not self._buckets or self._buckets[-1] > self._lmax:
-            raise ValueError("prompt_buckets must be non-empty and <= max_len")
-        if any(b2 <= b1 for b1, b2 in zip(self._buckets, self._buckets[1:])):
-            raise ValueError(
-                "prompt_buckets must be sorted strictly ascending (submit "
-                f"bisects over them), got {self._buckets}")
         # host mirror of the carried next-token per slot; lengths and the
         # slot -> request table live on the cache manager.  Handed to a
         # dispatch as jnp.asarray(self._cur.copy()) — a copy: the mirror is written
@@ -1068,8 +1017,9 @@ class ServingEngine:
         self._rids = set()
         # pipelined-dispatch state: the one outstanding (dispatched, not yet
         # drained) step, the device-resident carries feeding the NEXT
-        # dispatch without a host round-trip, and the slots admitted since
-        # the last dispatch (whose cur/length live host-side until mixed in)
+        # dispatch without a host round-trip, and the slots adopted
+        # (``adopt_prefilled``) since the last dispatch, whose cur/length
+        # live host-side until mixed in
         self._inflight = None
         self._dev_cur = None
         self._dev_len = None
@@ -1078,12 +1028,10 @@ class ServingEngine:
         # = admission order, the budget-spend order), the device-resident
         # first token of slots whose final chunk is dispatched but whose
         # host copy has not been drained yet, the (slot, request, first)
-        # triples awaiting host emission, and the was-a-prefill-running
-        # flag feeding the decode-interference histogram
+        # triples awaiting host emission
         self._pf = {}
         self._dev_first = {}
         self._pending_firsts = []
-        self._adm_wave = False
         self._t_lastdrain = None
         # reliability state: the bounded admission queue, the dispatch
         # retry policy, the fault-injection plan (None in production) and
@@ -1130,10 +1078,10 @@ class ServingEngine:
                 2 if self._spec is not None and self._spec.tree else 1)
         else:
             per = self._sync
-        # a pipelined engine discovers retirement one drain late, so one
+        # a decoding engine discovers retirement one drain late, so one
         # extra full dispatch of cache writes can land past the emission
         # point before the slot's offset is masked to lmax
-        return 2 * per if self._pipeline else per
+        return per if self._prefill_only else 2 * per
 
     def submit(self, request):
         if self._prefill_only and request.max_new_tokens != 1:
@@ -1142,19 +1090,12 @@ class ServingEngine:
                 "(the prefill's own first token) — decode belongs to a "
                 f"decode worker, got max_new={request.max_new_tokens}")
         p = int(request.prompt_ids.size)
-        i = bisect.bisect_left(self._buckets, p)
-        if i == len(self._buckets):
-            raise ValueError(
-                f"prompt length {p} exceeds the largest prompt bucket "
-                f"{self._buckets[-1]}")
-        bucket = self._buckets[i]
         need = p + request.max_new_tokens + self._headroom()
         if need > self._lmax:
             raise ValueError(
                 f"request needs {need} cache rows (prompt {p} + "
                 f"max_new {request.max_new_tokens} + headroom "
                 f"{self._headroom()}) > max_len {self._lmax}")
-        request._bucket = bucket
         # load shedding AFTER validation (a malformed request stays a
         # ValueError) but BEFORE rid assignment (a shed request never
         # consumes engine state): bounding what's QUEUED — resident slots
@@ -1267,11 +1208,10 @@ class ServingEngine:
         waiter is blocked (no free slot, or the block pool cannot cover
         its worst case).  Victims go lowest priority first; within a
         class the most recently submitted loses (old work keeps
-        finishing).  Paged continuous engines only — and a strict no-op
-        while every queued priority <= every resident priority, which is
-        what keeps all-default traffic byte-identical."""
-        if not self._paged or self._policy != "continuous" \
-                or not self._queue:
+        finishing).  Paged engines only — and a strict no-op while every
+        queued priority <= every resident priority, which is what keeps
+        all-default traffic byte-identical."""
+        if not self._paged or not self._queue:
             return
         top = max(self._queue, key=lambda q: q.priority)
         for _ in range(self._B):
@@ -1337,7 +1277,7 @@ class ServingEngine:
     def _forget_slot(self, slot):
         """Drop every piece of per-slot scheduler state that outlives the
         slot's request: chunked-prefill progress, the device-resident
-        first token, monolithic-admission membership and not-yet-drained
+        first token, just-adopted membership and not-yet-drained
         first-token records.  Records already riding an inflight dispatch
         need no scrub — the drain's identity check discards them."""
         self._pf.pop(slot, None)
@@ -1686,17 +1626,6 @@ class ServingEngine:
             block_tables=self._tables() if self._paged else None,
             program_key=pk)
 
-    def _call_prefill_slot(self, tokens, prompt_len, slot):
-        if self._tp is not None:
-            return self._tp.prefill_slot(self._params, tokens, prompt_len,
-                                         self._kv.caches, slot,
-                                         self._hist, self._hist_len)
-        return self._fam.prefill_slot(
-            self._params, self._cfg, tokens, prompt_len, self._kv.caches,
-            slot, hist=self._hist, hist_len=self._hist_len,
-            with_hist=self._mode == "spec", chunk_size=self._chunk,
-            program_key=self._pk)
-
     def _call_prefill_chunk(self, tokens, offset, prompt_len, slot):
         if self._tp is not None:
             if self._paged:
@@ -1800,58 +1729,6 @@ class ServingEngine:
         return self._k_cur
 
     def _admit(self):
-        free = self._kv.free_slots()
-        if not free or not self._queue:
-            return
-        if self._policy == "gang" and len(free) < self._B:
-            return  # run-to-completion: wait for the whole batch to drain
-        if self._pchunk is not None:
-            self._admit_chunked(free)
-            return
-        self._adm_wave = True
-        m = self._m
-        pending = []
-        while free and self._queue:
-            r = self._queue.popleft()
-            slot = free.pop(0)
-            self._kv.assign(slot, r)
-            self._reset_spec_slot(slot)
-            p = r.prompt_ids.size
-            with self._phase("admit", r, mark="prefilling", slot=slot,
-                             bucket=r._bucket):
-                if m is not None:
-                    m.admitted.inc()
-                    m.prefill(r._bucket)
-                    m.queue_wait.observe(time.perf_counter() - r.t_submit)
-                tokens = np.zeros((1, r._bucket), np.int32)
-                tokens[0, :p] = r.prompt_ids
-                first, okf, self._kv.caches, hist, hist_len = \
-                    self._call_prefill_slot(
-                        jnp.asarray(tokens),
-                        jnp.asarray(np.array([p], np.int32)),
-                        jnp.asarray(slot, jnp.int32))
-            if self._mode == "spec":
-                self._hist, self._hist_len = hist, hist_len
-            self._kv.lengths[slot] = p
-            self._adm_pending.add(slot)
-            pending.append((slot, first, okf))
-        # every prefill in the wave is dispatched (async) above; block ONCE
-        # here for all their first tokens (+ finite flags) — one host sync
-        # per _admit, not one per admitted request
-        vals = _host_fetch(*(x for _, f, o in pending for x in (f, o)))
-        for n, (slot, _, _) in enumerate(pending):
-            fv, ov = vals[2 * n], vals[2 * n + 1]
-            if not bool(ov[0]):
-                self._retire(slot, "poisoned")
-                continue
-            first = int(fv[0])
-            self._cur[slot] = first
-            self._emit(slot, [first])
-        if m is not None:
-            m.queue_depth.set(len(self._queue))
-            m.slots_occupied.set(self._kv.occupied())
-
-    def _admit_chunked(self, free):
         """Chunked admission: assign freed slots and queue each prompt for
         incremental chunk dispatch (``_spend_prefill``).  Nothing here
         touches the device, so admission itself never stalls the loop —
@@ -1867,6 +1744,9 @@ class ServingEngine:
         chunk is wider than the kv block the match is aligned DOWN to a
         chunk boundary so the suffix decomposes into the exact same
         compiled chunks a miss would run (byte-identity across hit/miss)."""
+        free = self._kv.free_slots()
+        if not free or not self._queue:
+            return
         m = self._m
         P = self._pchunk
         while free and self._queue:
@@ -1909,10 +1789,9 @@ class ServingEngine:
                     got = self._kv.restore_from_host(
                         tok, rid=r.rid, min_blocks=self._host_min_blocks)
                     if got:
-                        self._restore_s.append(time.perf_counter() - t0)
                         if m is not None:
                             m.tier_restore_seconds.observe(
-                                self._restore_s[-1])
+                                time.perf_counter() - t0)
                         off0, shared = self._kv.match_prefix(tok)
                 if P > C:
                     off0 = (off0 // P) * P
@@ -1935,8 +1814,12 @@ class ServingEngine:
                     break
             self._queue.remove(r)
             slot = free.pop(0)
+            # the prompt's power-of-two class: the label of the per-bucket
+            # prefill counter and the admit event
+            bucket = min(self._lmax, max(
+                16, 1 << (int(r.prompt_ids.size) - 1).bit_length()))
             with self._phase("admit", r, mark="prefilling", slot=slot,
-                             bucket=r._bucket):
+                             bucket=bucket):
                 self._kv.assign(slot, r)
                 self._reset_spec_slot(slot)
                 p = int(tok.size)
@@ -1989,7 +1872,7 @@ class ServingEngine:
                                   "plen": jnp.asarray(np.array([p], np.int32))}
                 if m is not None:
                     m.admitted.inc()
-                    m.prefill(r._bucket)
+                    m.prefill(bucket)
                     if self._paged:
                         m.prompt_tokens.inc(p)
                     m.queue_wait.observe(time.perf_counter() - r.t_submit)
@@ -2012,8 +1895,7 @@ class ServingEngine:
         slot plus pool capacity for the imported chain AND the decode
         growth budget.  The coordinator gates on this BEFORE paying for
         a transfer — a deferred migration costs nothing."""
-        if not self._paged or self._policy != "continuous" \
-                or self._prefill_only:
+        if not self._paged or self._prefill_only:
             return False
         if not self._kv.free_slots():
             return False
@@ -2025,14 +1907,12 @@ class ServingEngine:
 
     def adoption_viable(self, request):
         """The static half of ``can_adopt``: could this request EVER fit
-        this engine (prompt bucket exists, worst-case rows within
-        ``max_len``)?  The coordinator sheds statically-impossible
-        requests at submit time — a ``can_adopt`` False only ever means
-        *defer and retry*, never *abort*."""
-        p = int(request.prompt_ids.size)
-        if bisect.bisect_left(self._buckets, p) == len(self._buckets):
-            return False
-        return p + request.max_new_tokens + self._headroom() <= self._lmax
+        this engine (worst-case rows within ``max_len``)?  The coordinator
+        sheds statically-impossible requests at submit time — a
+        ``can_adopt`` False only ever means *defer and retry*, never
+        *abort*."""
+        return (int(request.prompt_ids.size) + request.max_new_tokens
+                + self._headroom() <= self._lmax)
 
     def adopt_prefilled(self, request, first, leaves):
         """Admit ``request`` with its prefill already done elsewhere:
@@ -2044,9 +1924,9 @@ class ServingEngine:
         rides the handoff, never the adoption.  Raises on capacity
         (callers gate on ``can_adopt``); a failed import rolls its
         blocks back (kv_cache.import_chain).  Returns the slot."""
-        if not self._paged or self._policy != "continuous":
+        if not self._paged:
             raise ValueError(
-                "adopt_prefilled requires a paged continuous engine "
+                "adopt_prefilled requires a paged engine "
                 "(the block pool IS the migration transfer unit)")
         if self._prefill_only:
             raise ValueError("a prefill-only engine cannot adopt decode "
@@ -2059,12 +1939,6 @@ class ServingEngine:
             raise EngineOverloaded("no free slot to adopt into")
         tok = request.prompt_ids
         p = int(tok.size)
-        i = bisect.bisect_left(self._buckets, p)
-        if i == len(self._buckets):
-            raise ValueError(
-                f"prompt length {p} exceeds the largest prompt bucket "
-                f"{self._buckets[-1]}")
-        request._bucket = self._buckets[i]
         rem = max(1, request.max_new_tokens - len(request.output_ids))
         need = min(self._lmax, p + rem + self._headroom())
         # rid bookkeeping mirrors submit(): the coordinator's rid is
@@ -2244,9 +2118,10 @@ class ServingEngine:
         return spent
 
     def _flush_firsts(self):
-        """Synchronous-mode first-token drain: block ONCE on the wave of
-        pending final chunks and emit (``pipeline=True`` instead rides
-        them on the next inflight record, fetched with its tokens)."""
+        """A prefill-only engine's drain: block ONCE on the wave of
+        pending final chunks and emit (an engine that decodes instead
+        rides them on its next inflight record, fetched with its
+        tokens)."""
         if not self._pending_firsts:
             return 0
         pend, self._pending_firsts = self._pending_firsts, []
@@ -2361,16 +2236,18 @@ class ServingEngine:
         self._apply_poison()
         self._apply_host_corrupt()
         self._maybe_preempt()
-        self._adm_wave = False
         self._admit()
         spent = self._spend_prefill()
-        # decode-interference flag for this iteration: a monolithic prefill
-        # wave ran, chunks were spent, or a prefill is still in progress
-        adm_active = self._adm_wave or spent > 0 or bool(self._pf)
-        if not self._pipeline:
-            self._adm_pending.clear()
-            out = self._step_sync(adm_active)
+        if self._prefill_only:
+            out = self._flush_firsts()
+            if any(self._decodable(i) for i in range(self._B)):
+                raise RuntimeError(
+                    "prefill-only engine reached a decode dispatch — a "
+                    "resident request survived its first-token flush")
         else:
+            # decode-interference flag for this iteration: chunks were
+            # spent, or a prefill is still in progress
+            adm_active = spent > 0 or bool(self._pf)
             # the double buffer: stash the record of the PREVIOUS
             # iteration's dispatch, issue the next dispatch, and only then
             # drain the stash — step N+1 is outstanding on the device while
@@ -2391,9 +2268,8 @@ class ServingEngine:
     def _observe_interference(self, adm_active, per_slot_tokens):
         """Feed ``serving_tpot_during_admission_seconds``: the per-token
         interval between this decode drain and the previous one, observed
-        only while admission work (monolithic wave or chunked backlog) was
-        in flight — the series the chunked-prefill A/B reads its
-        TPOT-p95-during-admission from."""
+        only while admission work (a chunked-prefill backlog) was in
+        flight."""
         now = time.perf_counter()
         if self._m is not None:
             self._m.live_tokens.set(self._kv.live_tokens())
@@ -2421,83 +2297,6 @@ class ServingEngine:
                 # lockstep from the admission-time draft reservation
                 self._kv.ensure_draft_rows(i, upto)
 
-    # ------------------------------------------------- synchronous baseline
-    def _step_sync(self, adm_active=False):
-        """``pipeline=False``: dispatch one step and block on its tokens in
-        the same iteration — the A/B baseline the pipelined loop is
-        byte-identical to."""
-        m = self._m
-        emitted = self._flush_firsts()
-        live = [i for i in range(self._B) if self._decodable(i)]
-        if not live:
-            return emitted
-        if self._prefill_only:
-            raise RuntimeError(
-                "prefill-only engine reached a decode dispatch — a "
-                "resident request survived its first-token flush")
-        self._ensure_decode_rows(live)
-        active = np.array([self._decodable(i) for i in range(self._B)])
-        dev_len = self._decode_lengths(active)
-        if self._mode == "greedy":
-            def go(attempt):
-                self._fault_point("dispatch", attempt)
-                return self._call_decode(jnp.asarray(self._cur.copy()), dev_len)
-            with self._phase("dispatch", **self._dispatch_detail(live)):
-                toks, okd, self._kv.caches = self._retry(
-                    go, "decode dispatch")
-            with self._phase("drain", mode="greedy", n_live=len(live)):
-                with self._phase("drain.wait"):
-                    toks, okd = self._fetch("drain", toks, okd)
-                with self._phase("emit"):
-                    self._observe_interference(adm_active, self._sync)
-                    for i in live:
-                        if not bool(okd[i]):
-                            self._retire(i, "poisoned")
-                            continue
-                        emitted += self._emit(i, toks[i].tolist())
-                        self._kv.lengths[i] += self._sync
-                        self._cur[i] = toks[i, -1]
-        else:
-            k = self._next_k(live)
-            self._event("draft", source=self._spec.source, k=k,
-                        n_live=len(live))
-
-            def go(attempt):
-                self._fault_point("dispatch", attempt)
-                return self._call_spec(jnp.asarray(self._cur.copy()), dev_len,
-                                       jnp.asarray(active), k)
-            with self._phase("dispatch", **self._dispatch_detail(live)):
-                blk, j, cur, _, oks, self._kv.caches, self._hist, \
-                    self._hist_len = self._retry(go, "spec dispatch")
-            with self._phase("drain", mode="spec", n_live=len(live)):
-                with self._phase("drain.wait"):
-                    blk, j, cur, oks = self._fetch("drain", blk, j, cur, oks)
-                accepted = 0
-                rounds = []
-                with self._phase("emit"):
-                    for i in live:
-                        if not bool(oks[i]):
-                            self._retire(i, "poisoned")
-                            continue
-                        emitted += self._emit(
-                            i, blk[i, :int(j[i]) + 1].tolist())
-                        self._kv.lengths[i] += int(j[i]) + 1
-                        self._cur[i] = cur[i]
-                        accepted += int(j[i])
-                        rounds.append((i, int(j[i])))
-            self._event("verify", k=k, drafted=k * len(rounds),
-                        accepted=accepted)
-            self._event("rewind", tokens=k * len(rounds) - accepted)
-            self._adapt_k(rounds, k)
-            self._observe_interference(
-                adm_active, 1.0 + accepted / len(live))
-            if m is not None:
-                # per verify round each live slot drafts k and accepts
-                # j of them (the +1 bonus token is the verify forward's own
-                # pick, not a draft)
-                m.spec_round(k * len(live), accepted)
-        return emitted
-
     # --------------------------------------------------- pipelined dispatch
     def _dispatch(self, adm_active=False):
         """Dispatch the next decode step WITHOUT waiting for the previous
@@ -2514,17 +2313,16 @@ class ServingEngine:
         if not live:
             return
         self._ensure_decode_rows(live)
-        with self._phase("dispatch",
-                         **self._dispatch_detail(live, pipelined=True)):
+        with self._phase("dispatch", **self._dispatch_detail(live)):
             self._dispatch_live(live, adm_active)
 
-    def _dispatch_detail(self, live, **extra):
+    def _dispatch_detail(self, live):
         """What a ``dispatch`` phase says of itself: the batch it runs
         over and the engine's storage / kernel knobs."""
         return dict(mode=self._mode, n_live=len(live), kv_quant=self._kvq,
                     attn_impl=self._attn_label,
                     prefill_impl=self._prefill_label,
-                    weight_dtype=self._wq_label, **extra)
+                    weight_dtype=self._wq_label)
 
     def _dispatch_live(self, live, adm_active):
         m = self._m
@@ -2598,8 +2396,7 @@ class ServingEngine:
         host-visible half of the one-step-late retirement invariant."""
         if rec is None:
             return 0
-        with self._phase("drain", mode=rec["kind"], n_live=len(rec["live"]),
-                         pipelined=True):
+        with self._phase("drain", mode=rec["kind"], n_live=len(rec["live"])):
             return self._drain_record(rec)
 
     def _drain_record(self, rec):

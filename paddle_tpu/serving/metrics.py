@@ -2,8 +2,8 @@
 
 Extracted from serving/engine.py alongside the KVCacheManager so the
 engine file holds scheduling logic only.  The series live in ``registry``
-(default: the process-wide one) keyed by a ``policy`` label, so a
-continuous engine and its gang baseline stay separable in one scrape.
+(default: the process-wide one) keyed by a ``policy`` label, which the
+engine sets to its one scheduling policy, ``"continuous"``.
 All instrumentation is host-side bookkeeping — the compiled device
 programs are untouched, which is what keeps the instrumented engine's
 token outputs byte-identical to an uninstrumented run (tested:
@@ -192,9 +192,9 @@ class EngineMetrics:
             L).labels(**lbl)
         self.tpot_admission = reg.histogram(
             "serving_tpot_during_admission_seconds",
-            "per-token decode interval observed while a prefill "
-            "(monolithic or chunked) was in progress — the decode-"
-            "interference histogram", L).labels(**lbl)
+            "per-token decode interval observed while a chunked prefill "
+            "was in progress — the decode-interference histogram",
+            L).labels(**lbl)
         self.pipeline_stall = reg.histogram(
             "serving_pipeline_stall_seconds",
             "drain-side block waiting on the inflight dispatch",
@@ -204,8 +204,8 @@ class EngineMetrics:
             "device steps dispatched but not yet drained", L).labels(**lbl)
         # paged-KV series (PagedKVCacheManager): block-pool occupancy,
         # the token-budget admission numerator, and the prefix-reuse
-        # counters the shared-prefix bench derives its hit rate from
-        # (reuse / prompt tokens).  Zero-valued on dense engines —
+        # counters a prefix hit rate derives from (reuse / prompt
+        # tokens).  Zero-valued on dense engines —
         # pre-registered like every other family
         # pool occupancy is TENANT-split: target = the served model's
         # chains plus evictable cached prefixes, draft = the resident
@@ -292,7 +292,7 @@ class EngineMetrics:
         # /debug/flightrecorder's kv_quant dispatch detail) states the
         # storage mode without string-valued metrics, plus the analytic
         # per-context-token KV traffic at int8 (0 on unquantized
-        # engines; the bench A/B pins it at ~0.53x the bf16 column)
+        # engines; ~0.53x the bf16 figure)
         self._kv_quant_mode = reg.gauge(
             "serving_kv_quant_mode",
             "KV cache quantization mode info gauge: the child whose "
@@ -309,8 +309,7 @@ class EngineMetrics:
         # (weight_dtype=): the same info-gauge shape as kv_quant_mode —
         # every known child pre-registered to 0 so a scrape always shows
         # the full mode set, the active child set to 1 at construction —
-        # plus the analytic int8-weight traffic column the bench A/B
-        # pins against the bf16-weight baseline
+        # plus the analytic int8-weight traffic figure
         self._decode_kernel = reg.gauge(
             "serving_decode_kernel",
             "decode cache-read implementation info gauge: 'fused' (the "

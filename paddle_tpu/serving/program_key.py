@@ -77,7 +77,7 @@ class StaticAxis:
 
 #: THE registry.  One row per static knob; every consumer (the serving
 #: impls' ``program_key`` static, the engine's constructor kwargs, the TP
-#: program-cache key, bench_sweep axes, PTL014) derives from this tuple.
+#: program-cache key, PTL014) derives from this tuple.
 PROGRAM_AXES = (
     StaticAxis(
         "attn_impl", None,

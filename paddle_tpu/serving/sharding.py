@@ -57,8 +57,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from paddle_tpu.models.llama_decode import (
     _mon, _serving_decode_steps_impl, _serving_prefill_chunk_impl,
-    _serving_prefill_slot_impl, _serving_spec_draft_step_impl,
-    _serving_spec_step_impl,
+    _serving_spec_draft_step_impl, _serving_spec_step_impl,
 )
 
 __all__ = ["match_partition_rules", "llama_tp_rules", "kv_cache_pspec",
@@ -207,8 +206,6 @@ class TPPrograms:
     grow one trailing replicated ``tables`` operand and the cache
     shardings apply to the ``[num_blocks, C, Hkv, D]`` pools (same
     ``kv_cache_pspec`` — the head axis is index 2 in both geometries).
-    ``prefill_slot`` stays dense-only; the paged engine always runs
-    chunked prefill.
     """
 
     def __init__(self, mesh, axis, cfg, param_specs, n_layers, *,
@@ -394,17 +391,6 @@ class TPPrograms:
                               hshard, repl),
                 out_shardings=(repl, repl, cshard, hshard, repl),
                 donate_argnums=(4, 6) if with_hist else (4,)))
-
-        def pslot(params, tokens, prompt_len, caches, slot, hist, hist_len):
-            return _serving_prefill_slot_impl(
-                params, cfg, tokens, prompt_len, caches, slot,
-                hist=hist, hist_len=hist_len, with_hist=with_hist,
-                chunk_size=chunk_size, program_key=program_key)
-        self.prefill_slot = _mon.wrap("serving_prefill_slot", jax.jit(
-            pslot,
-            in_shardings=(pshard, repl, repl, cshard, repl, hshard, repl),
-            out_shardings=(repl, repl, cshard, hshard, repl),
-            donate_argnums=(3, 5) if with_hist else (3,)))
 
 
 # process-wide: two engines with the same (mesh, specs, statics) must

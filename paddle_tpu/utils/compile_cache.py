@@ -2,7 +2,7 @@
 
 The 16-layer serving and training programs take minutes to compile cold,
 and every fresh process would pay that again.  The entry points
-(``chip_smoke.py``, ``bench.py``, ``bench_sweep.py``,
+(``chip_smoke.py``, ``benchmark/run.py``,
 ``python -m paddle_tpu.serving.worker``) call :func:`enable_compile_cache`
 once, before their first compile; ``import paddle_tpu`` never does — a
 library import must not decide where a process writes.

@@ -1,7 +1,8 @@
 """Chipless compiles for a described TPU v5e: every Pallas kernel on the
-serving and training main paths, at the real widths of the ``bench.py`` /
-``chip_smoke.py`` model (hidden 2048, 16 heads / 4 kv heads, head dim 128,
-Lmax 2048, batch 8 serving / 16 training, bf16 and int8+f16-scale caches).
+serving and training main paths, at the real widths of the ``chip_smoke.py``
+model (hidden 2048, 16 heads / 4 kv heads, head dim 128, Lmax 2048, batch 8
+serving / 16 training, bf16 and int8+f16-scale caches) and, further down, of
+the benchmark's configurations (``benchmark/configs/``).
 
 Interpret-mode parity suites run the kernels' LOGIC on the CPU; they cannot
 see what the TPU lowering refuses (block shapes off the (8, 128) tiling, f16
@@ -138,7 +139,7 @@ def test_fused_prefill_kernel_compiles(one_chip, kind):
 
 
 def test_flash_attention_fwd_bwd_compiles(one_chip):
-    """The training attention: causal GQA flash fwd + bwd at the bench
+    """The training attention: causal GQA flash fwd + bwd at the smoke
     batch (B16 x L2048 x H16/Hkv4 x D128, bf16)."""
     from paddle_tpu.ops.flash_attention import flash_attention_blhd
 

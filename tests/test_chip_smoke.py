@@ -100,53 +100,9 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch):
         assert ".jax_cache/" in f.read().split()
 
 
-# ---------------------------------------------------------------------------
-# bench.py: no result without naming the device, no failure swallowed
-# ---------------------------------------------------------------------------
-
 class _FakeDevice:
     platform = "tpu"
     device_kind = "TPU v9 imaginary"
-
-
-@pytest.fixture
-def bench(monkeypatch):
-    import bench as bench_mod
-
-    monkeypatch.setattr(compile_cache, "enable_compile_cache",
-                        lambda: "(off under test)")
-    return bench_mod
-
-
-def test_bench_unknown_device_kind_is_an_error(bench, monkeypatch):
-    with pytest.raises(RuntimeError, match="no peak FLOP/s on record"):
-        bench._peak_tflops()          # the CPU the tests run on
-    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeDevice()])
-    with pytest.raises(RuntimeError, match="TPU v9 imaginary"):
-        bench._peak_tflops()
-
-
-@pytest.mark.parametrize("fails", [False, True], ids=["ok", "phase-fails"])
-def test_bench_line_names_the_device_and_failure_exits_nonzero(
-        bench, monkeypatch, capsys, fails):
-    def bench_eager():
-        if fails:
-            raise ValueError("boom")
-        return {"eager_train_steps_per_sec": 1.0}
-
-    monkeypatch.setattr(bench, "bench_eager", bench_eager)
-    monkeypatch.setenv("BENCH_ONLY", "bench_eager")
-    if fails:
-        with pytest.raises(SystemExit) as e:
-            bench.main()
-        assert e.value.code == 1
-    else:
-        bench.main()
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["device"] == {"platform": "cpu",
-                              "kind": jax.devices()[0].device_kind,
-                              "count": len(jax.devices())}
-    assert ("bench_eager_error" in line) == fails
 
 
 def test_device_spec_detect_has_no_silent_default(monkeypatch):

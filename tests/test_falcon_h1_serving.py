@@ -262,12 +262,11 @@ def gap_under_reference(config, r):
     return float((want.max(-1) - want[np.arange(len(out)), out]).max())
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_engine_serves_the_references_tokens(config, model, pipeline):
+def test_engine_serves_the_references_tokens(config, model):
     """Continuous batching over 2 slots: 6 requests of mixed lengths, so
     every slot is reused, prompts are admitted while others decode, and
     chunks of several prompts interleave."""
-    eng = engine(model, pipeline=pipeline)
+    eng = engine(model)
     reqs = [eng.submit(Request(p, n)) for p, n in zip(
         prompts((21, 9, 30, 16, 32, 3), seed=2), (5, 7, 4, 6, 3, 8))]
     eng.run()
@@ -281,19 +280,18 @@ def state_of(eng, slot):
             for layer in eng._kv.caches]
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_a_reused_slot_serves_what_a_fresh_engine_serves(model, pipeline):
+def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
     """One slot, two requests one after the other: the second request's
     tokens AND the slot's final state and tail are those of a fresh engine
     that only ever saw the second — the first tenant, the pipeline's
     one-step-late stale step after it retired, and the padded end of its
     last chunk left nothing behind."""
     first, second = prompts((27, 19), seed=6)
-    used = engine(model, batch_size=1, pipeline=pipeline)
+    used = engine(model, batch_size=1)
     a = used.submit(Request(first, 9))
     b = used.submit(Request(second, 7))
     used.run()
-    fresh = engine(model, batch_size=1, pipeline=pipeline)
+    fresh = engine(model, batch_size=1)
     c = fresh.submit(Request(second, 7))
     fresh.run()
     assert a.status == b.status == c.status == "done"
@@ -385,7 +383,7 @@ def test_state_counters(model):
     (dict(weight_dtype="int8"), "no int8 weight quantizer"),
     (dict(attn_impl="pallas"), "fused cache-read kernel"),
     (dict(prefill_impl="pallas"), "fused prefill kernel"),
-    (dict(prefill_chunk=None), "no monolithic prefill program"),
+    (dict(prefill_chunk=None), "chunked prefill is the only prefill"),
     (dict(prefill_chunk=12), "multiple of mamba_chunk_size"),
 ])
 def test_unsupported_options_raise_at_construction(model, option, missing):
@@ -426,7 +424,7 @@ def test_llama_family_is_the_llama_programs():
     assert fam.name == "llama" and fam.state_leaves == ()
     assert fam.decode_steps is ld.serving_decode_steps
     assert fam.prefill_chunk is ld.serving_prefill_chunk
-    assert fam.prefill_slot is ld.serving_prefill_slot
+    assert not hasattr(fam, "prefill_slot")
     assert fam.spec_step is ld.serving_spec_step
     assert fam.spec_draft_step is ld.serving_spec_draft_step
     params, cfg = fam.decode_params(llama, LMAX)

@@ -171,7 +171,7 @@ def test_flash_attention_packed_rope_parity():
     """Rope fused INTO the flash kernels (q/k rotate on VMEM tiles, bwd
     re-rotates from raw residuals and inverse-rotates dq/dk in-kernel):
     values + grads match rotate-then-attend.  Not routed by the model at
-    bench shapes (measured slower there — BENCH_NOTES r5); parity keeps
+    bench shapes (measured slower there, round 5); parity keeps
     the op usable where the tradeoff inverts."""
     from paddle_tpu.ops.flash_attention import (flash_attention_packed,
                                                 flash_attention_packed_rope)

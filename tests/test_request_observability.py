@@ -3,10 +3,10 @@ SLO tracking, /debug endpoints).
 
 The load-bearing properties: (1) recording is pure host bookkeeping —
 token outputs are BYTE-IDENTICAL recorder-on vs recorder-off across
-greedy/spec × pipeline on/off, with zero retraces over a ragged mixed
-workload; (2) an anomaly (timeout / poison / retry exhaustion) auto-dumps
-exactly one flight-recorder snapshot that reconstructs the request's full
-lifecycle; (3) the /debug/* JSON endpoints are safe to scrape from
+greedy/spec, with zero retraces over a ragged mixed workload; (2) an
+anomaly (timeout / poison / retry exhaustion) auto-dumps exactly one
+flight-recorder snapshot that reconstructs the request's full lifecycle;
+(3) the /debug/* JSON endpoints are safe to scrape from
 another thread while the engine serves.
 """
 import json
@@ -175,13 +175,11 @@ class TestRequestTimeline:
 # ------------------------------------------ identity + retrace acceptance
 class TestRecorderByteIdentity:
     @pytest.mark.parametrize("mode", ["greedy", "spec"])
-    @pytest.mark.parametrize("pipeline", [False, True])
-    def test_outputs_identical_recorder_on_off(self, mode, pipeline):
+    def test_outputs_identical_recorder_on_off(self, mode):
         """Acceptance: the recorder-on engine's outputs are byte-identical
-        to recorder-off across greedy/spec × pipeline on/off on a ragged
-        mixed workload."""
+        to recorder-off across greedy/spec on a ragged mixed workload."""
         model = _tiny_model()
-        kw = dict(mode=mode, pipeline=pipeline)
+        kw = dict(mode=mode)
         if mode == "spec":
             kw["spec_k"] = 4
         eng_on, on = _run_ragged(model, **kw)
@@ -200,9 +198,9 @@ class TestRecorderByteIdentity:
         identity."""
         from paddle_tpu.analysis import assert_no_retrace
         model = _tiny_model()
-        _run_ragged(model, pipeline=True)        # warmup traces
+        _run_ragged(model)        # warmup traces
         with assert_no_retrace():
-            _run_ragged(model, pipeline=True)
+            _run_ragged(model)
 
 
 # ----------------------------------------------------- anomaly auto-dumps

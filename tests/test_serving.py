@@ -23,6 +23,13 @@ def _tiny_model(seed=0):
     return model
 
 
+def _greedy_ref(model, prompt, n, max_len):
+    """``decode_greedy`` of one prompt alone: the independent reference."""
+    return np.asarray(decode_greedy(
+        model, paddle.to_tensor(prompt[None], dtype="int64"),
+        max_new_tokens=n, max_len=max_len))[0].tolist()
+
+
 def _run(model, prompts, new_lens, **kw):
     eng = ServingEngine(model, **kw)
     for p, n in zip(prompts, new_lens):
@@ -43,11 +50,8 @@ class TestServingSmoke:
         new_lens = [6, 4, 8, 5]
         outs = _run(model, prompts, new_lens, batch_size=2, max_len=64)
         for i, (p, n) in enumerate(zip(prompts, new_lens)):
-            ref = np.asarray(decode_greedy(
-                model, paddle.to_tensor(p[None], dtype="int64"),
-                max_new_tokens=n, max_len=64))[0]
             r = outs[i]
-            np.testing.assert_array_equal(np.array(r.output_ids), ref)
+            assert list(r.output_ids) == _greedy_ref(model, p, n, 64)
             assert r.done and r.t_done >= r.t_first >= r.t_submit
 
     def test_streaming_and_detokenizer(self):
@@ -66,14 +70,25 @@ class TestServingSmoke:
         eng = ServingEngine(model, batch_size=2, max_len=32)
         with pytest.raises(ValueError, match="cache rows"):
             eng.submit(Request(np.arange(16), 32))
-        with pytest.raises(ValueError, match="bucket"):
+        with pytest.raises(ValueError, match="cache rows"):
             eng.submit(Request(np.arange(40), 4))
         with pytest.raises(ValueError, match="max_new_tokens"):
             Request(np.arange(4), 0)
         with pytest.raises(ValueError):
             ServingEngine(model, mode="beam")
-        with pytest.raises(ValueError):
-            ServingEngine(model, policy="fifo")
+        with pytest.raises(ValueError, match="only prefill"):
+            ServingEngine(model, prefill_chunk=None)
+        with pytest.raises(ValueError, match="only prefill"):
+            ServingEngine(model, prefill_chunk=0)
+
+    @pytest.mark.parametrize("removed", [
+        dict(policy="fifo"), dict(policy="continuous"),
+        dict(pipeline=False), dict(prompt_buckets=(8, 16))])
+    def test_removed_options_are_rejected(self, removed):
+        """One prefill, one policy, one step loop: the keywords that used
+        to select another are not accepted."""
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            ServingEngine(_tiny_model(), **removed)
 
 
 class TestAdmissionInvariance:
@@ -113,20 +128,6 @@ class TestAdmissionInvariance:
         s = _run(model, prompts, new_lens, mode="spec", spec_k=4, **kw)
         for i in g:
             np.testing.assert_array_equal(s[i].output_ids, g[i].output_ids)
-
-    def test_gang_policy_matches_continuous_outputs(self):
-        """The run-to-completion baseline produces identical per-request
-        outputs — only the schedule (and the wall-clock) differs."""
-        model = _tiny_model()
-        rng = np.random.default_rng(4)
-        prompts = [rng.integers(0, 256, (p,)) for p in (5, 11, 7, 9)]
-        new_lens = [4, 9, 6, 11]
-        kw = dict(batch_size=2, max_len=64)
-        cont = _run(model, prompts, new_lens, policy="continuous", **kw)
-        gang = _run(model, prompts, new_lens, policy="gang", **kw)
-        for i in cont:
-            np.testing.assert_array_equal(gang[i].output_ids,
-                                          cont[i].output_ids)
 
 
 class TestRequestTiming:
@@ -220,29 +221,12 @@ class TestRetirement:
 
 
 class TestPipelinedDispatch:
-    """pipeline=True double-buffers the decode loop: step N+1 is dispatched
+    """The engine double-buffers the decode loop: step N+1 is dispatched
     before step N's tokens are synced, so host emit/admit work overlaps
-    device compute.  The contract under test: token streams byte-identical
-    to the synchronous engine (pipeline=False) across modes and policies —
-    including slots that retire while a step is already inflight (the
-    one-step-late retirement invariant)."""
-
-    def test_pipeline_matches_sync_all_modes(self):
-        model = _tiny_model(seed=8)
-        rng = np.random.default_rng(8)
-        prompts = [rng.integers(0, 256, (p,)) for p in (5, 9, 12, 7, 10, 6)]
-        new_lens = [6, 11, 4, 9, 13, 8]
-        for kw in (dict(mode="greedy", policy="continuous", sync_every=2),
-                   dict(mode="greedy", policy="gang"),
-                   dict(mode="spec", spec_k=4, policy="continuous")):
-            # decode_chunk small enough to exercise the chunked read at
-            # this max_len (the default 256 would fall back to full)
-            base = dict(batch_size=2, max_len=64, decode_chunk=16, **kw)
-            sync = _run(model, prompts, new_lens, pipeline=False, **base)
-            pipe = _run(model, prompts, new_lens, pipeline=True, **base)
-            for i in sync:
-                np.testing.assert_array_equal(pipe[i].output_ids,
-                                              sync[i].output_ids)
+    device compute.  The contract under test: one dispatch stays
+    outstanding between iterations, and a slot that retires while a step
+    is already inflight leaves every stream as a fresh engine serves it
+    (the one-step-late retirement invariant)."""
 
     def test_step_leaves_a_dispatch_outstanding(self):
         """The double buffer is real: each iteration drains the PREVIOUS
@@ -250,7 +234,7 @@ class TestPipelinedDispatch:
         dispatched step stays inflight (regression: dispatch-then-drain of
         the SAME record in one iteration — no overlap at all)."""
         model = _tiny_model(seed=11)
-        eng = ServingEngine(model, batch_size=1, max_len=64, pipeline=True)
+        eng = ServingEngine(model, batch_size=1, max_len=64)
         r = eng.submit(Request(np.arange(1, 7), 4))
         eng.step()  # admit + final prefill chunk + dispatch step 1; the
         # first token is a device future riding the inflight record
@@ -277,7 +261,7 @@ class TestPipelinedDispatch:
         ref = _run(model, [other], [7], batch_size=1, max_len=64)[0]
         # batch_size=1 forces the race: every drain-retirement happens with
         # a dispatched step for the same slot outstanding
-        eng = ServingEngine(model, batch_size=1, max_len=64, pipeline=True)
+        eng = ServingEngine(model, batch_size=1, max_len=64)
         r0 = eng.submit(Request(prompt, 8, eos_token_id=eos))
         r1 = eng.submit(Request(other, 7))
         eng.run()
@@ -286,7 +270,7 @@ class TestPipelinedDispatch:
         np.testing.assert_array_equal(r1.output_ids, ref.output_ids)
 
     def test_ragged_serving_steps_are_retrace_free(self):
-        """Acceptance: once a warmup run has traced the prefill bucket and
+        """Acceptance: once a warmup run has traced the prefill chunk and
         the decode step, a second mixed ragged run — admissions,
         retirements, pipelined double-buffered dispatch, chunked reads —
         triggers ZERO retraces: the chunked trip count is a traced scalar,
@@ -298,7 +282,7 @@ class TestPipelinedDispatch:
         rng = np.random.default_rng(12)
         prompts = [rng.integers(0, 256, (p,)) for p in (5, 9, 14, 7)]
         new_lens = [6, 4, 9, 5]
-        kw = dict(batch_size=2, max_len=64, decode_chunk=16, pipeline=True)
+        kw = dict(batch_size=2, max_len=64, decode_chunk=16)
         _run(model, prompts, new_lens, **kw)  # warmup: the legitimate traces
         with assert_no_retrace():
             _run(model, prompts, new_lens, **kw)
@@ -310,8 +294,7 @@ class TestPipelinedDispatch:
 
         model = _tiny_model(seed=10)
         reg = MetricsRegistry()
-        eng = ServingEngine(model, batch_size=2, max_len=64, registry=reg,
-                            pipeline=True)
+        eng = ServingEngine(model, batch_size=2, max_len=64, registry=reg)
         eng.submit(Request(np.arange(1, 8), 6))
         eng.submit(Request(np.arange(2, 12), 5))
         done = eng.run()
@@ -324,32 +307,66 @@ class TestPipelinedDispatch:
 
 class TestChunkedPrefill:
     """Chunked prefill (serving_prefill_chunk) under budgeted
-    prefill/decode interleaving: byte-identical to the monolithic
-    per-bucket path, O(1) compiled programs, retrace-free steady state,
+    prefill/decode interleaving: byte-identical to ``decode_greedy``,
+    O(1) compiled programs, retrace-free steady state,
     and invisible to resident decode streams."""
 
-    @pytest.mark.parametrize("pipeline", [False, True])
     @pytest.mark.parametrize("mode", ["greedy", "spec"])
-    def test_parity_matrix_vs_monolithic(self, mode, pipeline):
-        """Byte-identity across prompt lengths that are <, =, a multiple
-        of, and a non-multiple of the chunk size (P=8), in both scheduler
-        modes with the pipeline on and off."""
+    def test_chunked_prefill_matches_decode_greedy(self, mode):
+        """Byte-identity with ``decode_greedy`` of each prompt — the
+        independent reference — across prompt lengths that are <, =, a
+        multiple of, and a non-multiple of the chunk size (P=8), in both
+        scheduler modes."""
         model = _tiny_model(seed=21)
         rng = np.random.default_rng(21)
         prompts = [rng.integers(0, 256, (p,)) for p in (5, 8, 16, 13)]
         new_lens = [6, 5, 4, 7]
-        kw = dict(batch_size=2, max_len=64, mode=mode, pipeline=pipeline)
-        mono = _run(model, prompts, new_lens, prefill_chunk=None, **kw)
-        chunk = _run(model, prompts, new_lens, prefill_chunk=8,
-                     prefill_budget=2, **kw)
-        for i in range(len(prompts)):
-            assert list(chunk[i].output_ids) == list(mono[i].output_ids)
+        chunk = _run(model, prompts, new_lens, batch_size=2, max_len=64,
+                     mode=mode, prefill_chunk=8, prefill_budget=2)
+        for i, (p, n) in enumerate(zip(prompts, new_lens)):
+            assert list(chunk[i].output_ids) == _greedy_ref(model, p, n, 64)
+
+    @pytest.mark.parametrize("paged", [{}, dict(kv_block=8)],
+                             ids=["dense", "paged"])
+    def test_prompt_above_half_of_max_len_is_served(self, paged):
+        """Admission is by rows alone (prompt + max_new + headroom within
+        ``max_len``): a 44-token prompt in a 64-row engine is served, and
+        as ``decode_greedy`` serves it; a decode worker calls it viable;
+        one row too many is still refused."""
+        model = _tiny_model(seed=25)
+        rng = np.random.default_rng(25)
+        prompt = rng.integers(0, 256, (44,))
+        eng = ServingEngine(model, batch_size=2, max_len=64,
+                            prefill_chunk=8, **paged)
+        assert eng.adoption_viable(Request(prompt, 8))
+        r = eng.submit(Request(prompt, 8))
+        eng.run()
+        assert list(r.output_ids) == _greedy_ref(model, prompt, 8, 64)
+        too_long = Request(rng.integers(0, 256, (55,)), 8)    # 55 + 8 + 2
+        assert not eng.adoption_viable(too_long)
+        with pytest.raises(ValueError, match="cache rows"):
+            eng.submit(too_long)
+
+    @pytest.mark.parametrize("prompt_len,bucket", [
+        (5, 16), (16, 16), (17, 32), (40, 64), (70, 100)])
+    def test_prefill_counter_is_labelled_by_the_prompts_power_of_two(
+            self, prompt_len, bucket):
+        """``serving_prefill_total``'s ``bucket`` is the next power of two
+        at or above the prompt length, from 16 up to ``max_len``."""
+        from paddle_tpu.observability import MetricsRegistry
+
+        reg = MetricsRegistry()
+        eng = ServingEngine(_tiny_model(), batch_size=1, max_len=100,
+                            prefill_chunk=8, registry=reg)
+        eng.submit(Request(np.arange(prompt_len) % 256, 2))
+        eng.run()
+        counter = reg.get("serving_prefill_total")
+        assert counter.labels(policy="continuous", bucket=bucket).value == 1
 
     def test_prefill_program_count_is_o1(self):
-        """Eight DISTINCT prompt lengths across three buckets cost exactly
-        ONE serving_prefill_chunk trace — the per-bucket program family is
-        gone (offset / prompt_len / slot are traced operands; only the
-        chunk width P is a shape)."""
+        """Eight DISTINCT prompt lengths cost exactly ONE
+        serving_prefill_chunk trace (offset / prompt_len / slot are traced
+        operands; only the chunk width P is a shape)."""
         from paddle_tpu.models.llama_decode import _mon
 
         model = _tiny_model(seed=22)
@@ -357,16 +374,12 @@ class TestChunkedPrefill:
         lens = (3, 5, 7, 9, 11, 14, 17, 21)
         prompts = [rng.integers(0, 256, (p,)) for p in lens]
         before = _mon.trace_counts().get("serving_prefill_chunk", 0)
-        mono_before = _mon.trace_counts().get("serving_prefill_slot", 0)
         _run(model, prompts, [3] * len(lens), batch_size=2, max_len=64,
-             prefill_chunk=8, prompt_buckets=(8, 16, 24))
+             prefill_chunk=8)
         # at most ONE new program for eight distinct lengths (zero when an
         # earlier test in this process already traced the P=8 program —
         # the jit cache is process-wide, which is exactly the point)
         assert _mon.trace_counts()["serving_prefill_chunk"] - before <= 1
-        # and the monolithic family was never touched
-        assert _mon.trace_counts().get(
-            "serving_prefill_slot", 0) == mono_before
 
     def test_staggered_admissions_are_retrace_free(self):
         """Acceptance: steady-state serving with long prompts admitted
@@ -380,7 +393,7 @@ class TestChunkedPrefill:
         def go():
             eng = ServingEngine(model, batch_size=2, max_len=64,
                                 prefill_chunk=4, prefill_budget=1,
-                                decode_chunk=16, pipeline=True)
+                                decode_chunk=16)
             eng.submit(Request(rng.integers(0, 256, (17,)), 6))
             for _ in range(3):
                 eng.step()
@@ -404,7 +417,7 @@ class TestChunkedPrefill:
         prompt = rng.integers(0, 256, (6,))
         other = rng.integers(0, 256, (21,))
         kw = dict(batch_size=2, max_len=64, prefill_chunk=4,
-                  prefill_budget=1, pipeline=True)
+                  prefill_budget=1)
         eng = ServingEngine(model, **kw)
         alone = eng.submit(Request(prompt.copy(), 10))
         eng.run()
@@ -420,15 +433,15 @@ class TestChunkedPrefill:
 
 
 class TestSubmitValidation2:
-    """rid bookkeeping and bucket-order validation (PR-5 satellites)."""
+    """rid bookkeeping (PR-5 satellites)."""
 
     def test_auto_rids_only_advance_on_assignment(self):
         model = _tiny_model()
         eng = ServingEngine(model, batch_size=2, max_len=64)
         r0 = eng.submit(Request(np.arange(1, 5), 2))
         assert r0.rid == 0
-        with pytest.raises(ValueError, match="bucket"):
-            eng.submit(Request(np.arange(0, 40), 2))
+        with pytest.raises(ValueError, match="cache rows"):
+            eng.submit(Request(np.arange(0, 70), 2))
         # the rejected submit must not have burned an auto rid
         assert eng.submit(Request(np.arange(1, 6), 2)).rid == 1
 
@@ -449,15 +462,6 @@ class TestSubmitValidation2:
         eng = ServingEngine(model, batch_size=2, max_len=64)
         eng.submit(Request(np.arange(1, 5), 2, rid=5))
         assert eng.submit(Request(np.arange(1, 6), 2)).rid == 6
-
-    def test_unsorted_buckets_rejected(self):
-        model = _tiny_model()
-        with pytest.raises(ValueError, match="sorted strictly ascending"):
-            ServingEngine(model, batch_size=2, max_len=64,
-                          prompt_buckets=(16, 8, 32))
-        with pytest.raises(ValueError, match="sorted strictly ascending"):
-            ServingEngine(model, batch_size=2, max_len=64,
-                          prompt_buckets=(8, 8, 16))
 
 
 class TestKVCacheGuards:
@@ -489,9 +493,8 @@ class TestKVCacheGuards:
 
 @pytest.mark.slow
 class TestServingMixedWorkload:
-    """Long mixed-length workload (the bench_serving shape in miniature):
-    every request completes, outputs are byte-identical across the
-    continuous scheduler, the gang baseline, and speculative serving."""
+    """Long mixed-length workload: every request completes, outputs are
+    byte-identical across the greedy scheduler and speculative serving."""
 
     def test_mixed_lengths_all_policies_agree(self):
         model = _tiny_model(seed=7)
@@ -502,12 +505,9 @@ class TestServingMixedWorkload:
         prompts = [rng.integers(0, 256, (p,)) for p in plens]
         kw = dict(batch_size=4, max_len=128)
         cont = _run(model, prompts, olens, sync_every=2, **kw)
-        gang = _run(model, prompts, olens, policy="gang", **kw)
         spec = _run(model, prompts, olens, mode="spec", spec_k=4, **kw)
         assert len(cont) == n_req
         for i in range(n_req):
             assert len(cont[i].output_ids) == olens[i]
-            np.testing.assert_array_equal(gang[i].output_ids,
-                                          cont[i].output_ids)
             np.testing.assert_array_equal(spec[i].output_ids,
                                           cont[i].output_ids)

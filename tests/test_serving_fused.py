@@ -182,7 +182,7 @@ class TestZeroRetraceFused:
                     for p in rng.integers(4, 20, size=n)]
 
         kw = dict(batch_size=2, max_len=64, decode_chunk=16,
-                  pipeline=True, attn_impl="pallas", kv_dtype="int8",
+                  attn_impl="pallas", kv_dtype="int8",
                   weight_dtype="int8", **_PAGED)
         eng = ServingEngine(model, **kw)
         for p in wave(4):

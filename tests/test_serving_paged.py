@@ -3,9 +3,9 @@
 The acceptance properties on the CPU mesh at f32:
 
 * the paged engine's token streams are BYTE-IDENTICAL to the dense
-  engine on the same workload, across greedy/spec x pipeline on/off,
-  including shared-prefix prompts that exercise radix hits and block
-  adoption mid-run;
+  engine on the same workload, across greedy/spec, including
+  shared-prefix prompts that exercise radix hits and block adoption
+  mid-run;
 * token-budget admission DEFERS (and later completes) requests the pool
   cannot cover — exhaustion is back-pressure, never a crash;
 * a warm paged engine runs a staggered workload with prefix hits,
@@ -175,9 +175,6 @@ class TestPagedAllocator:
 class TestPagedEngineSmoke:
     def test_constructor_validation(self):
         model = _tiny_model()
-        with pytest.raises(ValueError, match="chunked prefill"):
-            ServingEngine(model, batch_size=2, max_len=64,
-                          prefill_chunk=None, kv_block=16)
         with pytest.raises(ValueError, match="requires kv_block"):
             ServingEngine(model, batch_size=2, max_len=64,
                           prefill_chunk=16, max_live_tokens=128)
@@ -191,23 +188,22 @@ class TestPagedEngineSmoke:
                                          share=(2, 4))
         new_lens = [10, 6, 12, 8, 9]
         for mode in ("greedy", "spec"):
-            for pipeline in (False, True):
-                kw = dict(GEOM, mode=mode, pipeline=pipeline)
-                base, _ = _run(_tiny_model(), prompts, new_lens, **kw)
-                paged, eng = _run(_tiny_model(), prompts, new_lens,
-                                  **kw, **PAGED)
-                assert base == paged, (mode, pipeline)
-                # retirement returned every live block; shared-prefix
-                # chains may park evictable for the next identical prompt
-                assert eng._kv.live_tokens() == 0
-                assert eng._kv.blocks_used() == eng._kv.evictable_count()
-                # n-gram spec rewind invariant: every rejected draft
-                # row's over-allocation was rolled back by the length
-                # rewind — no outstanding reservations survive the
-                # drain, and prompt-lookup drafting (no resident draft
-                # model) never touches the draft tenant's accounting
-                assert eng._kv.outstanding() == 0
-                assert eng._kv.draft_blocks_used() == 0
+            kw = dict(GEOM, mode=mode)
+            base, _ = _run(_tiny_model(), prompts, new_lens, **kw)
+            paged, eng = _run(_tiny_model(), prompts, new_lens,
+                              **kw, **PAGED)
+            assert base == paged, mode
+            # retirement returned every live block; shared-prefix
+            # chains may park evictable for the next identical prompt
+            assert eng._kv.live_tokens() == 0
+            assert eng._kv.blocks_used() == eng._kv.evictable_count()
+            # n-gram spec rewind invariant: every rejected draft
+            # row's over-allocation was rolled back by the length
+            # rewind — no outstanding reservations survive the
+            # drain, and prompt-lookup drafting (no resident draft
+            # model) never touches the draft tenant's accounting
+            assert eng._kv.outstanding() == 0
+            assert eng._kv.draft_blocks_used() == 0
 
     def test_token_budget_defers_then_completes(self):
         # pool = ONE full-length request (8 blocks): each 60-token prompt
@@ -231,7 +227,7 @@ class TestPagedEngineSmoke:
         reg = MetricsRegistry()
         eng = ServingEngine(_tiny_model(), batch_size=4, max_len=128,
                             decode_chunk=16, prefill_chunk=16, kv_block=16,
-                            max_live_tokens=4 * 96, pipeline=True,
+                            max_live_tokens=4 * 96,
                             registry=reg)
         for p in prompts:
             eng.submit(Request(p, 6))
@@ -268,7 +264,7 @@ class TestPagedEngineSmoke:
         model = _tiny_model()
         kw = dict(batch_size=4, max_len=128, decode_chunk=16,
                   prefill_chunk=16, kv_block=16, max_live_tokens=4 * 96,
-                  pipeline=True, instrument=False, recorder=False)
+                  instrument=False, recorder=False)
         eng = ServingEngine(model, **kw)
         for p in wave(6):
             eng.submit(Request(p, 6))
@@ -318,12 +314,11 @@ class TestPagedParityMatrix:
             rng, (7, 19, 33, 12, 25, 9, 40, 15), share=(2, 4, 6))
         new_lens = [10, 6, 12, 8, 9, 7, 11, 5]
         for mode in ("greedy", "spec"):
-            for pipeline in (False, True):
-                kw = dict(GEOM, mode=mode, pipeline=pipeline)
-                base, _ = _run(_tiny_model(), prompts, new_lens, **kw)
-                paged, _ = _run(_tiny_model(), prompts, new_lens,
-                                **kw, **PAGED)
-                assert base == paged, (mode, pipeline)
+            kw = dict(GEOM, mode=mode)
+            base, _ = _run(_tiny_model(), prompts, new_lens, **kw)
+            paged, _ = _run(_tiny_model(), prompts, new_lens,
+                            **kw, **PAGED)
+            assert base == paged, mode
 
     @pytest.mark.parametrize("kv_block", [8, 32])
     def test_block_chunk_geometry_variants(self, kv_block):
@@ -333,7 +328,7 @@ class TestPagedParityMatrix:
         prompts = _shared_prefix_prompts(rng, (7, 19, 33, 12, 25),
                                          share=(2, 4))
         new_lens = [10, 6, 12, 8, 9]
-        kw = dict(GEOM, mode="greedy", pipeline=True)
+        kw = dict(GEOM, mode="greedy")
         base, _ = _run(_tiny_model(), prompts, new_lens, **kw)
         paged, _ = _run(_tiny_model(), prompts, new_lens, **kw,
                         kv_block=kv_block, max_live_tokens=3 * 128)

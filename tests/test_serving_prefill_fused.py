@@ -312,8 +312,8 @@ class TestZeroRetracePrefillFused:
                     for p in rng.integers(4, 33, size=n)]
 
         kw = dict(batch_size=2, max_len=64, decode_chunk=16,
-                  prefill_chunk=16, pipeline=True,
-                  prefill_impl="pallas", kv_dtype="int8", **_PAGED)
+                  prefill_chunk=16, prefill_impl="pallas",
+                  kv_dtype="int8", **_PAGED)
         eng = ServingEngine(model, **kw)
         for p in wave(4):
             eng.submit(Request(p, 5))

@@ -5,12 +5,12 @@ The load-bearing properties:
 
 - **Parity/drift**: greedy decoding on the q8 cache tracks the f32
   reference within a small drift budget across the full scheduler matrix
-  (greedy/spec x pipeline on/off x paged/dense); the tiny f32 test model
+  (greedy/spec x paged/dense); the tiny f32 test model
   has wide logit margins, so observed drift is typically zero, and the
   budget (25% of emitted tokens) is a backstop against argmax ties.
 - **Byte-identity of q8-internal invariants**: everything that was
-  byte-identical at f32 stays byte-identical at q8 — pipeline on == off,
-  paged == dense.  Quantization changes values, never scheduling.
+  byte-identical at f32 stays byte-identical at q8 — paged == dense.
+  Quantization changes values, never scheduling.
 - **Zero retraces**: a warmed q8 engine serves a staggered ragged wave
   without a single new trace — the (int8 data, f16 scale) tuple leaves
   change program identity ONCE, at warmup, not per step.
@@ -127,12 +127,10 @@ class TestDtypeValidation:
 
 class TestParityMatrix:
     @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-    @pytest.mark.parametrize("pipeline", [False, True],
-                             ids=["nopipe", "pipe"])
     @pytest.mark.parametrize("mode", ["greedy", "spec"])
-    def test_q8_tracks_f32(self, mode, pipeline, paged):
+    def test_q8_tracks_f32(self, mode, paged):
         model = _tiny_model()
-        kw = dict(mode=mode, pipeline=pipeline)
+        kw = dict(mode=mode)
         if mode == "spec":
             kw["spec_k"] = 4
         if paged:
@@ -141,20 +139,10 @@ class TestParityMatrix:
         q8 = _outputs_memo(model, kv_dtype="int8", **kw)
         assert _drift(q8, ref) <= 0.25
 
-    def test_q8_pipeline_invariant_byte_identical(self):
-        model = _tiny_model()
-        on = _outputs_memo(model, kv_dtype="int8", mode="greedy",
-                           pipeline=True)
-        off = _outputs_memo(model, kv_dtype="int8", mode="greedy",
-                            pipeline=False)
-        assert on == off
-
     def test_q8_paged_matches_dense_byte_identical(self):
         model = _tiny_model()
-        dense = _outputs_memo(model, kv_dtype="int8", mode="greedy",
-                              pipeline=True)
-        paged = _outputs_memo(model, kv_dtype="int8", mode="greedy",
-                              pipeline=True, **_PAGED)
+        dense = _outputs_memo(model, kv_dtype="int8", mode="greedy")
+        paged = _outputs_memo(model, kv_dtype="int8", mode="greedy", **_PAGED)
         assert dense == paged
 
 
@@ -175,7 +163,7 @@ class TestZeroRetrace:
                     for p in rng.integers(4, 20, size=n)]
 
         kw = dict(batch_size=2, max_len=64, decode_chunk=16,
-                  pipeline=True, kv_dtype="int8", **_PAGED)
+                  kv_dtype="int8", **_PAGED)
         eng = ServingEngine(model, **kw)
         for p in wave(4):
             eng.submit(Request(p, 5))

@@ -121,15 +121,13 @@ class TestDispatchRetry:
 
 class TestPoisonQuarantine:
     @pytest.mark.parametrize("mode", ["greedy", "spec"])
-    @pytest.mark.parametrize("pipeline", [False, True])
-    def test_poisoned_request_quarantined_cohabitant_exact(
-            self, mode, pipeline):
+    def test_poisoned_request_quarantined_cohabitant_exact(self, mode):
         """Tentpole acceptance: a NaN payload in one slot retires that
         request with status "poisoned"; its cohabitant's output stays
         byte-identical to an unfaulted run, and the freed slot re-admits
         a queued request that completes normally."""
         model = _tiny_model()
-        kw = dict(mode=mode, pipeline=pipeline)
+        kw = dict(mode=mode)
         if mode == "spec":
             kw["spec_k"] = 4
         ref = _clean_outputs(model, **kw)
@@ -229,15 +227,13 @@ class TestCancellation:
         assert statuses == {"long": "cancelled", "next": "done"}
         assert long.output_ids == [] and len(nxt.output_ids) == 4
 
-    @pytest.mark.parametrize("pipeline", [False, True])
-    def test_cancel_mid_flight_cohabitant_exact(self, pipeline):
+    def test_cancel_mid_flight_cohabitant_exact(self):
         """Cancelling a decoding request — including one with tokens
         riding the inflight pipelined dispatch — keeps its cohabitant
         byte-identical and frees the slot for a queued request."""
         model = _tiny_model()
-        ref = _clean_outputs(model, pipeline=pipeline)
-        eng = ServingEngine(model, batch_size=2, max_len=64,
-                            pipeline=pipeline)
+        ref = _clean_outputs(model)
+        eng = ServingEngine(model, batch_size=2, max_len=64)
         r0 = eng.submit(Request(_PROMPTS[0], _NEW[0], rid="victim"))
         r1 = eng.submit(Request(_PROMPTS[1], _NEW[1], rid="bystander"))
         r2 = eng.submit(Request(np.arange(3, 9), 4, rid="readmit"))
@@ -258,7 +254,7 @@ class TestCancellation:
         import time
         from paddle_tpu.analysis import assert_no_retrace
         model = _tiny_model()
-        kw = dict(batch_size=2, max_len=64, pipeline=True)
+        kw = dict(batch_size=2, max_len=64)
 
         def gauntlet():
             eng = ServingEngine(model, faults=FaultPlan(poison={"p": 2}),
@@ -323,8 +319,7 @@ class TestLoadShedding:
 class TestDrainClose:
     def test_close_keeps_partial_outputs_and_is_idempotent(self):
         model = _tiny_model()
-        eng = ServingEngine(model, batch_size=2, max_len=64,
-                            pipeline=True)
+        eng = ServingEngine(model, batch_size=2, max_len=64)
         r0 = eng.submit(Request(_PROMPTS[0], 20))
         q = eng.submit(Request(_PROMPTS[1], 20))
         eng.submit(Request(np.arange(3, 9), 20))

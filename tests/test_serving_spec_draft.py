@@ -5,7 +5,7 @@ The acceptance properties on the CPU mesh at f32:
 
 * LOSSLESS: a draft-model spec engine's token streams are BYTE-
   IDENTICAL to the greedy engine on the same workload, across
-  paged/dense KV x f32/int8 x pipeline on/off x TP 1x4 mesh x
+  paged/dense KV x f32/int8 x TP 1x4 mesh x
   disaggregated 1P+1D — the verify forward's own greedy picks are the
   only emission path, so draft quality moves throughput, never bytes;
 * the draft model is a POOL TENANT, not a second pool: its chains draw
@@ -205,10 +205,9 @@ class TestDraftSpecByteIdentity:
         assert base == out, extra
         return eng
 
-    @pytest.mark.parametrize("pipeline", [False, True])
     @pytest.mark.parametrize("paged", [False, True])
-    def test_matches_greedy(self, paged, pipeline):
-        extra = dict(spec=_sc(_draft()), pipeline=pipeline)
+    def test_matches_greedy(self, paged):
+        extra = dict(spec=_sc(_draft()))
         if paged:
             extra.update(PAGED)
         self._matrix_run(**extra)
@@ -236,8 +235,7 @@ class TestDraftSpecByteIdentity:
     @pytest.mark.slow  # third tree-program family (adaptive rungs x tree)
     def test_matches_greedy_tree_pipelined_adaptive(self):
         self._matrix_run(spec=_sc(_draft(), tree="top2",
-                                  adaptive_window=3),
-                         pipeline=True)
+                                  adaptive_window=3))
 
     @pytest.mark.slow  # compiles the TP draft program family on the mesh
     def test_tp_mesh_matches_single_device_greedy(self):
@@ -253,7 +251,7 @@ class TestDraftSpecByteIdentity:
         base, _ = _run(_model(num_key_value_heads=4), prompts, new_lens,
                        mode="greedy", **GEOM)
         for extra in (dict(), dict(**PAGED),
-                      dict(spec=None, pipeline=True, **PAGED)):
+                      dict(spec=None, **PAGED)):
             kw = dict(GEOM)
             kw.update(extra)
             kw["spec"] = _sc(drf, adaptive_window=3) \
@@ -457,7 +455,7 @@ class TestWarmDraftZeroRetrace:
 
         kw = dict(mode="spec",
                   spec=_sc(_draft(), adaptive_window=2, k_min=1),
-                  pipeline=True, **{**GEOM, **PAGED})
+                  **{**GEOM, **PAGED})
         wave(ServingEngine(_model(), **kw))       # warm: traces all rungs
         eng2 = ServingEngine(_model(), **kw)
         with assert_no_retrace():

@@ -215,10 +215,7 @@ def test_engine_registers_every_counter_of_the_list(name):
     assert reg.get(name) is not None
 
 
-@pytest.mark.parametrize("pipeline,read,live", [(False, 256, 94),
-                                                (True, 320, 120)])
-def test_kv_rows_counters_read_the_numbers_computed_by_hand(pipeline, read,
-                                                            live):
+def test_kv_rows_counters_read_the_numbers_computed_by_hand():
     """``serving_kv_rows_read_total`` / ``serving_kv_rows_live_total`` on a
     stream with known lengths: one request of 21 prompt tokens and 5 new
     ones in a 2-slot engine with 16-row chunks.  The first token comes from
@@ -226,18 +223,18 @@ def test_kv_rows_counters_read_the_numbers_computed_by_hand(pipeline, read,
     other slot parked), each attending to length + 1 rows and — two slots
     being one block, the batch-wide rule — reading ``ceil((length + 1) /
     16) = 2`` chunks of both slots: 4 x 2 x 2 x 16 rows read, 22 + 23 + 24
-    + 25 live.  The pipelined engine has dispatched a fifth step (length
-    25) before the fourth's tokens tell it the request is done: 64 and 26
-    more."""
+    + 25 live = 256 and 94.  The pipelined engine has dispatched a fifth
+    step (length 25) before the fourth's tokens tell it the request is
+    done: 64 and 26 more."""
     from paddle_tpu.observability.metrics import MetricsRegistry
 
     reg = MetricsRegistry()
-    eng = tiny_engine(registry=reg, pipeline=pipeline)
+    eng = tiny_engine(registry=reg)
     eng.submit(Request(PROMPTS[0], NEW[0]))
     eng.run()
     lbl = dict(policy="continuous")
-    assert reg.get("serving_kv_rows_read_total").labels(**lbl).value == read
-    assert reg.get("serving_kv_rows_live_total").labels(**lbl).value == live
+    assert reg.get("serving_kv_rows_read_total").labels(**lbl).value == 320
+    assert reg.get("serving_kv_rows_live_total").labels(**lbl).value == 120
 
 
 # (c) the host spans, on the profiler's timeline
@@ -335,12 +332,11 @@ def losses(n=3):
     return [float(step(ids, ids).numpy()) for _ in range(n)]
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_served_tokens_do_not_depend_on_instrumentation(pipeline, tmp_path):
-    plain = served(instrument=False, recorder=False, pipeline=pipeline)
-    assert served(pipeline=pipeline) == plain
+def test_served_tokens_do_not_depend_on_instrumentation(tmp_path):
+    plain = served(instrument=False, recorder=False)
+    assert served() == plain
     with profiled(tmp_path):
-        assert served(pipeline=pipeline) == plain
+        assert served() == plain
 
 
 def test_losses_do_not_depend_on_a_profiler_session(tmp_path):
