@@ -16,33 +16,38 @@ __all__ = ["recompute", "recompute_sequential", "recompute_hybrid"]
 
 
 _POLICIES = {
-    None: None,
     "full": None,  # save only the region inputs, recompute everything
     # save matmul/conv outputs: backward recomputes only cheap elementwise
     "dots": "dots_saveable",
     "dots_no_batch": "dots_with_no_batch_dims_saveable",
-    # save outputs tagged jax.ad_checkpoint.checkpoint_name(x, "ckpt")
-    "named": "save_only_these_names",
 }
 
 
-def _resolve_policy(name):
-    if name in (None, "full"):
+def resolve_policy(policy):
+    """``None`` / ``"full"``: keep the region's inputs only.  A tuple of
+    ``jax.ad_checkpoint.checkpoint_name`` names: keep those values beside
+    the inputs (how ``models/llama.py`` keeps the attention kernel's
+    residuals).  ``"dots"`` / ``"dots_no_batch"``: jax's policies."""
+    if policy is None:
         return None
-    import jax.ad_checkpoint as adc
-
-    key = _POLICIES.get(name)
-    if key is None:
+    if isinstance(policy, tuple):
+        return jax.checkpoint_policies.save_only_these_names(*policy)
+    if policy == "named":
         raise ValueError(
-            f"unknown recompute policy {name!r}; one of {sorted(_POLICIES)}")
-    pol = getattr(adc.checkpoint_policies, key)
-    return pol("ckpt") if name == "named" else pol
+            "recompute policy 'named' is gone: LlamaConfig(recompute=True) "
+            "keeps the attention kernel's output and log-sum-exp by default "
+            "(recompute_policy=None); 'full' keeps the layer inputs only")
+    if policy not in _POLICIES:
+        raise ValueError(
+            f"unknown recompute policy {policy!r}; one of {sorted(_POLICIES)}")
+    key = _POLICIES[policy]
+    return None if key is None else getattr(jax.checkpoint_policies, key)
 
 
 def recompute(function, *args, **kwargs):
     use_reentrant = kwargs.pop("use_reentrant", True)  # noqa: F841 (API parity)
     preserve_rng_state = kwargs.pop("preserve_rng_state", True)  # noqa: F841
-    policy = _resolve_policy(kwargs.pop("policy", None))
+    policy = resolve_policy(kwargs.pop("policy", None))
 
     fn = function.forward if hasattr(function, "forward") else function
 

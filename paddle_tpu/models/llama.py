@@ -31,12 +31,19 @@ from paddle_tpu.nn.layer.common import Embedding, Linear
 from paddle_tpu.nn.layer.container import LayerList
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.nn.layer.norm import RMSNorm
+from paddle_tpu.observability.trace import ATTN_RESIDUALS
 from paddle_tpu.tensor.tensor import Tensor
 
 __all__ = [
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama_shardings",
     "shard_llama",
 ]
+
+
+# what a recomputed layer's checkpoint keeps beside its input: the attention
+# kernel's out and lse (LlamaConfig's comment on recompute_policy has the
+# rule, its bytes and why it is not wider)
+_RECOMPUTE_KEEPS = ATTN_RESIDUALS[3:]
 
 
 @dataclass
@@ -67,16 +74,32 @@ class LlamaConfig:
     # reference materializes full logits (fused_softmax_mask kernels help
     # softmax but not the memory)
     loss_chunk_size: int = 0
-    # jax.checkpoint policy for per-layer recompute: None/"full" saves only
-    # layer inputs; "named" additionally saves the flash-attention output
-    # (checkpoint_name-tagged) so backward skips the quadratic attention
-    # recompute at b*l*h extra bytes per layer; "dots"/"dots_no_batch" save
-    # every matmul output (memory-hungry, small models only)
+    # what a recomputed layer's jax.checkpoint keeps beside its input.
+    # None (the default): the attention kernel's output and log-sum-exp
+    # (observability.trace.ATTN_RESIDUALS: the flash custom VJPs name what
+    # their backward reads), so the recompute inside the backward runs no
+    # second attention forward.  Cost: 2*h + 4*heads bytes a token and
+    # layer in bf16 (134 + 2 MB a layer at 16,384 tokens x hidden 4096 —
+    # as much again as the layer input that full recompute keeps).  ONE
+    # fixed rule, and not wider: also keeping q, then k and v (another
+    # 2*h, then 4*h*kv_heads/heads bytes a token) was measured SLOWER on
+    # the chip — at Mistral-7B's widths the step then passes the compiler's
+    # memory budget and XLA re-runs MLP products on its own (PERF.md,
+    # PR 32).  "full": the layer input only, everything run again — the
+    # lean setting for a job at its memory limit.  "dots"/"dots_no_batch":
+    # every matmul output (memory-hungry, small models only).
     recompute_policy: str | None = None
     # remat only the FIRST k decoder layers (None = all): un-remat layers
     # keep their intermediates (~14*h bytes/token/layer in bf16) and cost no
     # recompute FLOPs in backward — the HBM-for-FLOPs dial
     recompute_layers: int | None = None
+
+    def __post_init__(self):
+        # an unknown policy (or "named", which is gone) fails here, not at
+        # the first forward
+        from paddle_tpu.distributed.fleet.recompute import resolve_policy
+
+        resolve_policy(self.recompute_policy)
 
     # tiny preset used by tests / dryrun
     @staticmethod
@@ -234,12 +257,6 @@ class LlamaAttention(Layer):
                 q, k, v, attn_mask=attn_mask,
                 is_causal=attn_mask is None and l > 1,
             )
-        if cfg.recompute and cfg.recompute_policy == "named":
-            from jax.ad_checkpoint import checkpoint_name
-
-            # saved under the "named" remat policy: backward reuses the
-            # attention output instead of re-running the quadratic kernel
-            out = apply("attn_ckpt", lambda x: checkpoint_name(x, "ckpt"), out)
         return out
 
 
@@ -326,8 +343,10 @@ class LlamaModel(Layer):
             if remat_this:
                 from paddle_tpu.distributed.fleet.recompute import recompute
 
+                policy = self.config.recompute_policy
                 h = recompute(layer_fn, h, attn_mask,
-                              policy=self.config.recompute_policy)
+                              policy=_RECOMPUTE_KEEPS if policy is None
+                              else policy)
             elif caches is not None:
                 h, c = layer_fn(h, attn_mask, caches[i], position_offset)
                 new_caches.append(c)

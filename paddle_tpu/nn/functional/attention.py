@@ -8,8 +8,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.autograd.engine import apply
+from paddle_tpu.observability.trace import ATTN_RESIDUALS
 from paddle_tpu.tensor.tensor import Tensor
 
 
@@ -127,8 +129,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         m = rest[0] if rest else None
         if m is not None and m.dtype == jnp.bool_ and m.ndim == 2:
             m = m[:, None, None, :]  # [B, Lk] key-padding -> broadcastable
-        return _sdpa_ref(q, k, v, m, dropout_p if use_dropout else 0.0, is_causal,
-                         dropout_key=dk)
+        out = _sdpa_ref(q, k, v, m, dropout_p if use_dropout else 0.0, is_causal,
+                        dropout_key=dk)
+        # the name the flash kernels' forward rules give their output, so a
+        # checkpoint whose policy keeps it follows one rule on either path
+        return checkpoint_name(out, ATTN_RESIDUALS[3])
 
     args = [_t(query), _t(key), _t(value)]
     if attn_mask is not None:

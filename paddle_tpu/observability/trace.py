@@ -26,7 +26,7 @@ from __future__ import annotations
 import functools
 
 __all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "LOOPS", "SPANS",
-           "COUNTERS"]
+           "COUNTERS", "ATTN_RESIDUALS"]
 
 # model components, the same names in the serving programs
 # (models/llama_decode.py, ops/decode_attention.py) and the training model
@@ -49,6 +49,14 @@ STATE_SCOPES = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.state_update",
 # n_steps scan of the decode program and the cache-chunk loop of the
 # chunked attention read
 LOOPS = ("decode.steps", "attn.core.chunks")
+# what the flash-attention backward reads of its forward, in the order of the
+# custom VJPs' residual tuples (ops/flash_attention.py: q, k, v, out, lse).
+# Each forward rule passes them through jax.ad_checkpoint.checkpoint_name
+# under these names — the identity everywhere but inside a jax.checkpoint
+# whose policy asks for a name, where a kept ``out`` + ``lse`` let the
+# recompute drop the forward kernel (models/llama.py picks the kept set)
+ATTN_RESIDUALS = ("attn.res.q", "attn.res.k", "attn.res.v", "attn.res.out",
+                  "attn.res.lse")
 # host spans: the engine's phases (serving/engine.py::_phase) and the
 # train step's dispatch (static/functionalize.py)
 SPANS = ("serving.submit", "serving.step", "serving.admit",

@@ -34,7 +34,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+
+from paddle_tpu.observability.trace import ATTN_RESIDUALS
 
 _NEG_INF = -1e30
 
@@ -1263,6 +1266,17 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, num_heads, num_kv_heads,
 
 
 # --------------------------------------------------------------- packed entry
+def _named_residuals(q, k, v, out, lse):
+    """The backward's five residuals, each under its ``ATTN_RESIDUALS``
+    name.  A forward rule returns THIS ``out`` as its primal output too:
+    nothing downstream then reads the kernel's raw output, so a
+    ``jax.checkpoint`` whose policy keeps ``out`` and ``lse`` recomputes
+    no forward kernel.  Under any other transformation a name is the
+    identity and compiles to nothing."""
+    return tuple(checkpoint_name(x, n)
+                 for x, n in zip((q, k, v, out, lse), ATTN_RESIDUALS))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_packed(q, k, v, num_heads, num_kv_heads, causal=False,
                            scale=None, interpret=False):
@@ -1276,6 +1290,7 @@ def _fap_fwd(q, k, v, num_heads, num_kv_heads, causal, scale, interpret):
     out, lse = _flash_fwd_pallas(q, k, v, num_heads, num_kv_heads,
                                  causal=causal, scale=scale,
                                  interpret=interpret)
+    q, k, v, out, lse = _named_residuals(q, k, v, out, lse)
     return out, (q, k, v, out, lse)
 
 
@@ -1309,6 +1324,7 @@ def _faps_fwd(q, k, v, q_segments, k_segments, num_heads, num_kv_heads,
                                  causal=causal, scale=scale,
                                  interpret=interpret, q_segments=q_segments,
                                  k_segments=k_segments)
+    q, k, v, out, lse = _named_residuals(q, k, v, out, lse)
     return out, (q, k, v, q_segments, k_segments, out, lse)
 
 
@@ -1402,6 +1418,7 @@ def _fapr_fwd(q, k, v, cos, sin, num_heads, num_kv_heads, causal, scale,
     out, lse = _flash_fwd_pallas(q, k, v, num_heads, num_kv_heads,
                                  causal=causal, scale=scale,
                                  interpret=interpret, rope_tables=tables)
+    q, k, v, out, lse = _named_residuals(q, k, v, out, lse)
     return out, (q, k, v, out, lse, cos, sin)
 
 

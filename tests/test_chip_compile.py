@@ -16,6 +16,7 @@ The topology is described inside a module-scoped fixture (never at import:
 only one process may load the TPU library, and every xdist worker imports
 every test file), in this ONE file, in the test's own process.
 """
+import collections
 import functools
 import re
 
@@ -382,3 +383,108 @@ def test_decode_program_reads_the_cache_in_place_and_stays_small(
     want = _DECODE_SIZE[geometry]
     assert size[1] == want["parent"][1]
     assert all(a <= b for a, b in zip(size, want["ceiling"])), size
+
+
+# The benchmark's train cell (``benchmark/configs/mistral7b_train.json``:
+# Mistral-7B's widths, 4 x 4096 tokens, every layer recomputed, int8 / bf16
+# AdamW moments), built by the benchmark's own code with the seeded weights
+# left out: the model's initial arrays on the CPU give the shapes and
+# nothing runs.  The platform gates (``flash_attention.available``, the
+# kernels' ``interpret`` rule, the q8 AdamW gate) are steered to their TPU
+# branch for the trace alone.
+TRAIN_BYTES_LINE = 15.5e9
+_PALLAS_CALL = re.compile(r'custom-call\(.*op_name="[^"]*/(\w+)/pallas_call"')
+
+
+def _train_cell(mp, layers=None):
+    """``(architecture file, configuration)`` of the train cell, at
+    ``layers`` of its layers if given."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mp.syspath_prepend(root)
+    from benchmark.lib import weights
+    from benchmark.models import llama as arch
+
+    mp.setattr(weights, "place_into", lambda *a: None)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mistral7b_train.json")) as f:
+        config = json.load(f)
+    tr = config["train"]
+    assert tr["recompute"] and tr["recompute_layers"] is None
+    if layers is not None:
+        config["num_hidden_layers"] = layers
+    return arch, config
+
+
+def _attention_kernels(text, layers):
+    """One forward attention custom call a layer, and the backward's three
+    kernels once a layer, in a compiled text that still recomputes."""
+    calls = collections.Counter(_PALLAS_CALL.findall(text))
+    assert calls["flash_attention_fwd"] == layers
+    for kernel in ("bwd_delta", "bwd_dkv", "bwd_dq"):
+        assert calls["flash_attention_" + kernel] == layers
+    assert "rematted_computation/mlp" in text
+
+
+def test_gradient_recomputes_no_attention_forward(one_chip, monkeypatch):
+    """The loss's gradient at published widths and 2 layers holds ONE
+    forward attention custom call a layer: each layer's checkpoint keeps
+    the kernel's ``out`` and ``lse`` (``models/llama.py::_RECOMPUTE_KEEPS``),
+    so the recompute inside the backward has no second one (the parent,
+    and ``recompute_policy="full"``: two a layer, 43 ms of a 1,390 ms step
+    on the chip — PERF.md, PR 32)."""
+    from paddle_tpu.autograd import engine
+    from paddle_tpu.tensor.tensor import Tensor
+
+    arch, config = _train_cell(monkeypatch, layers=M_LAYERS)
+    tr = config["train"]
+    model = arch.build(config, 0, tr["seq"], recompute=True,
+                       loss_chunk_size=tr["loss_chunk_size"])
+    params, buffers = model.functional_state()
+
+    def loss_of(ps, ids):
+        with engine.no_grad():
+            return model.functional_call(
+                ps, buffers, Tensor(ids), Tensor(ids)).data
+
+    ids = jax.ShapeDtypeStruct((tr["batch"], tr["seq"]), jnp.int32,
+                               sharding=one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(jax.value_and_grad(loss_of),
+                        _shapes(params, one_chip), ids)
+    _attention_kernels(compiled.as_text(), M_LAYERS)
+
+
+@pytest.mark.slow
+def test_train_step_fits_the_chip_without_compiler_remat(one_chip,
+                                                         monkeypatch):
+    """The cell's whole step (6 layers, optimizer and all) as
+    ``build_step`` makes it: arguments + temporaries at or under 15.5 GB of
+    the 16.9 the runtime offers (ISSUE 32's line; 14.31 GB, and the
+    parent's program 13.98 by this count), and NO instruction
+    rematerialized by the compiler on its own.  That second line is the
+    one that bites: with ``q`` kept too the step is 14.61 GB, XLA re-runs
+    MLP products (31 ``.remat`` instructions) and the chip reads ``mlp``
+    +50 ms — which is why the kept set stops at ``out`` + ``lse``.  Marked
+    slow (80 s alone, and the compiler's threads slow the other workers of
+    a tier-1 run, whose limit has little room): run it by hand in a PR
+    that changes what the train step keeps alive —
+    ``pytest tests/test_chip_compile.py -m slow``."""
+    arch, config = _train_cell(monkeypatch)
+    from benchmark.drivers.train import build_step
+
+    tr = config["train"]
+    _, step = build_step(arch, config, 0)
+    ids = jnp.zeros((tr["batch"], tr["seq"]), jnp.int32)
+    operands = _shapes(step._operands(1, (ids, ids)), one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = step._jitted.lower(*operands).compile()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    nbytes = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"compiled train step: {nbytes / 1e9:.2f} GB")
+    _attention_kernels(text, config["num_hidden_layers"])
+    assert ".remat" not in text
+    assert nbytes <= TRAIN_BYTES_LINE
