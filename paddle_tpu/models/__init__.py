@@ -22,3 +22,6 @@ from paddle_tpu.models.moe_llm import MoEConfig, MoEForCausalLM  # noqa: F401
 from paddle_tpu.models.falcon_h1 import (  # noqa: F401
     FalconH1Config, FalconH1ForCausalLM,
 )
+from paddle_tpu.models.glm4_moe_lite import (  # noqa: F401
+    Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
+)

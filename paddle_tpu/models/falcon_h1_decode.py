@@ -45,7 +45,9 @@ from paddle_tpu.models.falcon_h1 import (
 from paddle_tpu.models.llama_decode import (
     _greedy_pick, _rope_at, _rope_tables,
 )
-from paddle_tpu.models.serving_family import ServingFamily, StateLeaf
+from paddle_tpu.models.serving_family import (
+    RowsLeaves, ServingFamily, StateLeaf,
+)
 from paddle_tpu.observability.compilecache import CompileCacheMonitor
 from paddle_tpu.ops.decode_attention import (
     decode_attention, init_kv_cache, slot_prefill_attention,
@@ -314,7 +316,8 @@ def check_options(options):
 FALCON_H1_FAMILY = ServingFamily(
     name="falcon_h1",
     decode_params=_decode_params_of,
-    kv_geometry=lambda cfg: (cfg.heads, cfg.kv_heads, cfg.head_dim),
+    rows_leaves=lambda cfg: RowsLeaves(2, (cfg.kv_heads, cfg.head_dim),
+                                       cfg.heads),
     init_layer_cache=init_layer_cache,
     decode_steps=serving_decode_steps,
     prefill_chunk=serving_prefill_chunk,

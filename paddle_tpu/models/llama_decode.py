@@ -30,7 +30,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.models.serving_family import ServingFamily
+from paddle_tpu.models.serving_family import RowsLeaves, ServingFamily
 from paddle_tpu.observability.compilecache import CompileCacheMonitor
 from paddle_tpu.ops.decode_attention import (
     _Q8_MAX, _Q8_SCALE_DTYPE, _canon_dtype, _kv_data, decode_attention,
@@ -1008,7 +1008,7 @@ def _llama_tp_rules(axis):
 LLAMA_FAMILY = ServingFamily(
     name="llama",
     decode_params=_decode_params_of,
-    kv_geometry=lambda cfg: cfg[:3],
+    rows_leaves=lambda cfg: RowsLeaves(2, (cfg[1], cfg[2]), cfg[0]),
     init_layer_cache=lambda cfg, batch, max_len, kv_dtype: init_kv_cache(
         batch, max_len, cfg[1], cfg[2], kv_dtype),
     decode_steps=serving_decode_steps,

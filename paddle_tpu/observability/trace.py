@@ -25,8 +25,8 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "LOOPS", "SPANS",
-           "COUNTERS", "ATTN_RESIDUALS"]
+__all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "EXPERT_SCOPES",
+           "LOOPS", "SPANS", "COUNTERS", "ATTN_RESIDUALS"]
 
 # model components, the same names in the serving programs
 # (models/llama_decode.py, ops/decode_attention.py) and the training model
@@ -45,6 +45,16 @@ SCOPES = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
 # (benchmark/lib/falcon_h1_reduce.py).
 STATE_SCOPES = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.state_update",
                 "ssm.norm_gate", "ssm.out")
+# a routed expert FFN (ops/moe.py: the router, ordering and gathering the
+# (token, expert) pairs, the grouped products, the combine; moe.shared the
+# shared expert) and latent attention's absorbed products (mla.absorb: W_uk
+# folded into the query and W_uv applied to the latent-wide result in
+# models/glm4_moe_lite_decode.py; the cached rows' up-projection in the
+# whole-sequence forward).  The projections, rope, the latent write and the
+# core stay under the attn.* names above.  A tuple of its own for the reason
+# STATE_SCOPES is (benchmark/lib/glm4_moe_lite_reduce.py reduces with both).
+EXPERT_SCOPES = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+                 "moe.shared", "mla.absorb")
 # the compiled loops, so that a %while in a device trace can be told: the
 # n_steps scan of the decode program and the cache-chunk loop of the
 # chunked attention read
@@ -69,11 +79,15 @@ SPANS = ("serving.submit", "serving.step", "serving.admit",
 # decode_batch_mean and window record), the recurrent state beside the K/V
 # rows, and the decode cache read against the rows it needs — one layer's
 # count at each decode dispatch's host lengths by the read's own rule
-# (ops.decode_attention.kv_rows_read); read / live is the over-read
+# (ops.decode_attention.kv_rows_read); read / live is the over-read; and
+# what a routed expert FFN served: live (token, expert) pairs by expert,
+# experts touched and counted runs by program (decode / prefill)
 COUNTERS = ("serving_steps_total", "serving_tokens_emitted_total",
             "serving_prefill_chunks_total", "serving_state_bytes",
             "serving_state_resets_total", "serving_kv_rows_read_total",
-            "serving_kv_rows_live_total")
+            "serving_kv_rows_live_total", "serving_moe_expert_tokens_total",
+            "serving_moe_experts_touched_total",
+            "serving_moe_dispatches_total")
 
 SPAN_EVENT_TYPE = "Span"
 

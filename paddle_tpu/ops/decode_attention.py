@@ -63,6 +63,17 @@ TPU-first design choices:
   and any position past the slot's mapped capacity (or a table sentinel
   ``>= N``) is routed past the pool so the scatter DROPS it — the
   write-drop parking invariant survives paging unchanged.
+* **One latent rows leaf** (``v_width``).  A latent-attention cache row is
+  ONE leaf ``[B, Lmax, R]`` (read as ``[B, Lmax, 1, R]``: stored with the
+  unit head dim, the TPU compiler gives the leaf a position-minor layout
+  and copies it whole on the way in and out — chipless compile, PR 33)
+  whose first ``v_width`` values are also the values (an MLA row: the
+  normed latent, then the one shared rope key).
+  It is a case of the same read, not a copy of it: ``v_new`` / ``v_cache``
+  are ``None``, the row is appended once and gathered once a chunk trip,
+  scores run over the full row and the values are a slice of the gathered
+  tile — same slot order, chunk and block rules, ``kv_rows_read`` unchanged
+  (dense float ``blhd`` only).
 * **GQA-native.**  kv heads are consumed directly (``[B, Hkv, G, ...]``
   einsums) — no ``repeat`` materialization, KV reads are 1/G of expanded
   heads.
@@ -294,9 +305,11 @@ def _append(cache, new, lengths, layout, block_table=None):
 
 
 def _attend_full(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
-                 attn_bias):
+                 attn_bias, v_width=None):
     """Single fused masked read over the whole [Lmax] cache."""
     b, hkv, g, t, d = qg.shape
+    if v_cache is None:         # one latent rows leaf: values = row[:v_width]
+        v_cache = k_cache[..., :v_width]
     if isinstance(k_cache, tuple):
         if layout != "blhd":
             raise ValueError(
@@ -401,8 +414,12 @@ def kv_rows_read(lengths, t, chunk, lmax, plain=True):
 
 
 def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
-                    attn_bias, chunk, block_table=None):
+                    attn_bias, chunk, block_table=None, v_width=None):
     """Online-softmax ``lax.while_loop`` over [C]-sized cache chunks.
+
+    ``v_cache=None``: one latent rows leaf (module docstring) — a trip
+    gathers the rows once and the values are their first ``v_width``
+    columns; everything else below is the same.
 
     Flash-style running (max, denominator, accumulator) carry; exact (not
     approximate) — the recurrence rescales previous partial sums by
@@ -525,7 +542,8 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
         """Fold chunk ``i`` into the carry rows of ``slots``."""
         start = jnp.minimum(i * c, lmax - c)  # clamped tail start
         kb = read_chunk(k_cache, i, start, slots)
-        vb = read_chunk(v_cache, i, start, slots)
+        vb = (kb[..., :v_width] if v_cache is None
+              else read_chunk(v_cache, i, start, slots))
         s = jnp.einsum(
             "bkgtd,bkcd->bkgtc", q, kb.astype(jnp.float32),
             preferred_element_type=jnp.float32) * scale
@@ -552,7 +570,8 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
 
     m0 = jnp.full((b, hkv, g, t), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, t), jnp.float32)
-    acc0 = jnp.zeros((b, hkv, g, t, d), jnp.float32)
+    acc0 = jnp.zeros((b, hkv, g, t, d if v_cache is not None else v_width),
+                     jnp.float32)
 
     if rows is None:
         # highest live position + 1 this step: parked slots (>= lmax) excluded
@@ -616,7 +635,7 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
 
 def _attend_dispatch(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
                      attn_bias, chunk_size, lmax, block_table, attn_impl,
-                     where):
+                     where, v_width=None):
     """Select the attention-read implementation for one attend.
 
     ``attn_impl`` (static): ``None`` / ``"reference"`` keep the existing
@@ -648,17 +667,33 @@ def _attend_dispatch(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
                                block_table)
     if chunk_size is not None and int(chunk_size) < lmax:
         return _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale,
-                               layout, attn_bias, int(chunk_size))
+                               layout, attn_bias, int(chunk_size),
+                               v_width=v_width)
     return _attend_full(qg, k_cache, v_cache, lengths, q_pos, scale,
-                        layout, attn_bias)
+                        layout, attn_bias, v_width)
+
+
+def _latent_only_dense(where, v_width, v_new, v_cache, k_cache, layout,
+                       block_table, impl):
+    """One latent rows leaf (``v_width``) is read by the dense float
+    ``blhd`` path alone: anything else is a loud error, not a wrong read."""
+    if v_width is None:
+        return
+    if (v_new is not None or v_cache is not None or layout != "blhd"
+            or isinstance(k_cache, tuple) or block_table is not None
+            or impl == "pallas"):
+        raise ValueError(
+            f"{where}: v_width= (one latent rows leaf) takes v_new=None, "
+            "v_cache=None and the dense float blhd cache with the "
+            "reference read (no paging, no int8, no Pallas kernel)")
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "layout", "chunk_size",
-                                    "attn_impl"))
+                                    "attn_impl", "v_width"))
 def decode_attention(q, k_new, v_new, k_cache, v_cache, lengths, scale=None,
                      layout="blhd", attn_bias=None, chunk_size=None,
-                     block_table=None, attn_impl=None):
+                     block_table=None, attn_impl=None, v_width=None):
     """One decode step: append new kv, attend causally over the cache.
 
     q [B, T, H, D] (T = tokens this step, usually 1); k_new/v_new
@@ -691,9 +726,18 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, lengths, scale=None,
     online softmax into one VMEM residency per KV chunk
     (ops/paged_attention_pallas.py) with reference fallback on
     unsupported geometry (logged once per process).
+
+    ``v_width`` (static): the cache is ONE latent rows leaf (module
+    docstring) — ``v_new`` and ``v_cache`` are ``None``, the values are the
+    first ``v_width`` columns of a row, ``out`` is ``[B, T, H, v_width]``
+    and the returned ``v_cache`` is ``None``.
     """
     b, t, h, d = q.shape
     hkv = k_new.shape[2]
+    _latent_only_dense("decode_attention", v_width, v_new, v_cache, k_cache,
+                       layout, block_table, attn_impl)
+    if v_width is not None:
+        k_cache = k_cache[:, :, None]       # the leaf is [B, Lmax, R]
     k_data = _kv_data(k_cache)
     if isinstance(k_cache, tuple) and layout != "blhd":
         raise ValueError(
@@ -719,7 +763,8 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, lengths, scale=None,
 
     with jax.named_scope("attn.kv_write"):
         k_cache = _append(k_cache, k_new, lengths, layout, block_table)
-        v_cache = _append(v_cache, v_new, lengths, layout, block_table)
+        if v_width is None:
+            v_cache = _append(v_cache, v_new, lengths, layout, block_table)
 
     with jax.named_scope("attn.core"):
         qg = q.reshape(b, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
@@ -728,9 +773,13 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, lengths, scale=None,
             + jnp.arange(t, dtype=jnp.int32)[None, :]       # [B,T]
         out = _attend_dispatch(qg, k_cache, v_cache, lengths, q_pos, scale,
                                layout, attn_bias, chunk_size, lmax,
-                               block_table, attn_impl, "decode_attention")
-        out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d) \
+                               block_table, attn_impl, "decode_attention",
+                               v_width)
+        out = out.transpose(0, 3, 1, 2, 4) \
+            .reshape(b, t, h, d if v_width is None else v_width) \
             .astype(q.dtype)
+    if v_width is not None:
+        k_cache = k_cache[:, :, 0]
     return out, k_cache, v_cache, lengths + t
 
 
@@ -775,7 +824,7 @@ def _prefill_dispatch(q, k_new, v_new, k_cache, v_cache, slot, offset,
 
 def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
                            scale=None, chunk_size=None, block_table=None,
-                           attn_impl=None, prefill_impl=None):
+                           attn_impl=None, prefill_impl=None, v_width=None):
     """Chunked-prefill attention for ONE slot of the batch cache.
 
     The serving engine's chunked admission path processes a prompt in
@@ -814,10 +863,19 @@ def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
     otherwise.  ``attn_impl`` keeps selecting the cache-READ kernel on
     the reference path.
 
+    ``v_width``: ONE latent rows leaf, as in ``decode_attention``
+    (``v_new`` / ``v_cache`` ``None``; ``out`` ``[1, P, H, v_width]``).
+
     q [1, P, H, D]; k_new/v_new [1, P, Hkv, D]; caches [B, Lmax, Hkv, D].
     Returns (out [1, P, H, D], k_cache', v_cache').
     """
     b, t, h, d = q.shape
+    _latent_only_dense("slot_prefill_attention", v_width, v_new, v_cache,
+                       k_cache, "blhd", block_table,
+                       "pallas" if "pallas" in (attn_impl, prefill_impl)
+                       else None)
+    if v_width is not None:
+        k_cache = k_cache[:, :, None]       # the leaf is [B, Lmax, R]
     if b != 1:
         raise ValueError(
             f"slot_prefill_attention: chunk batch must be 1 (got {b})")
@@ -891,7 +949,8 @@ def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
 
     with jax.named_scope("attn.kv_write"):
         k_cache = scatter(k_cache, k_new)
-        v_cache = scatter(v_cache, v_new)
+        if v_width is None:
+            v_cache = scatter(v_cache, v_new)
 
     # the slot's [1, Lmax] view (slot < B: no dynamic_slice clamping)
     def slot_view(cache):
@@ -908,14 +967,17 @@ def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
 
     with jax.named_scope("attn.core"):
         ks = slot_view(k_cache)
-        vs = slot_view(v_cache)
+        vs = slot_view(v_cache) if v_width is None else None
         qg = q.reshape(1, t, hkv, g, d).transpose(0, 2, 3, 1, 4) \
             .astype(jnp.float32)                            # [1,Hkv,G,T,D]
         q_pos = offset[None, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
         lengths = offset[None]                              # [1]
         out = _attend_dispatch(qg, ks, vs, lengths, q_pos, scale, "blhd",
                                None, chunk_size, lmax, None, attn_impl,
-                               "slot_prefill_attention")
-        out = out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d) \
+                               "slot_prefill_attention", v_width)
+        out = out.transpose(0, 3, 1, 2, 4) \
+            .reshape(1, t, h, d if v_width is None else v_width) \
             .astype(q.dtype)
+    if v_width is not None:
+        k_cache = k_cache[:, :, 0]
     return out, k_cache, v_cache

@@ -165,6 +165,17 @@ def _host_fetch(*arrays):
 _SPEC_FALLBACK_WARNED = False
 
 
+def _head_rows(routes, n):
+    """The first ``n`` rows of a request's recorded routes (a list of
+    ``[rows, L_moe, k]`` pieces, or None), as a list of pieces again."""
+    if not n or routes is None:
+        return None
+    head = np.concatenate(routes, axis=0)[:n]
+    if len(head) < n:
+        raise RuntimeError(f"{len(head)} recorded rows for {n} reused ones")
+    return [head]
+
+
 def _warn_spec_fallback():
     global _SPEC_FALLBACK_WARNED
     if _SPEC_FALLBACK_WARNED:
@@ -348,6 +359,10 @@ class Request:
         self.preempts = 0
         self._adm_ids = None      # tokens the last chunked admission prefilled
         self.output_ids = []
+        # a model with routed experts: int8 [rows, L_moe, k] pieces, one row
+        # a position the programs ran (prompt, then each emitted token's
+        # input) — the experts that served it; None for any other model
+        self.routes = None
         self.text = ""
         self.done = False
         self.status = None
@@ -683,7 +698,8 @@ class ServingEngine:
         # this one record; options the family cannot serve raise here
         fam = self._fam = family_of(model)
         self._params, self._cfg = fam.decode_params(model, self._lmax)
-        nh, nkv, hd = fam.kv_geometry(self._cfg)
+        rows = fam.rows_leaves(self._cfg)
+        nh, (nkv, hd) = rows.query_heads, rows.row
         fam.check_options(dict(
             cfg=self._cfg, mode=mode, kv_block=kv_block, mesh=mesh,
             kv_dtype=kv_dtype, weight_dtype=weight_dtype,
@@ -777,7 +793,8 @@ class ServingEngine:
                     "and the family must have a draft-step program")
             self._dparams, self._dcfg = fam.decode_params(
                 spec.draft_model, self._lmax)
-            dnh, dnkv, dhd = fam.kv_geometry(self._dcfg)
+            drows = fam.rows_leaves(self._dcfg)
+            dnh, (dnkv, dhd) = drows.query_heads, drows.row
             if int(self._dparams["embed"].shape[0]) \
                     != int(self._params["embed"].shape[0]):
                 raise ValueError(
@@ -873,7 +890,6 @@ class ServingEngine:
                 rules=fam.tp_rules(tp_axis))
             dspecs = None
             if self._dspec:
-                dnh, dnkv, _ = fam.kv_geometry(self._dcfg)
                 if dnkv % n or dnh % n:
                     raise ValueError(
                         f"draft heads not shardable {n}-way along "
@@ -963,6 +979,15 @@ class ServingEngine:
                 if cache_sharding is not None:
                     self._dcaches = _place_caches(
                         self._dcaches, cache_sharding, scale_sharding)
+        # a family with routed experts hands back, beside the tokens of a
+        # dispatch and of a prefill chunk, the experts that served each
+        # live row: drained with the tokens, counted, and appended to
+        # Request.routes.  ``_chunk_routes``: (request, real rows, device
+        # array, the request's ``preempts`` then) of chunks dispatched
+        # since the last decode dispatch, whose record they ride
+        self._n_experts = (fam.routed_experts(self._params)
+                           if fam.routed_experts is not None else 0)
+        self._chunk_routes = []
         # recurrent state beside the K/V rows (a family's state_leaves):
         # resident bytes for the serving_state_bytes gauge
         self._state_idx = tuple(leaf.index for leaf in fam.state_leaves)
@@ -981,10 +1006,11 @@ class ServingEngine:
             if self._q8:
                 # analytic per-context-token KV traffic at int8: 1 data
                 # byte per (head, dim) element + 2 f16 scale bytes per
-                # (position, head) row, both k and v, every layer
+                # (position, head) row, every rows leaf (k and v), every
+                # layer
                 n_layers = len(self._params["layers"])
                 self._m.hbm_gb_per_tok_q8.set(
-                    n_layers * 2 * nkv * (hd + 2) / 1e9)
+                    n_layers * rows.count * nkv * (hd + 2) / 1e9)
             if self._w8:
                 # analytic per-decode-token WEIGHT traffic at int8: every
                 # projection element is read once per token — 1 byte of
@@ -1280,7 +1306,11 @@ class ServingEngine:
         first token, just-adopted membership and not-yet-drained
         first-token records.  Records already riding an inflight dispatch
         need no scrub — the drain's identity check discards them."""
-        self._pf.pop(slot, None)
+        st = self._pf.pop(slot, None)
+        if st is not None and self._chunk_routes:
+            # chunks of the interrupted prefill that no dispatch has taken
+            self._chunk_routes = [c for c in self._chunk_routes
+                                  if c[0] is not st["req"]]
         self._dev_first.pop(slot, None)
         self._adm_pending.discard(slot)
         self._pending_firsts = [t for t in self._pending_firsts
@@ -1368,7 +1398,7 @@ class ServingEngine:
                 .set(jnp.nan)
             self._kv.caches[0] = tuple(layer)
             return
-        k, v = self._kv.caches[0]
+        k, *rest = self._kv.caches[0]       # the first rows leaf
 
         def poison(leaf, *idx):
             if isinstance(leaf, tuple):
@@ -1380,9 +1410,9 @@ class ServingEngine:
             if b >= self._kv.num_blocks:
                 return   # no rows mapped yet (unreachable: _apply_poison
                          # already defers slots with no chunk dispatched)
-            self._kv.caches[0] = (poison(k, b, 0), v)
+            self._kv.caches[0] = (poison(k, b, 0), *rest)
             return
-        self._kv.caches[0] = (poison(k, slot, 0), v)
+        self._kv.caches[0] = (poison(k, slot, 0), *rest)
 
     def _apply_poison(self):
         """Inject every due NaN payload from the fault plan.  Injection
@@ -1865,6 +1895,10 @@ class ServingEngine:
                         w = min(padded.size, self._lmax)
                         row[:w] = padded[:w]
                         self._hist = self._hist.at[slot].set(jnp.asarray(row))
+                if self._n_experts:
+                    # one row a position: an admission prefills (and
+                    # records) everything from row off0 again
+                    r.routes = _head_rows(r.routes, off0)
                 # device-ready prompt length, built here (outside the chunk
                 # dispatch loop) so _spend_prefill stays sync-free
                 self._pf[slot] = {"req": r, "tok": padded, "p": p, "off": off0,
@@ -2072,12 +2106,17 @@ class ServingEngine:
                             # the family's program resets the slot's
                             # recurrent state inside this chunk
                             m.state_resets.inc()
-                        first, okf, self._kv.caches, hist, hist_len = \
-                            self._call_prefill_chunk(
+                        first, okf, self._kv.caches, hist, hist_len, \
+                            *routes = self._call_prefill_chunk(
                                 jnp.asarray(chunk),
                                 jnp.asarray(st["off"], jnp.int32),
                                 st["plen"],
                                 jnp.asarray(slot, jnp.int32))
+                    if routes:
+                        # .preempts: the admission these rows belong to
+                        self._chunk_routes.append(
+                            (st["req"], min(P, st["p"] - st["off"]),
+                             routes[0], st["req"].preempts))
                     if self._mode == "spec":
                         self._hist, self._hist_len = hist, hist_len
                     st["off"] += P
@@ -2350,13 +2389,18 @@ class ServingEngine:
             def go(attempt):
                 self._fault_point("dispatch", attempt)
                 return self._call_decode(cur, host_len)
-            toks, okd, self._kv.caches = self._retry(go, "decode dispatch")
+            toks, okd, self._kv.caches, *routes = self._retry(
+                go, "decode dispatch")
             self._dev_cur = toks[:, -1]
             for i in live:
                 self._kv.lengths[i] += self._sync
             self._inflight = {"kind": "greedy", "toks": toks, "ok": okd,
                               "reqs": list(self._kv.reqs), "live": live,
                               "firsts": firsts, "adm": adm_active}
+            if routes:
+                self._inflight["routes"] = routes[0]
+                self._inflight["chunk_routes"], self._chunk_routes = \
+                    self._chunk_routes, []
         else:
             if self._dev_len is None:
                 dev_len = host_len
@@ -2410,15 +2454,41 @@ class ServingEngine:
         fo = [x for _, _, f, o in firsts for x in (f, o)]
         out = (rec["blk"], rec["j"], rec["ok"]) if spec \
             else (rec["toks"], rec["ok"])
+        # recorded routes ride the same fetch (no extra device sync)
+        routed = [rec["routes"]] + [c[2] for c in rec["chunk_routes"]] \
+            if "routes" in rec else []
         # the ONE blocking fetch of the iteration: what the engine thread
         # waits on the device for (serving_pipeline_stall_seconds)
         with self._phase("drain.wait",
                          observe=m.pipeline_stall if m is not None else None):
-            vals = self._fetch("drain", *out, *fo)
+            vals = self._fetch("drain", *out, *fo, *routed)
         if m is not None:
             m.inflight.set(still_inflight)
+        n = len(out) + len(fo)
         with self._phase("emit"):
-            return self._emit_record(rec, vals[:len(out)], vals[len(out):])
+            if routed:
+                self._take_routes(rec, vals[n], vals[n + 1:])
+            return self._emit_record(rec, vals[:len(out)], vals[len(out):n])
+
+    def _take_routes(self, rec, decode, chunks):
+        """A drained record's recorded routes (``int8``, ``-1`` where a row
+        was not live): the chunks dispatched ahead of this decode
+        dispatch ``[P, L_moe, k]`` go to their requests' ``routes`` row
+        for row, the dispatch's own ``[B, n_steps, L_moe, k]`` are kept on
+        the record for ``_emit_record`` to hand out with the tokens; both
+        feed the expert counters (one ``bincount`` a program)."""
+        m = self._m
+        for (r, n, _, admission), routes in zip(rec["chunk_routes"], chunks):
+            if r.preempts != admission:
+                continue        # parked since: re-admission records anew
+            if r.routes is None:
+                r.routes = []
+            r.routes.append(routes[:n])
+            if m is not None:
+                m.expert_routes("prefill", routes[:n, None], self._n_experts)
+        rec["routes"] = decode
+        if m is not None:
+            m.expert_routes("decode", decode, self._n_experts)
 
     def _emit_record(self, rec, out, fvals):
         """Hand a drained record's tokens to their requests."""
@@ -2452,8 +2522,12 @@ class ServingEngine:
                 if not bool(okd[i]):
                     self._retire(i, "poisoned")
                     continue
-                emitted += self._emit(i, toks[i].tolist())
+                took = self._emit(i, toks[i].tolist())
+                emitted += took
                 self._cur[i] = toks[i, -1]
+                if "routes" in rec and took:
+                    # the routes of each emitted token's INPUT position
+                    rec["reqs"][i].routes.append(rec["routes"][i, :took])
             return emitted
         blk, j, okd = out
         k = rec.get("k", self._spec_k)

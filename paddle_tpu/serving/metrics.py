@@ -11,6 +11,8 @@ tests/test_observability.py).
 """
 from __future__ import annotations
 
+import numpy as np
+
 from paddle_tpu.observability.metrics import get_registry
 
 __all__ = ["EngineMetrics", "DisaggMetrics"]
@@ -186,6 +188,28 @@ class EngineMetrics:
             "serving_kv_rows_live_total",
             "cache rows some live slot attends to, summed over decode "
             "dispatches", L).labels(**lbl)
+        # routed experts (a serving family's routed_experts; never bumped
+        # for a model that has none), fed on the host from the routes a
+        # dispatch or a prefill chunk hands back with its tokens
+        self._expert_tokens = reg.counter(
+            "serving_moe_expert_tokens_total",
+            "live (token, expert) pairs served, all expert layers",
+            ("policy", "expert"))
+        self._experts_touched = reg.counter(
+            "serving_moe_experts_touched_total",
+            "experts with at least one live pair, summed over expert "
+            "layers, steps and runs of a program", ("policy", "program"))
+        self._moe_dispatches = reg.counter(
+            "serving_moe_dispatches_total",
+            "runs of a program whose recorded routes were counted",
+            ("policy", "program"))
+        # the children, looked up once: expert_routes runs on the scheduler
+        # thread at every dispatch and every chunk
+        self._expert_children = {}
+        self._experts_touched, self._moe_dispatches = (
+            {program: c.labels(policy=policy, program=program)
+             for program in ("decode", "prefill")}
+            for c in (self._experts_touched, self._moe_dispatches))
         self.prefill_backlog = reg.gauge(
             "serving_prefill_backlog",
             "prompt chunks still to dispatch across slots mid-prefill",
@@ -390,6 +414,28 @@ class EngineMetrics:
         for m in ("off", "int8"):
             self._weight_quant_mode.labels(policy=self._policy, mode=m).set(
                 1 if m == mode else 0)
+
+    def expert_routes(self, program, routes, n_experts):
+        """Count one run's recorded routes ``int8 [rows, steps, L_moe, k]``
+        (``-1``: the row was not live): pairs by expert, and experts
+        touched a (step, layer)."""
+        live = routes >= 0
+        flat = routes[live]
+        self._moe_dispatches[program].inc()
+        if not flat.size:
+            return
+        counts = np.bincount(flat, minlength=n_experts)
+        for e in np.flatnonzero(counts):
+            child = self._expert_children.get(e)
+            if child is None:
+                child = self._expert_children[e] = self._expert_tokens.labels(
+                    policy=self._policy, expert=str(e))
+            child.inc(int(counts[e]))
+        # distinct (step, layer, expert) triples among the live pairs
+        steps, layers = np.nonzero(live)[1:3]
+        key = (steps * routes.shape[2] + layers) * n_experts + flat
+        self._experts_touched[program].inc(
+            int(np.count_nonzero(np.bincount(key))))
 
     def stream_cb_error(self, etype):
         self._stream_cb_errors.labels(

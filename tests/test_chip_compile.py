@@ -310,6 +310,93 @@ def test_state_space_serving_programs_compile_in_place(one_chip, program):
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
 
 
+# GLM-4.7-Flash's widths (the benchmark's glm47flash_code_steady cell): the
+# dense layer and one expert layer at the cell's geometry
+def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608):
+    """The same two programs of the GLM-4.7-Flash cell; returns ``(lowered,
+    latent leaf's shape, experts' shape)``.  The grouped product's
+    ``interpret`` rule is steered to its TPU branch for the trace."""
+    from paddle_tpu.models import glm4_moe_lite_decode as gd
+    from paddle_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
+                                                 statics_of)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = Glm4MoeLiteConfig(num_hidden_layers=2)
+    cfg = statics_of(c)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bf16 = functools.partial(sds, dtype=jnp.bfloat16)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    h, heads = c.hidden_size, c.num_attention_heads
+    e, f = c.n_routed_experts, c.moe_intermediate_size
+    attn = {"ln1": bf16((h,)), "ln2": bf16((h,)),
+            "w_dq": bf16((h, c.q_lora_rank)),
+            "q_norm": bf16((c.q_lora_rank,)),
+            "w_uq": bf16((c.q_lora_rank, heads * (cfg.nope + cfg.rope))),
+            "w_dkv": bf16((h, cfg.row)), "kv_norm": bf16((cfg.kv_rank,)),
+            "w_uk": bf16((heads, cfg.nope, cfg.kv_rank)),
+            "w_uv": bf16((heads, cfg.kv_rank, cfg.v_dim)),
+            "wo": bf16((heads * cfg.v_dim, h))}
+    dense = dict(attn, gate=bf16((h, c.intermediate_size)),
+                 up=bf16((h, c.intermediate_size)),
+                 down=bf16((c.intermediate_size, h)))
+    experts = (e, h, f)
+    moe = dict(attn, router=bf16((h, e)), router_bias=sds((e,), jnp.float32),
+               e_gate=bf16(experts), e_up=bf16(experts),
+               e_down=bf16((e, f, h)), s_gate=bf16((h, f)),
+               s_up=bf16((h, f)), s_down=bf16((f, h)))
+    params = {"embed": bf16((c.vocab_size, h)), "norm": bf16((h,)),
+              "lm_head": bf16((h, c.vocab_size)), "layers": [dense, moe],
+              "_rope": (bf16((lmax, cfg.rope)), bf16((lmax, cfg.rope)))}
+    leaf = (batch, lmax, cfg.row_stored)
+    caches = [(bf16(leaf),) for _ in range(2)]
+    if program == "decode_steps":
+        return gd.serving_decode_steps.__wrapped__.lower(
+            params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
+            chunk_size=256), leaf, experts
+    return gd.serving_prefill_chunk.__wrapped__.lower(
+        params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+        i32(()), chunk_size=256), leaf, experts
+
+
+@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
+def test_expert_latent_serving_programs_compile_in_place(one_chip, program,
+                                                         monkeypatch):
+    """The two GLM-4.7-Flash serving programs compile for the chip at
+    published widths (64 experts of [2048, 1536], 64 slots x 4,608 latent
+    rows of 576): the grouped expert products are IN the program (three a
+    layer), no weight — 2-D or a stacked expert tensor — and no latent
+    cache leaf is copied into another order, and a chunk trip gathers the
+    latent leaf ONCE a layer (the row is key and value both: not one
+    gather for keys and one for values, as a (k, v) family's two)."""
+    lowered, leaf, experts = _glm_program(one_chip, program, monkeypatch)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r'op_name="[^"]*moe\.experts[^"]*pallas_call',
+                          text)) >= 3
+    assert _weight_copies(text) == []
+    sizes = {n for shape in (experts, leaf)
+             for n in [shape[0] * shape[1] * shape[2]]}
+    for m in re.finditer(r"= bf16\[([\d,]+)\]\S* (?:copy|transpose)\(", text):
+        n = 1
+        for x in m.group(1).split(","):
+            n *= int(x)
+        assert n not in sizes, m.group(0)
+    if program == "decode_steps":
+        # a trip's read of the flat [B * Lmax, 1, R] view: a ``gather``, or
+        # what the compiler makes of it (a loop of window copies into ONE
+        # [8 slots, 256 rows, 1, R] buffer in fast memory) — one a layer
+        flat = "bf16[%d,1,%d]" % (leaf[0] * leaf[1], leaf[2])
+        reads = [ln for ln in text.split("\n") if " gather(" in ln
+                 and flat in ln] + re.findall(
+            r"ROOT %%dynamic-update-slice\S* = bf16\[8,256,1,%d\]" % leaf[2],
+            text)
+        assert len(reads) == 2, reads
+    # donated caches are updated in place and nothing leaf-sized is made:
+    # stored [B, Lmax, 1, 576] the leaf was copied whole four times a run
+    # (768 MB of temporaries at 2 layers; now 8)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
 # The decode program's cache read (PERF.md, PR 30).  Counts of the whole
 # compiled text at 2 layers: (fusion, while, custom-call).  PARENT: commit
 # 16e570b (PR 28's tree: one batch-wide chunk loop a layer), read by this

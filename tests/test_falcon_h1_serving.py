@@ -28,7 +28,7 @@ from paddle_tpu.models.falcon_h1 import (
     FalconH1Config, FalconH1ForCausalLM, statics_of,
 )
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.models.serving_family import family_of
+from paddle_tpu.models.serving_family import RowsLeaves, family_of
 from paddle_tpu.observability.metrics import MetricsRegistry
 from paddle_tpu.ops import ssm
 from paddle_tpu.serving import Request, ServingEngine
@@ -428,7 +428,7 @@ def test_llama_family_is_the_llama_programs():
     assert fam.spec_step is ld.serving_spec_step
     assert fam.spec_draft_step is ld.serving_spec_draft_step
     params, cfg = fam.decode_params(llama, LMAX)
-    assert fam.kv_geometry(cfg) == (4, 2, 16)
+    assert fam.rows_leaves(cfg) == RowsLeaves(2, (2, 16), 4)
     k, v = fam.init_layer_cache(cfg, 3, LMAX, "float32")
     assert k.shape == v.shape == (3, LMAX, 2, 16)
     assert dataclasses.is_dataclass(fam)

@@ -15,14 +15,17 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.models import falcon_h1_decode
+from paddle_tpu.models import falcon_h1_decode, glm4_moe_lite_decode
 from paddle_tpu.models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
+from paddle_tpu.models.glm4_moe_lite import (
+    Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
+)
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.llama_decode import (
     serving_decode_steps, serving_prefill_chunk,
 )
 from paddle_tpu.observability.trace import (
-    COUNTERS, LOOPS, SCOPES, SPANS, STATE_SCOPES,
+    COUNTERS, EXPERT_SCOPES, LOOPS, SCOPES, SPANS, STATE_SCOPES,
 )
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.static.functionalize import build_train_step
@@ -36,7 +39,9 @@ READER_PATTERNS = ("flash", "_step_fn", "serving_decode_steps",
 MODULE_NAMES = {"decode": "serving_decode_steps",
                 "prefill": "serving_prefill_chunk", "train": "_step_fn",
                 "ssm_decode": "serving_decode_steps",
-                "ssm_prefill": "serving_prefill_chunk"}
+                "ssm_prefill": "serving_prefill_chunk",
+                "moe_decode": "serving_decode_steps",
+                "moe_prefill": "serving_prefill_chunk"}
 SERVING = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
            "attn.core", "attn.out", "mlp", "lm_head", "sample",
            "attn.core.chunks")
@@ -49,6 +54,10 @@ APPLIES = {
     "prefill": SERVING,
     "ssm_decode": SERVING + SSM + ("decode.steps", "ssm.state_update"),
     "ssm_prefill": SERVING + SSM + ("ssm.scan",),
+    # latent attention + routed experts (models/glm4_moe_lite_decode.py):
+    # the dense first layer keeps ``mlp``
+    "moe_decode": SERVING + EXPERT_SCOPES + ("decode.steps",),
+    "moe_prefill": SERVING + EXPERT_SCOPES,
     "train": ("embed", "norm", "attn.qkv", "attn.rope", "attn.core",
               "attn.out", "mlp", "lm_head", "loss", "optimizer"),
 }
@@ -78,6 +87,14 @@ def tiny_ssm_engine(**kw):
                          decode_chunk=16, **kw)
 
 
+def tiny_moe_engine(**kw):
+    paddle.seed(0)
+    model = Glm4MoeLiteForCausalLM(Glm4MoeLiteConfig.tiny())
+    model.eval()
+    return ServingEngine(model, batch_size=2, max_len=64, prefill_chunk=16,
+                         decode_chunk=16, **kw)
+
+
 def tiny_train_step(seed=0):
     paddle.seed(seed)
     model = LlamaForCausalLM(LlamaConfig.tiny(
@@ -96,7 +113,20 @@ def lowered():
     rows, scalar = jnp.zeros((2,), jnp.int32), jnp.int32(0)
     step, ids = tiny_train_step()
     ssm = tiny_ssm_engine()
+    glm = tiny_moe_engine()
     return {
+        "moe_decode":
+            glm4_moe_lite_decode.serving_decode_steps.__wrapped__.lower(
+                glm._params, glm._cfg, rows, glm._kv.caches, rows,
+                n_steps=glm._sync, chunk_size=glm._chunk, block_tables=None,
+                program_key=glm._pk),
+        "moe_prefill":
+            glm4_moe_lite_decode.serving_prefill_chunk.__wrapped__.lower(
+                glm._params, glm._cfg, jnp.zeros((1, 16), jnp.int32), scalar,
+                jnp.zeros((1,), jnp.int32), glm._kv.caches, scalar,
+                hist=None, hist_len=None, with_hist=False,
+                chunk_size=glm._chunk, block_tables=None,
+                program_key=glm._pk),
         "ssm_decode": falcon_h1_decode.serving_decode_steps.__wrapped__.lower(
             ssm._params, ssm._cfg, rows, ssm._kv.caches, rows,
             n_steps=ssm._sync, chunk_size=ssm._chunk, block_tables=None,
@@ -154,7 +184,7 @@ def test_scope_names_the_compiled_operations(op_names, program, name):
 @pytest.mark.parametrize("program", sorted(APPLIES))
 def test_program_carries_no_name_outside_its_list(op_names, program):
     found = {c for path in op_names[program] for c in components(path)
-             if c in SCOPES + STATE_SCOPES + LOOPS}
+             if c in SCOPES + STATE_SCOPES + EXPERT_SCOPES + LOOPS}
     assert found == set(APPLIES[program])
 
 
@@ -162,12 +192,24 @@ def test_loops_are_named(op_names):
     """A %while of a device trace can be told: the cache-chunk loop's own
     ``while`` sits under its name, and the step's operations under the
     scan's (at ``sync_every=1`` XLA takes the one-trip loop itself away)."""
-    for program in ("decode", "ssm_decode"):
+    for program in ("decode", "ssm_decode", "moe_decode"):
         assert any("decode.steps/while/body/" in path
                    for path in op_names[program])
-    for program in ("decode", "prefill", "ssm_decode", "ssm_prefill"):
+    for program in ("decode", "prefill", "ssm_decode", "ssm_prefill",
+                    "moe_decode", "moe_prefill"):
         assert any(path.endswith("attn.core.chunks/while")
                    for path in op_names[program])
+
+
+def test_latent_read_is_named_at_its_call(op_names):
+    """On the chip the latent read's gather becomes a loop of window copies
+    whose operations keep the path of the ``decode_attention`` CALL and
+    lose the scopes inside it (PERF.md section 5, PR 33): the scope the
+    model opens around the call is what names them."""
+    inside = [p for p in op_names["moe_decode"] if "jit(decode_attention)" in p]
+    assert inside and all(
+        "attn.core" in components(p.split("jit(decode_attention)")[0])
+        for p in inside)
 
 
 def test_backward_and_recompute_keep_the_forwards_names(op_names):
@@ -179,7 +221,8 @@ def test_backward_and_recompute_keep_the_forwards_names(op_names):
                for p in paths)
 
 
-@pytest.mark.parametrize("name", SCOPES + STATE_SCOPES + LOOPS + SPANS)
+@pytest.mark.parametrize("name", SCOPES + STATE_SCOPES + EXPERT_SCOPES
+                         + LOOPS + SPANS)
 def test_vocabulary_avoids_the_readers_patterns(name):
     assert not any(pat in name or name in pat for pat in READER_PATTERNS)
     assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)*", name)
@@ -187,7 +230,7 @@ def test_vocabulary_avoids_the_readers_patterns(name):
 
 def test_applies_covers_the_vocabulary():
     assert set().union(*map(set, APPLIES.values())) \
-        == set(SCOPES + STATE_SCOPES + LOOPS)
+        == set(SCOPES + STATE_SCOPES + EXPERT_SCOPES + LOOPS)
 
 
 def test_state_counters_are_the_names_the_readers_ask_for():
@@ -202,6 +245,28 @@ def test_state_counters_are_the_names_the_readers_ask_for():
     lbl = dict(policy="continuous")
     assert reg.get("serving_state_bytes").labels(**lbl).value > 0
     assert reg.get("serving_state_resets_total").labels(**lbl).value == 1
+
+
+def test_expert_counters_are_the_names_the_readers_ask_for():
+    """``serving_moe_*``: what a model with routed experts adds to the
+    registry — pairs by expert, experts touched and counted runs by
+    program —, fed from the routes its programs hand back."""
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = tiny_moe_engine(registry=reg)
+    r = eng.submit(Request(PROMPTS[0], 3))
+    eng.run()
+    snap = reg.snapshot()
+    by = lambda name, label: {s["labels"][label]: s["value"]
+                              for s in snap[name]["series"]}
+    pairs = by("serving_moe_expert_tokens_total", "expert")
+    assert set(pairs) <= {str(e) for e in range(8)}
+    rows = sum(len(x) for x in r.routes)
+    assert rows == len(PROMPTS[0]) + 3 - 1
+    assert sum(pairs.values()) >= rows * 2 * 2          # 2 layers x top-2
+    assert by("serving_moe_dispatches_total", "program")["prefill"] == 2
+    assert by("serving_moe_experts_touched_total", "program")["decode"] > 0
 
 
 @pytest.mark.parametrize("name", COUNTERS)
