@@ -210,7 +210,8 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
     at the prompt's last column relative to the chunk, meaningful in the
     final chunk only), plus the state's reset, carry and padding rules of
     this module's docstring.  ``P`` must be a multiple of
-    ``mamba_chunk_size``."""
+    ``mamba_chunk_size`` (one prefill chunk or the whole chunks of one
+    run: the scan carries the state across them)."""
     _mon.mark_trace("serving_prefill_chunk")
     _refuse(with_hist=with_hist, block_tables=block_tables is not None)
     t = tokens.shape[1]

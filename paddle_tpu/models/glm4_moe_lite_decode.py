@@ -173,8 +173,9 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
     latent rows — ``llama_decode._serving_prefill_chunk_impl``'s contract
     (one compiled program for every prompt length; the greedy pick at the
     prompt's last column relative to the chunk, meaningful in the final
-    chunk only) plus a sixth result: the chunk's recorded routes
-    ``int8 [P, L_moe, k]``."""
+    chunk only) plus a sixth result: the recorded routes of the run's
+    rows ``int8 [P, L_moe, k]`` (``P``: one prefill chunk or the whole
+    chunks of one run)."""
     _mon.mark_trace("serving_prefill_chunk")
     _refuse(with_hist=with_hist, block_tables=block_tables is not None)
     t = tokens.shape[1]

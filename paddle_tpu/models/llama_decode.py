@@ -644,10 +644,12 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
                                 caches, slot, hist=None, hist_len=None,
                                 with_hist=False, chunk_size=None,
                                 block_tables=None, program_key=None):
-    """Process the next ``[1, P]`` chunk of an admitted prompt against the
+    """Process the next ``[1, P]`` rows of an admitted prompt against the
     slot's rows of the batch cache — ONE compiled program for every prompt
     length (``P`` is the only shape; ``offset``, ``prompt_len`` and
-    ``slot`` are traced operands).
+    ``slot`` are traced operands).  The body is generic in ``P``: the
+    engine runs it at one prefill chunk and at the power-of-two multiples
+    a scheduler step may spend on one prompt (a program a width).
 
     ``tokens [1, P]`` is the chunk, right-padded past the prompt tail;
     ``offset`` (traced scalar) is the device-carried write cursor — chunk

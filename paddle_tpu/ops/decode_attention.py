@@ -825,6 +825,27 @@ def _prefill_dispatch(q, k_new, v_new, k_cache, v_cache, slot, offset,
 def slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
                            scale=None, chunk_size=None, block_table=None,
                            attn_impl=None, prefill_impl=None, v_width=None):
+    """``_slot_prefill_attention`` (below: the contract), jitted as
+    ``decode_attention`` is: a prefill program's layers call it at one set
+    of shapes, so its body is traced and lowered ONCE a program and not
+    once a layer — half of what tracing a 16-layer prefill program costs,
+    and a serving engine traces one such program a run width in its set-up
+    (PERF.md, PR 34).  The call sits under ``attn.core``: what the compiler
+    makes at the call's boundary keeps only the call's own path, and the
+    scopes inside still name everything else."""
+    with jax.named_scope("attn.core"):
+        return _slot_prefill_attention(
+            q, k_new, v_new, k_cache, v_cache, slot, offset, scale=scale,
+            chunk_size=chunk_size, block_table=block_table,
+            attn_impl=attn_impl, prefill_impl=prefill_impl, v_width=v_width)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "chunk_size", "attn_impl",
+                                    "prefill_impl", "v_width"))
+def _slot_prefill_attention(q, k_new, v_new, k_cache, v_cache, slot, offset,
+                            scale=None, chunk_size=None, block_table=None,
+                            attn_impl=None, prefill_impl=None, v_width=None):
     """Chunked-prefill attention for ONE slot of the batch cache.
 
     The serving engine's chunked admission path processes a prompt in

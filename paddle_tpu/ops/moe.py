@@ -83,6 +83,20 @@ def _grouped(x, w, sizes, interpret=None):
 
 
 def expert_ffn(x, experts, gates, live, w_gate, w_up, w_down):
+    """``_expert_ffn`` (below: the contract), jitted: an expert model's
+    layers call it at one set of shapes, so the ordering, the three
+    grouped products and the combine are traced and lowered once a
+    program and not once a layer — a serving engine traces one prefill
+    program a run width in its set-up (PERF.md, PR 34).  The call sits
+    under ``moe.dispatch``: what the compiler makes at the call's boundary
+    (the pairs' coming and going) keeps only the call's own path, and the
+    scopes inside still name everything else."""
+    with jax.named_scope("moe.dispatch"):
+        return _expert_ffn(x, experts, gates, live, w_gate, w_up, w_down)
+
+
+@jax.jit
+def _expert_ffn(x, experts, gates, live, w_gate, w_up, w_down):
     """``sum_e gates_e * down_e(silu(gate_e x) * up_e x)`` over each live
     row's chosen experts.  x [T, h]; experts, gates [T, k]; live [T] bool;
     w_gate, w_up [E, h, f]; w_down [E, f, h].  Every shape is static in

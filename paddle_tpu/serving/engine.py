@@ -11,17 +11,24 @@ program keeps ONE static compiled shape:
   finished slots (EOS / max-new-tokens) and admits queued requests into
   them *between* compiled steps.
 * **Chunked prefill with budgeted interleaving** (``prefill_chunk``,
-  default 256; ``prefill_budget`` chunks per scheduler step).  Admission
-  is INCREMENTAL: an admitted request enters a ``prefilling`` state and
-  its prompt is processed in fixed ``[1, P]`` chunks
+  default 256; ``prefill_budget`` chunks of prompt per scheduler step).
+  Admission is INCREMENTAL: an admitted request enters a ``prefilling``
+  state and its prompt is processed in whole ``P``-row chunks
   (``serving_prefill_chunk``) written straight into the slot's rows of
-  the batch cache at a device-carried offset — ONE compiled program for
-  every prompt length (short/tail chunks are length-masked, zero
-  retraces in steady state), and each scheduler step spends at most
+  the batch cache at a device-carried offset — short/tail chunks are
+  length-masked, and each scheduler step spends at most
   ``prefill_budget`` chunks before dispatching the decode step, so a
   long prompt never stalls resident decode for its full prefill
   (Sarathi-style stall-free admission: the TPOT spike at admission is
-  bounded by the budget).  The final chunk's program also returns the
+  bounded by the budget).  The chunks a step spends on ONE prompt ride
+  in ONE run of the program — ``[1, k * P]`` rows, ``k`` the widest
+  power of two the budget and the prompt have left — so the weights are
+  read once a step, not once a chunk; the program is generic in its row
+  count, the power-of-two ladder (``_run_widths``) bounds the compiled
+  programs at ``log2(prefill_budget) + 1`` for every prompt length, and
+  the engine's first prefill run rehearses the other widths
+  (``_rehearse_widths``), so nothing retraces or compiles after the
+  first prefill-spending step.  The final run's program also returns the
   first sampled token — it stays device-resident and feeds the slot's
   first decode dispatch without a host round-trip; the host copy is
   synced at the next drain.  Chunked prefill is the only prefill: a
@@ -441,11 +448,12 @@ class ServingEngine:
     (ops/decode_attention.py); ``None`` reads the full ``[B, max_len]``
     cache every step.  The default (256) falls back to the full read
     automatically when ``max_len <= 256``.
-    ``prefill_chunk``: prompt tokens per chunked-prefill dispatch (one
-    compiled program for every prompt length; clamped to ``max_len``).
-    ``prefill_budget``: max prefill chunks dispatched per scheduler step
+    ``prefill_chunk``: prompt rows per chunk of the chunked prefill, the
+    unit a prompt is spent in (clamped to ``max_len``).
+    ``prefill_budget``: max chunks of prompt spent per scheduler step
     before the decode step goes out — bounds how long resident decode can
-    stall on an admission.
+    stall on an admission.  The chunks a step spends on one prompt go out
+    as one run of the prefill program (a power of two of them a run).
     ``kv_block``: paged KV cache — the per-layer cache becomes a global
     ``[num_blocks, kv_block, Hkv, D]`` pool indirected through per-slot
     block tables (serving/kv_cache.PagedKVCacheManager), with
@@ -983,8 +991,8 @@ class ServingEngine:
         # dispatch and of a prefill chunk, the experts that served each
         # live row: drained with the tokens, counted, and appended to
         # Request.routes.  ``_chunk_routes``: (request, real rows, device
-        # array, the request's ``preempts`` then) of chunks dispatched
-        # since the last decode dispatch, whose record they ride
+        # array, the request's ``preempts`` then) of the prefill runs
+        # dispatched since the last decode dispatch, whose record they ride
         self._n_experts = (fam.routed_experts(self._params)
                            if fam.routed_experts is not None else 0)
         self._chunk_routes = []
@@ -1054,10 +1062,14 @@ class ServingEngine:
         # = admission order, the budget-spend order), the device-resident
         # first token of slots whose final chunk is dispatched but whose
         # host copy has not been drained yet, the (slot, request, first)
-        # triples awaiting host emission
+        # triples awaiting host emission; the widths (in chunks) a prefill
+        # run may take, and whether each has been compiled (the first run
+        # rehearses the others)
         self._pf = {}
         self._dev_first = {}
         self._pending_firsts = []
+        self._widths = self._run_widths()
+        self._widths_warm = len(self._widths) == 1
         self._t_lastdrain = None
         # reliability state: the bounded admission queue, the dispatch
         # retry policy, the fault-injection plan (None in production) and
@@ -2064,19 +2076,89 @@ class ServingEngine:
         return slot
 
     def _spend_prefill(self):
-        """Dispatch up to ``prefill_budget`` prompt chunks across the
+        """Spend up to ``prefill_budget`` chunks of prompt across the
         slots mid-prefill, admission order first (the earliest admission
-        reaches its first token soonest).  Every chunk dispatch is async
+        reaches its first token soonest).  Every run dispatch is async
         and feeds off device-resident state (the carried caches / hist /
         write offset) — the loop never syncs, the tpu-lint PTL004 rule
         polices that.  A slot whose FINAL chunk went out leaves the
         prefilling state: it joins the very next decode dispatch with its
         device-resident first token, and the host copy is emitted at the
-        next drain.  Returns the number of chunks dispatched."""
+        next drain.  Returns the number of chunks spent."""
         if not self._pf:
             return 0
         with self._phase("spend_prefill", prefilling=len(self._pf)):
             return self._spend_chunks()
+
+    def _run_widths(self):
+        """The widths, in chunks, ONE prefill run may take: 1 and every
+        further power of two up to what a step may spend
+        (``prefill_budget``) and the longest prompt holds — the ladder
+        bounds the compiled prefill programs at ``log2(budget) + 1``, as
+        ``_k_rungs`` bounds the draft depths.  The one place that says
+        where the geometry keeps a run at one chunk: a run is whole chunks
+        from a chunk boundary, so what the constructor demands of
+        ``prefill_chunk`` as a multiple (whole SSD chunks, whole kv
+        blocks) holds for every width, and the reference append scatters
+        row by row at any offset; but a run starts where the last one
+        ended, NOT on a boundary of its own rows, and the fused prefill
+        kernel's appends (``prefill_impl="pallas"``) are DMA windows that
+        rely on ``offset % rows == 0`` — there a run stays one chunk."""
+        top = min(self._pbudget, -(-self._lmax // self._pchunk))
+        if self._prefill_impl == "pallas":
+            top = 1
+        return [1 << i for i in range(top.bit_length())]
+
+    def _run_tokens(self, st, off, k):
+        """The ``[1, k * P]`` prompt rows of a run from ``off`` (zeros
+        past the padded prompt: a rehearsal wider than what is left)."""
+        n = k * self._pchunk
+        tok = st["tok"][off:off + n]
+        if tok.size < n:
+            tok = np.pad(tok, (0, n - tok.size))
+        return tok[None, :]
+
+    def _prefill_run(self, slot, st, k):
+        """Dispatch ONE run of the prefill program over the slot's next
+        ``k`` chunks, carrying the donated caches (and the draft history
+        in spec mode).  Returns (first, ok, [routes])."""
+        first, okf, self._kv.caches, hist, hist_len, *routes = \
+            self._call_prefill_chunk(
+                jnp.asarray(self._run_tokens(st, st["off"], k)),
+                jnp.asarray(st["off"], jnp.int32), st["plen"],
+                jnp.asarray(slot, jnp.int32))
+        if self._mode == "spec":
+            self._hist, self._hist_len = hist, hist_len
+        return first, okf, routes
+
+    def _draft_prefill_run(self, slot, st, k):
+        """The same for the resident draft model's own cursor."""
+        self._call_draft_prefill_chunk(
+            self._run_tokens(st, st["doff"], k), st["doff"], st["plen"],
+            slot)
+
+    def _rehearse_widths(self, slot, st, k):
+        """The engine's first prefill run: run every OTHER width of the
+        ladder at the same ``(slot, offset)`` just before it, so that by
+        the end of this scheduler step every prefill program a later step
+        can dispatch (target and draft) is compiled and in the jit's
+        cache under the very operands later runs bring — the window of a
+        service never compiles for a prompt length its warm-up did not
+        happen to draw.  A run is idempotent on what it writes: the real
+        runs rewrite the prompt's rows, rows past the prompt are invisible
+        (and drop past ``max_len``; on unmapped blocks when paged), a
+        family's recurrent state is reset inside the run at offset 0 —
+        where an engine's first run always is for a family that has one —
+        and the draft history's frontier is set again by the final run.
+        Nothing of a rehearsal is counted, marked or recorded."""
+        for w in self._widths:
+            if w == k:
+                continue
+            if st["off"] < st["p"]:
+                self._prefill_run(slot, st, w)
+            if self._dspec and st["doff"] < st["p"]:
+                self._draft_prefill_run(slot, st, w)
+        self._widths_warm = True
 
     def _spend_chunks(self):
         m = self._m
@@ -2087,68 +2169,76 @@ class ServingEngine:
             if not budget:
                 break
             st = self._pf[slot]
+            req, p = st["req"], st["p"]
             while budget:
-                if st["off"] < st["p"]:
+                # ONE run takes as many whole chunks as the budget and
+                # the prompt have left (the widest rung of the ladder
+                # that fits): the weights are read once for all of them.
+                # The draft model's cursor is independent — a
+                # target-side radix hit skips chunks the draft may still
+                # need — and rides the same budget unit: k target chunks
+                # + k draft chunks per spend of k
+                cursors = (st["off"], st["doff"]) if self._dspec \
+                    else (st["off"],)
+                left = [-(-(p - off) // P) for off in cursors if off < p]
+                k = max(w for w in self._widths if w <= min([budget] + left))
+                if not self._widths_warm:
+                    self._rehearse_widths(slot, st, k)
+                if st["off"] < p:
+                    c0, last = st["off"] // P, (p - 1) // P
+                    if self._fr is not None and req._trace is not None:
+                        # one mark a CHUNK whatever the run's width, all
+                        # at the run's dispatch: a request's marks say
+                        # how much of its prompt was spent when
+                        for c in range(c0, c0 + k):
+                            req._trace.mark("prefilling", slot=slot,
+                                            chunk=c, final=c == last)
                     with self._phase(
-                            "prefill_chunk", st["req"], mark="prefilling",
-                            slot=slot, chunk=st["off"] // P,
-                            final=st["off"] + P >= st["p"]):
+                            "prefill_chunk", req, slot=slot, chunk=c0,
+                            chunks=k, final=c0 + k > last):
                         if self._paged:
-                            # map the chunk's REAL rows before its writes
+                            # map the run's REAL rows before its writes
                             # dispatch (pad columns past the prompt drop
                             # on the sentinel); draws down the
                             # reservation made at admission
                             self._kv.ensure_rows(
-                                slot, min(st["off"] + P, st["p"]))
-                        chunk = st["tok"][st["off"]:st["off"] + P][None, :]
+                                slot, min(st["off"] + k * P, p))
                         if (m is not None and self._state_idx
                                 and st["off"] == 0):
                             # the family's program resets the slot's
-                            # recurrent state inside this chunk
+                            # recurrent state inside this run
                             m.state_resets.inc()
-                        first, okf, self._kv.caches, hist, hist_len, \
-                            *routes = self._call_prefill_chunk(
-                                jnp.asarray(chunk),
-                                jnp.asarray(st["off"], jnp.int32),
-                                st["plen"],
-                                jnp.asarray(slot, jnp.int32))
+                        first, okf, routes = self._prefill_run(slot, st, k)
                     if routes:
+                        # one record a RUN, with its real rows;
                         # .preempts: the admission these rows belong to
                         self._chunk_routes.append(
-                            (st["req"], min(P, st["p"] - st["off"]),
-                             routes[0], st["req"].preempts))
-                    if self._mode == "spec":
-                        self._hist, self._hist_len = hist, hist_len
-                    st["off"] += P
+                            (req, min(k * P, p - st["off"]), routes[0],
+                             req.preempts))
+                    st["off"] += k * P
                     if m is not None:
-                        m.prefill_chunks.inc()
-                    if st["off"] >= st["p"]:
+                        m.prefill_chunks.inc(k)
+                        m.prefill_runs.inc()
+                    if st["off"] >= p:
                         # only the FINAL chunk's finite flag is meaningful
                         # (its query attends the whole prefix) — it rides
                         # with the first token and is checked at emission
                         st["first"], st["okf"] = first, okf
-                if self._dspec and st["doff"] < st["p"]:
-                    # the draft model's prompt KV rides the same budget
-                    # unit: one target chunk + one draft chunk per spend
-                    # (the draft forward is a fraction of the target's
-                    # cost).  Its cursor is independent — a target-side
-                    # radix hit skips chunks the draft may still need
+                if self._dspec and st["doff"] < p:
                     if self._paged:
                         self._kv.ensure_draft_rows(
-                            slot, min(st["doff"] + P, st["p"]))
-                    dchunk = st["tok"][st["doff"]:st["doff"] + P][None, :]
-                    self._call_draft_prefill_chunk(
-                        dchunk, st["doff"], st["plen"], slot)
-                    st["doff"] += P
-                budget -= 1
-                spent += 1
-                if st["off"] >= st["p"] and (
-                        not self._dspec or st["doff"] >= st["p"]):
+                            slot, min(st["doff"] + k * P, p))
+                    self._draft_prefill_run(slot, st, k)
+                    st["doff"] += k * P
+                budget -= k
+                spent += k
+                if st["off"] >= p and (
+                        not self._dspec or st["doff"] >= p):
                     del self._pf[slot]
-                    self._kv.lengths[slot] = st["p"]
+                    self._kv.lengths[slot] = p
                     self._dev_first[slot] = st["first"]
                     self._pending_firsts.append(
-                        (slot, st["req"], st["first"], st["okf"]))
+                        (slot, req, st["first"], st["okf"]))
                     break
         if m is not None:
             m.prefill_backlog.set(sum(
@@ -2472,9 +2562,10 @@ class ServingEngine:
 
     def _take_routes(self, rec, decode, chunks):
         """A drained record's recorded routes (``int8``, ``-1`` where a row
-        was not live): the chunks dispatched ahead of this decode
-        dispatch ``[P, L_moe, k]`` go to their requests' ``routes`` row
-        for row, the dispatch's own ``[B, n_steps, L_moe, k]`` are kept on
+        was not live): the prefill runs dispatched ahead of this decode
+        dispatch (``[rows, L_moe, k]`` each) go to their requests'
+        ``routes`` row for row, the dispatch's own
+        ``[B, n_steps, L_moe, k]`` are kept on
         the record for ``_emit_record`` to hand out with the tokens; both
         feed the expert counters (one ``bincount`` a program)."""
         m = self._m

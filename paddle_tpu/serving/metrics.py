@@ -164,7 +164,12 @@ class EngineMetrics:
             "the constructor knob)", L).labels(**lbl)
         self.prefill_chunks = reg.counter(
             "serving_prefill_chunks_total",
-            "prompt chunks dispatched by the chunked-prefill path",
+            "prompt chunks (prefill_chunk rows each) spent by the "
+            "chunked-prefill path", L).labels(**lbl)
+        self.prefill_runs = reg.counter(
+            "serving_prefill_runs_total",
+            "runs of the prefill program, of any width: chunks / runs is "
+            "how many chunks one read of the weights served",
             L).labels(**lbl)
         # recurrent state beside the K/V rows (a serving family's
         # state_leaves; 0 and never bumped for a model that has none)
@@ -212,7 +217,7 @@ class EngineMetrics:
             for c in (self._experts_touched, self._moe_dispatches))
         self.prefill_backlog = reg.gauge(
             "serving_prefill_backlog",
-            "prompt chunks still to dispatch across slots mid-prefill",
+            "prompt chunks still to spend across slots mid-prefill",
             L).labels(**lbl)
         self.tpot_admission = reg.histogram(
             "serving_tpot_during_admission_seconds",

@@ -202,9 +202,10 @@ def _weight_copies(text):
             if int(m.group(1)) * int(m.group(2)) >= 2 ** 22]
 
 
-def _mistral_program(one_chip, program, batch, lmax):
+def _mistral_program(one_chip, program, batch, lmax, rows=T_PREFILL):
     """The decode-steps or prefill-chunk program of the Mistral serving
-    cells (2 layers, abstract operands), lowered for the described chip."""
+    cells (2 layers, abstract operands), lowered for the described chip;
+    ``rows``: the prompt rows of a prefill run."""
     from paddle_tpu.models import llama_decode as ld
 
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
@@ -227,7 +228,7 @@ def _mistral_program(one_chip, program, batch, lmax):
             params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
             chunk_size=256)
     return ld.serving_prefill_chunk.__wrapped__.lower(
-        params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+        params, cfg, i32((1, rows)), i32(()), i32((1,)), caches,
         i32(()), chunk_size=256)
 
 
@@ -312,7 +313,8 @@ def test_state_space_serving_programs_compile_in_place(one_chip, program):
 
 # GLM-4.7-Flash's widths (the benchmark's glm47flash_code_steady cell): the
 # dense layer and one expert layer at the cell's geometry
-def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608):
+def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608,
+                 rows=T_PREFILL):
     """The same two programs of the GLM-4.7-Flash cell; returns ``(lowered,
     latent leaf's shape, experts' shape)``.  The grouped product's
     ``interpret`` rule is steered to its TPU branch for the trace."""
@@ -354,7 +356,7 @@ def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608):
             params, cfg, i32((batch,)), caches, i32((batch,)), n_steps=1,
             chunk_size=256), leaf, experts
     return gd.serving_prefill_chunk.__wrapped__.lower(
-        params, cfg, i32((1, T_PREFILL)), i32(()), i32((1,)), caches,
+        params, cfg, i32((1, rows)), i32(()), i32((1,)), caches,
         i32(()), chunk_size=256), leaf, experts
 
 
@@ -395,6 +397,65 @@ def test_expert_latent_serving_programs_compile_in_place(one_chip, program,
     # stored [B, Lmax, 1, 576] the leaf was copied whole four times a run
     # (768 MB of temporaries at 2 layers; now 8)
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
+# A prefill run of TWO chunks (PERF.md, PR 34: the chunks a scheduler step
+# spends on one prompt ride in one run).  Temporaries of the compiled run
+# at 2 layers, MB: (one chunk, two chunks, the ceiling held here).
+_WIDE_TEMP_MB = {"mistral7b_serve_long": (38.6, 59.0, 64),
+                 "glm47flash_serve": (7.9, 9.4, 16)}
+
+
+def _own_instructions(text):
+    """The lines of a compiled text's ENTRY computation and of its loops'
+    bodies and conditions: the instructions that run on their own, each a
+    pass over its operands (what a fused computation holds is not)."""
+    comps = {m.group(1): m.group(2).split("\n") for m in re.finditer(
+        r"\n(?:ENTRY )?(%?[\w.\-]+) \([^\n]*\) -> [^\n]* \{\n(.*?)\n\}",
+        text, re.S)}
+    names = [re.search(r"\nENTRY (%?[\w.\-]+) ", text).group(1)]
+    names += sorted(set(re.findall(r"(?:body|condition)=(%[\w.\-]+)", text)))
+    return [ln for name in names for ln in comps[name]]
+
+
+@pytest.mark.parametrize("config", sorted(_WIDE_TEMP_MB))
+def test_two_chunk_prefill_run_reads_weights_as_stored(one_chip, config,
+                                                       monkeypatch):
+    """The ``[1, 2 * 256]`` run of the prefill program at the rag and the
+    GLM cell's geometry is the one-chunk program at twice the rows and
+    nothing else: nothing of a weight's size is copied, transposed or
+    converted on its own (outside a fusion only activations are: the
+    run's rows are one of their dims), each
+    stacked expert tensor is an operand of ONE grouped product and of
+    nothing else, and the temporaries stay under the ceiling (a weight
+    materialised in another order or dtype is 8 MB at the least)."""
+    rows = 2 * T_PREFILL
+    if config == "glm47flash_serve":
+        lowered, _, experts = _glm_program(one_chip, "prefill_chunk",
+                                           monkeypatch, rows=rows)
+    else:
+        lowered = _mistral_program(one_chip, "prefill_chunk", 16, 4096,
+                                   rows=rows)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    relayout = re.compile(
+        r"= \w+\[([\d,]+)\]\S* (?:copy|transpose|convert)\(")
+    for ln in _own_instructions(text):
+        m = relayout.search(ln)
+        if m is None:
+            continue
+        dims = [int(x) for x in m.group(1).split(",")]
+        size = functools.reduce(lambda a, b: a * b, dims)
+        assert size < 2 ** 22 or rows in dims, ln[:160]
+    if config == "glm47flash_serve":
+        for leaf in ("e_gate", "e_up", "e_down"):
+            uses = [ln for ln in text.split("\n")
+                    if re.search(r"[(,] ?%%params\S*%s\S*[,)]" % leaf, ln)]
+            assert len(uses) == 1, (leaf, [u[:120] for u in uses])
+            assert "tpu_custom_call" in uses[0] and re.search(
+                r'op_name="[^"]*moe\.experts[^"]*pallas_call', uses[0])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < _WIDE_TEMP_MB[config][2] * 2 ** 20, temp
 
 
 # The decode program's cache read (PERF.md, PR 30).  Counts of the whole

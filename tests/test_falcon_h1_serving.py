@@ -14,12 +14,14 @@ reads 0.1-1).  The chunked scan against the token-by-token recurrence:
 1e-5 relative on outputs of O(1).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _wide_runs as W
 import paddle_tpu as paddle
 from benchmark.lib import falcon_h1_ref as ref
 from benchmark.models import falcon_h1 as arch
@@ -299,6 +301,50 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
     for (s1, t1), (s2, t2) in zip(state_of(used, 0), state_of(fresh, 0)):
         np.testing.assert_array_equal(s1, s2)
         np.testing.assert_array_equal(t1, t2)
+
+
+# (c') the chunks a step spends on one prompt ride in ONE run
+def wide(model):
+    return W.family(engine, model)
+
+
+@functools.lru_cache(maxsize=None)
+def _served_wide(model, budget, length):
+    return W.streams(W.serve(wide(model), budget, length)[1])
+
+
+@pytest.mark.parametrize("length", W.LENGTHS)
+@pytest.mark.parametrize("budget", W.BUDGETS)
+def test_wide_runs_serve_the_chunk_a_run_engines_streams(model, budget,
+                                                         length):
+    """Whatever the budget, tokens and finite flags are those of the engine
+    that runs a chunk a run (``prefill_budget=1``): the scan carries the
+    state across the SSD chunks of a run as the cache carries it across
+    runs, and a padded tail leaves it alone."""
+    got = _served_wide(model, budget, length)
+    assert [s for s, _ in got] == ["done", "done"]
+    assert got == _served_wide(model, 1, length)
+
+
+@pytest.mark.parametrize("length", [W.P + 1, 3 * W.P, 4 * W.P + 1])
+def test_state_after_the_final_run_is_the_chunk_a_run_engines(model, length):
+    """The slot's recurrent state and conv tail when its prompt's last
+    chunk has gone out, one wide run or several narrow ones (the same
+    products in another grouping: float32 rounding apart)."""
+    def final_state(budget):
+        eng = wide(model)(budget, batch_size=1)
+        eng.submit(Request(W.prompt(length, 3), W.NEW))
+        while eng._pf or not eng._kv.occupied():
+            eng.step()
+        return state_of(eng, 0)
+
+    for (s1, t1), (s2, t2) in zip(final_state(4), final_state(1)):
+        np.testing.assert_allclose(s1, s2, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(t1, t2, rtol=1e-5, atol=1e-6)
+
+
+def test_every_width_is_compiled_by_the_first_prefill_step(model):
+    W.check_warm_set(wide(model), fd._mon)
 
 
 def test_sync_every_steps_share_the_program(config, model):

@@ -13,11 +13,14 @@ form's different association and the chunked read, and is far under what a
 wrong route produces (a swapped expert reads 0.1-1).  In float32 the
 program's routes ARE the reference's own.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _wide_runs as wide_runs
 import paddle_tpu as paddle
 from benchmark.lib import glm4_moe_lite_ref as ref
 from benchmark.lib import glm4_moe_lite_weights as W
@@ -405,8 +408,8 @@ def test_a_readmitted_request_carries_one_row_a_position(model, when):
     eng = engine(model, batch_size=1)
     r = eng.submit(Request(prompt, 8))
     if when == "mid_prefill":
-        eng.step()          # two chunks of three dispatched, no decode yet
-        assert eng._pf and len(eng._chunk_routes) == 2
+        eng.step()          # two chunks of three in ONE run, no decode yet
+        assert eng._pf and [n for _, n, _, _ in eng._chunk_routes] == [32]
     else:
         while len(r.output_ids) < 3:
             eng.step()
@@ -417,6 +420,36 @@ def test_a_readmitted_request_carries_one_row_a_position(model, when):
     assert list(r.output_ids) == list(c.output_ids)
     np.testing.assert_array_equal(recorded(r), recorded(c))
     assert len(recorded(r)) == 40 + 8 - 1
+
+
+# (e') the chunks a step spends on one prompt ride in ONE run
+def wide(model):
+    return wide_runs.family(engine, model)
+
+
+@functools.lru_cache(maxsize=None)
+def _served_wide(model, budget, length):
+    reqs = wide_runs.serve(wide(model), budget, length)[1]
+    return wide_runs.streams(reqs), [recorded(r) for r in reqs]
+
+
+@pytest.mark.parametrize("length", wide_runs.LENGTHS)
+@pytest.mark.parametrize("budget", wide_runs.BUDGETS)
+def test_wide_runs_serve_the_chunk_a_run_engines_streams_and_routes(
+        model, budget, length):
+    """Whatever the budget, tokens, finite flags and the recorded routes —
+    row for row: one record a RUN, cut to its real rows — are those of the
+    engine that runs a chunk a run (``prefill_budget=1``)."""
+    got, routes = _served_wide(model, budget, length)
+    want, want_routes = _served_wide(model, 1, length)
+    assert [s for s, _ in got] == ["done", "done"]
+    assert got == want
+    for mine, theirs in zip(routes, want_routes):
+        np.testing.assert_array_equal(mine, theirs)
+
+
+def test_every_width_is_compiled_by_the_first_prefill_step(model):
+    wide_runs.check_warm_set(wide(model), gd._mon)
 
 
 # (f) counters
@@ -435,7 +468,9 @@ def test_expert_counters(model):
     rows = sum(len(recorded(r)) for r in reqs)
     assert 4 * rows <= total <= 4 * (rows + 3 * len(reqs))
     runs = by("serving_moe_dispatches_total", "program")
-    assert runs["prefill"] == 2 + 1 + 2          # chunks of 16
+    # chunks of 16, two a step, two slots: 21 rows (2 chunks) ride ONE
+    # run, 9 take one, and 30 (admitted once a slot is free) one again
+    assert runs["prefill"] == 1 + 1 + 1
     assert runs["decode"] >= 3
     touched = by("serving_moe_experts_touched_total", "program")
     assert 2 * runs["prefill"] <= touched["prefill"] <= 16 * runs["prefill"]

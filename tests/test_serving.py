@@ -6,9 +6,12 @@ between compiled steps — leaves every other slot's greedy continuation
 BYTE-IDENTICAL to an uninterrupted run, and every request's output
 byte-identical to a standalone ``decode_greedy`` of its own prompt.
 """
+import functools
+
 import numpy as np
 import pytest
 
+import _wide_runs as W
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.llama_decode import decode_greedy
@@ -363,23 +366,28 @@ class TestChunkedPrefill:
         counter = reg.get("serving_prefill_total")
         assert counter.labels(policy="continuous", bucket=bucket).value == 1
 
-    def test_prefill_program_count_is_o1(self):
-        """Eight DISTINCT prompt lengths cost exactly ONE
-        serving_prefill_chunk trace (offset / prompt_len / slot are traced
-        operands; only the chunk width P is a shape)."""
+    def test_prefill_program_count_is_one_a_width(self):
+        """Eight DISTINCT prompt lengths cost ONE serving_prefill_chunk
+        trace a WIDTH of the run ladder (1 and 2 chunks at the default
+        budget; offset / prompt_len / slot are traced operands, only the
+        run's rows are a shape) — all of them in the first step that
+        spends prefill, none later."""
         from paddle_tpu.models.llama_decode import _mon
 
         model = _tiny_model(seed=22)
         rng = np.random.default_rng(22)
         lens = (3, 5, 7, 9, 11, 14, 17, 21)
-        prompts = [rng.integers(0, 256, (p,)) for p in lens]
-        before = _mon.trace_counts().get("serving_prefill_chunk", 0)
-        _run(model, prompts, [3] * len(lens), batch_size=2, max_len=64,
-             prefill_chunk=8)
-        # at most ONE new program for eight distinct lengths (zero when an
-        # earlier test in this process already traced the P=8 program —
-        # the jit cache is process-wide, which is exactly the point)
-        assert _mon.trace_counts()["serving_prefill_chunk"] - before <= 1
+        # a geometry of this test's own: the jit cache is process-wide
+        eng = ServingEngine(model, batch_size=2, max_len=72, prefill_chunk=8)
+        count = lambda: _mon.trace_counts().get("serving_prefill_chunk", 0)
+        before = count()
+        reqs = [eng.submit(Request(rng.integers(0, 256, (p,)), 3))
+                for p in lens]
+        eng.step()
+        assert count() - before == len(eng._widths) == 2
+        eng.run()
+        assert count() - before == 2
+        assert all(r.status == "done" for r in reqs)
 
     def test_staggered_admissions_are_retrace_free(self):
         """Acceptance: steady-state serving with long prompts admitted
@@ -489,6 +497,87 @@ class TestKVCacheGuards:
         with pytest.raises(ValueError, match="already free"):
             kv.release(1)
         assert kv.free_slots() == [0, 1]
+
+
+class TestWideRuns:
+    """The chunks a scheduler step spends on ONE prompt ride in one run of
+    the prefill program (``[1, k * P]`` rows): whatever the budget, what is
+    served is what the chunk-a-run engine (``prefill_budget=1``) serves."""
+
+    MODES = {
+        "dense": {},
+        # (a paged cache's span is whole blocks)
+        "paged": dict(kv_block=16, max_len=128),
+        "paged_wide_block": dict(kv_block=32, max_len=128),
+        "int8": dict(kv_dtype="int8"),
+        "ngram": dict(mode="spec", spec_k=4),
+        "draft": dict(mode="spec"),
+        "draft_paged": dict(mode="spec", kv_block=16, max_len=128),
+        "prefill_only": dict(kv_block=16, max_len=128, prefill_only=True),
+    }
+
+    @staticmethod
+    def make(budget, mode="dense", **kw):
+        from paddle_tpu.serving.engine import SpecConfig
+
+        kw = {**dict(batch_size=2, max_len=W.LMAX, prefill_chunk=W.P,
+                     decode_chunk=16, prefill_budget=budget),
+              **TestWideRuns.MODES[mode], **kw}
+        if mode.startswith("draft"):
+            paddle.seed(1)
+            draft = LlamaForCausalLM(LlamaConfig.tiny(
+                dtype="float32", num_hidden_layers=1))
+            draft.eval()
+            kw["spec"] = SpecConfig(source="draft_model", draft_model=draft,
+                                    spec_k=4)
+        return ServingEngine(_tiny_model(seed=31), **kw)
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def served(mode, budget, length):
+        # (a prefill-only engine's requests carry their first token alone)
+        new = 1 if mode == "prefill_only" else W.NEW
+        _, reqs = W.serve(TestWideRuns.make, budget, length, new=new,
+                          mode=mode)
+        return W.streams(reqs)
+
+    @pytest.mark.parametrize("length", W.LENGTHS)
+    @pytest.mark.parametrize("budget", W.BUDGETS)
+    def test_streams_are_the_chunk_a_run_engines(self, budget, length):
+        got = self.served("dense", budget, length)
+        assert [s for s, _ in got] == ["done", "done"]
+        assert got == self.served("dense", 1, length)
+
+    @pytest.mark.parametrize("length", (W.P + 1, 3 * W.P, None))
+    @pytest.mark.parametrize("mode", sorted(set(MODES) - {"dense"}))
+    def test_streams_in_every_mode(self, mode, length):
+        got = self.served(mode, 4, length)
+        assert [s for s, _ in got] == ["done", "done"]
+        assert got == self.served(mode, 1, length)
+
+    def test_budget_one_is_a_chunk_a_run(self):
+        from paddle_tpu.observability import MetricsRegistry
+
+        reg = MetricsRegistry()
+        eng, _ = W.serve(self.make, 1, 4 * W.P + 1, registry=reg)
+        value = lambda name: reg.get(name).labels(policy="continuous").value
+        assert eng._widths == [1] and eng._widths_warm
+        assert value("serving_prefill_runs_total") == 5 + 3 \
+            == value("serving_prefill_chunks_total")
+
+    def test_the_fused_prefill_kernel_keeps_a_chunk_a_run(self):
+        """Its appends are DMA windows that rely on ``offset % rows == 0``,
+        and a run starts where the last one ended."""
+        eng = self.make(4, prefill_impl="pallas")
+        assert eng._widths == [1]
+
+    @pytest.mark.parametrize("mode", ["dense", "paged", "ngram", "draft",
+                                      "draft_paged"])
+    def test_every_width_is_compiled_by_the_first_prefill_step(self, mode):
+        from paddle_tpu.models.llama_decode import _mon
+
+        W.check_warm_set(self.make, _mon, mode=mode,
+                         programs=2 if mode.startswith("draft") else 1)
 
 
 @pytest.mark.slow

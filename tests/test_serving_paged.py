@@ -250,9 +250,12 @@ class TestPagedEngineSmoke:
         assert {"block_alloc", "block_free", "prefix_hit"} <= kinds
 
     def test_warm_paged_engine_zero_retraces(self):
-        # one engine warms the compiled programs; a second runs a
-        # staggered wave with hits, evictions (small pool), and chain
-        # growth — table values change every step, shapes never
+        # one engine warms the compiled programs — every width of the
+        # prefill run ladder in its FIRST prefill-spending step, whatever
+        # lengths its own wave draws; a second runs a staggered wave with
+        # hits (suffix runs of one chunk), misses (runs of two),
+        # evictions (small pool), and chain growth — table values change
+        # every step, shapes never
         rng = np.random.default_rng(7)
         sys_prompt = rng.integers(1, 200, size=40).tolist()
 
@@ -268,6 +271,8 @@ class TestPagedEngineSmoke:
         eng = ServingEngine(model, **kw)
         for p in wave(6):
             eng.submit(Request(p, 6))
+        eng.step()
+        assert eng._widths == [1, 2] and eng._widths_warm
         eng.run()
         eng2 = ServingEngine(model, **kw)
         with assert_no_retrace():

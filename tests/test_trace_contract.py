@@ -265,7 +265,9 @@ def test_expert_counters_are_the_names_the_readers_ask_for():
     rows = sum(len(x) for x in r.routes)
     assert rows == len(PROMPTS[0]) + 3 - 1
     assert sum(pairs.values()) >= rows * 2 * 2          # 2 layers x top-2
-    assert by("serving_moe_dispatches_total", "program")["prefill"] == 2
+    # 21 rows are two 16-row chunks in ONE run: one record, its real rows
+    assert by("serving_moe_dispatches_total", "program")["prefill"] == 1
+    assert len(r.routes[0]) == len(PROMPTS[0])
     assert by("serving_moe_experts_touched_total", "program")["decode"] > 0
 
 
@@ -300,6 +302,44 @@ def test_kv_rows_counters_read_the_numbers_computed_by_hand():
     lbl = dict(policy="continuous")
     assert reg.get("serving_kv_rows_read_total").labels(**lbl).value == 320
     assert reg.get("serving_kv_rows_live_total").labels(**lbl).value == 120
+
+
+def test_chunks_and_runs_read_the_numbers_computed_by_hand():
+    """``serving_prefill_chunks_total`` counts 16-row chunks, ``_runs_total``
+    runs of the program, on a hand-made arrival: 40 rows (3 chunks) and 50
+    rows (4 chunks) admitted together, two chunks a step.  Step 1: A's
+    chunks 0-1 in one run.  Step 2: A's odd last chunk alone, and B's first
+    takes the chunk the step has left — alone too.  Step 3: B's chunks 1-2
+    in one run.  Step 4: B's last.  7 chunks in 5 runs; a run of k chunks
+    leaves k ``prefilling`` marks with consecutive indices, ``final`` on
+    the chunk that holds the prompt's last row; the backlog gauge counts
+    chunks."""
+    from paddle_tpu.observability.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng = tiny_engine(registry=reg)
+    a, b = (eng.submit(Request(np.arange(1, 1 + n, dtype=np.int32), 3))
+            for n in (40, 50))
+    lbl = dict(policy="continuous")
+    value = lambda name: reg.get(name).labels(**lbl).value
+    eng.step()
+    assert (value("serving_prefill_chunks_total"),
+            value("serving_prefill_runs_total"),
+            value("serving_prefill_backlog")) == (2, 1, 1 + 4)
+    eng.run()
+    assert value("serving_prefill_chunks_total") == 7
+    assert value("serving_prefill_runs_total") == 5
+    runs = [(e["rid"], e["chunk"], e["chunks"], e["final"])
+            for e in eng.recorder.events() if e["kind"] == "prefill_chunk"]
+    assert runs == [(a.rid, 0, 2, False), (a.rid, 2, 1, True),
+                    (b.rid, 0, 1, False), (b.rid, 1, 2, False),
+                    (b.rid, 3, 1, True)]
+    for r, n in ((a, 3), (b, 4)):
+        marks = [m for m in r.timeline() if "chunk" in m]
+        assert [m["chunk"] for m in marks] == list(range(n))
+        assert [m["final"] for m in marks] == [False] * (n - 1) + [True]
+        assert all(m["phase"] == "prefilling" for m in marks)
+        assert marks[-1]["t"] <= r.t_first
 
 
 # (c) the host spans, on the profiler's timeline
@@ -348,10 +388,12 @@ def test_spans_of_one_request_share_its_rid(traced):
         mine = [e["name"] for e in spans if str(e.get("rid")) == str(rid)]
         assert mine[0] == "serving.submit" and mine[1] == "serving.admit"
         assert set(mine[2:]) == {"serving.prefill_chunk"}
-    chunks = [e for e in spans if e["name"] == "serving.prefill_chunk"]
-    # 21, 9 and 30 tokens in chunks of 16: 2 + 1 + 2, the last of each final
-    assert len(chunks) == 5
-    assert sum(int(e["final"]) for e in chunks) == 3
+    runs = [e for e in spans if e["name"] == "serving.prefill_chunk"]
+    # a span a RUN: 21, 9 and 30 tokens in chunks of 16, two a step, are
+    # 2 + 1 + 2 chunks in three runs, each holding its prompt's last row
+    assert [(int(e["chunk"]), int(e["chunks"])) for e in runs] == [
+        (0, 2), (0, 1), (0, 2)]
+    assert sum(int(e["final"]) for e in runs) == 3
 
 
 def test_train_spans_carry_their_step(traced):
