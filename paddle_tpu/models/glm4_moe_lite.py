@@ -4,8 +4,21 @@ is multi-head LATENT attention (MLA) in every layer and whose FFN, after
 ``n_routed_experts`` routed SwiGLU experts (``num_experts_per_tok`` a token)
 plus ``n_shared_experts`` shared ones.
 
+TWO published models run through this file and its serving programs
+(``models/glm4_moe_lite_decode.py``): GLM-4.7-Flash, and Xing4.0-29B-A4B
+(``models/xing4.py``: the same attention and expert FFN at other widths).
+Two facts of ``Glm4MoeLiteStatics`` tell them apart — the residual path
+(``hc``: one stream and ``h + branch``, or ``hc_mult`` streams under a
+hyper-connection, ``ops/hyper_connection.py``) and the softmax scale
+(``scale_mult``: YaRN's ``mscale_all_dim`` factor) — and one of the
+parameters: the rope table (``rope_tables``: plain, or YaRN's blended
+frequencies).
+
 Equations (config keys in backticks; ``RMS`` = RMSNorm, eps ``rms_norm_eps``;
-pre-norm residual blocks ``h += attn(RMS(h)); h += ffn(RMS(h))``):
+pre-norm residual blocks ``h += attn(RMS(h)); h += ffn(RMS(h))`` — with
+``hc_mult`` > 1 each ``+=`` is the hyper-connected sub-layer of
+``ops/hyper_connection.py``'s docstring instead, the stream starts as
+``hc_mult`` copies of the embedding and ends as their sum):
 
 - MLA.  ``c_q = RMS(x W_dq)`` (``q_lora_rank``); per head
   ``[q_nope | q_rope] = c_q W_uq`` (``qk_nope_head_dim`` |
@@ -19,6 +32,14 @@ pre-norm residual blocks ``h += attn(RMS(h)); h += ffn(RMS(h))``):
   the serving programs, ``models/glm4_moe_lite_decode.py``):
   ``q~ = W_uk^T q_nope`` (``kv_lora_rank``), ``s = (q~ . c + q_rope . k_r)
   / sqrt(nope + rope)``, ``o = W_uv (sum_j p_j c_j)``.
+  With ``rope_scaling`` (``type: yarn``; the DeepSeek-V3-shaped modelling
+  code's forms): the rotary frequencies ``inv_j = theta^(-2j/rope)`` are
+  blended with their interpolated values, ``inv'_j = inv_j (1 - r_j) +
+  (inv_j / factor) r_j``, ``r_j = clip((j - lo) / (hi - lo), 0, 1)``, ``lo =
+  floor(dim(beta_fast))``, ``hi = ceil(dim(beta_slow))``, ``dim(b) = rope
+  ln(original_max_position_embeddings / (2 pi b)) / (2 ln theta)``; cos / sin
+  are multiplied by ``m(mscale) / m(mscale_all_dim)`` and the softmax scale
+  by ``m(mscale_all_dim)^2``, ``m(a) = 0.1 a ln(factor) + 1``.
 - Router and experts: ``ops/moe.py`` (sigmoid scores, the selection bias
   ``e_score_correction_bias`` chooses, ``norm_topk_prob``,
   ``routed_scaling_factor``; ``n_group = topk_group = 1``).
@@ -40,19 +61,21 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import NamedTuple
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.autograd.engine import apply
 from paddle_tpu.models.falcon_h1 import _Born, _Fixed, _Weight
-from paddle_tpu.models.llama import _apply_rope
 from paddle_tpu.models.llama_decode import _rmsnorm as rmsnorm
+from paddle_tpu.models.llama_decode import _rope_at, _rope_tables
 from paddle_tpu.nn.layer.container import LayerList
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.ops.moe import expert_ffn, route
 
 __all__ = ["Glm4MoeLiteConfig", "Glm4MoeLiteForCausalLM",
-           "Glm4MoeLiteStatics"]
+           "Glm4MoeLiteStatics", "rope_tables"]
 
 
 @dataclass
@@ -82,6 +105,18 @@ class Glm4MoeLiteConfig:
     max_position_embeddings: int = 202752
     tie_word_embeddings: bool = False
     dtype: str = "bfloat16"
+    # None, or the published YaRN dict (``type``, ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``mscale``, ``mscale_all_dim``)
+    rope_scaling: dict | None = None
+    # residual streams: 1 = the plain ``h + branch``; above 1 every sub-layer
+    # is hyper-connected (ops/hyper_connection.py) and the four keys below
+    # are read
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
     def __post_init__(self):
         for key, want in (("n_group", 1), ("topk_group", 1),
@@ -95,6 +130,15 @@ class Glm4MoeLiteConfig:
             raise ValueError("first_k_dense_replace outside the layers")
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even (rotate-half)")
+        if self.rope_scaling is not None \
+                and self.rope_scaling.get("type") != "yarn":
+            raise ValueError(
+                f"glm4_moe_lite: rope_scaling type "
+                f"{self.rope_scaling.get('type')!r} has no path "
+                "(implemented: None or 'yarn')")
+        if self.hc_mult < 1:
+            raise ValueError(f"hc_mult={self.hc_mult}: a model has at least "
+                             "one residual stream")
 
     # tiny preset used by the tests
     @staticmethod
@@ -121,6 +165,14 @@ class Glm4MoeLiteStatics(NamedTuple):
     eps: float
     top_k: int
     route_scale: float
+    # the residual path: streams (1 = plain), and the hyper-connection's
+    # Sinkhorn steps, eps and (lo, hi) clamp
+    hc: int = 1
+    hc_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: tuple = (0.0, 0.0)
+    # YaRN's factor on the softmax scale (1 without rope_scaling)
+    scale_mult: float = 1.0
 
     @property
     def row(self):
@@ -139,14 +191,49 @@ class Glm4MoeLiteStatics(NamedTuple):
 
     @property
     def scale(self):
-        return float(self.nope + self.rope) ** -0.5
+        return float(self.nope + self.rope) ** -0.5 * self.scale_mult
+
+
+def _yarn_mscale(factor, a):
+    return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 def statics_of(c: Glm4MoeLiteConfig) -> Glm4MoeLiteStatics:
+    rs = c.rope_scaling
+    # a one-stream model's statics do not vary with keys it does not read
+    iters, eps, clamp = (c.hc_sinkhorn_iters, float(c.hc_eps), (
+        float(c.mhc_h_res_clamp_min), float(c.mhc_h_res_clamp_max))
+    ) if c.hc_mult > 1 else (0, 0.0, (0.0, 0.0))
     return Glm4MoeLiteStatics(
         c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
         c.v_head_dim, c.kv_lora_rank, float(c.rms_norm_eps),
-        c.num_experts_per_tok, float(c.routed_scaling_factor))
+        c.num_experts_per_tok, float(c.routed_scaling_factor),
+        hc=c.hc_mult, hc_iters=iters, hc_eps=eps, hc_clamp=clamp,
+        scale_mult=1.0 if rs is None else _yarn_mscale(
+            rs["factor"], rs.get("mscale_all_dim", 0)) ** 2)
+
+
+def rope_tables(c: Glm4MoeLiteConfig, lmax, dtype):
+    """cos / sin ``[lmax, rope]`` by the configuration's rule: the plain
+    ``theta^(-2j/rope)`` frequencies, or YaRN's (module docstring; float32
+    frequencies, the same two leaves)."""
+    d, theta, rs = c.qk_rope_head_dim, float(c.rope_theta), c.rope_scaling
+    if rs is None:
+        return _rope_tables(lmax, d, theta, dtype)
+    dim = lambda beta: d * math.log(rs["original_max_position_embeddings"]
+                                    / (beta * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    lo = max(math.floor(dim(rs["beta_fast"])), 0)
+    hi = min(math.ceil(dim(rs["beta_slow"])), d - 1)
+    j = jnp.arange(d // 2, dtype=jnp.float32)
+    ramp = jnp.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    inv = 1.0 / theta ** (2.0 * j / d)
+    inv = inv * (1.0 - ramp) + inv / rs["factor"] * ramp
+    freqs = jnp.outer(jnp.arange(lmax, dtype=jnp.float32), inv)
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    m = _yarn_mscale(rs["factor"], rs.get("mscale", 1)) \
+        / _yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0))
+    return (jnp.cos(emb) * m).astype(dtype), (jnp.sin(emb) * m).astype(dtype)
 
 
 # ---------------------------------------------------------------- block math
@@ -154,7 +241,46 @@ def statics_of(c: Glm4MoeLiteConfig) -> Glm4MoeLiteStatics:
 # [in, out]): ln1, ln2, w_dq, q_norm, w_uq, w_dkv, kv_norm, w_uk [H, nope,
 # rank], w_uv [H, rank, v], wo; a dense layer gate, up, down; an expert
 # layer router [h, E], router_bias [E] float32, e_gate, e_up [E, h, f],
-# e_down [E, f, h], s_gate, s_up, s_down (the shared expert)
+# e_down [E, f, h], s_gate, s_up, s_down (the shared expert); with hc > 1
+# hc1_* / hc2_* (phi, b, alpha: the attention and the FFN sub-layer's
+# hyper-connection, float32)
+def stream_in(cfg, h):
+    """The embedding ``[B, T, hidden]`` as the layers' state: itself, or
+    ``hc`` copies of it side by side ``[B, T, hc * hidden]`` (the layout
+    ``ops/hyper_connection.py`` argues)."""
+    # (a concatenate, not jnp.tile: that is a broadcast to [.., hc, hidden]
+    # and a reshape, which the TPU compiler makes a copy of the whole state)
+    return h if cfg.hc == 1 else jnp.concatenate([h] * cfg.hc, axis=-1)
+
+
+def stream_out(cfg, h):
+    """The state behind the last layer as the head's input: itself, or the
+    sum of its streams."""
+    if cfg.hc == 1:
+        return h
+    with jax.named_scope("norm"):
+        streams = h.reshape(h.shape[:-1] + (cfg.hc, -1))
+        return jnp.sum(streams.astype(jnp.float32), axis=-2).astype(h.dtype)
+
+
+def sublayer(lp, cfg, which, h, branch):
+    """One residual sub-layer (``which``: 1 attention, 2 FFN) around
+    ``branch(u [B, T, hidden]) -> (y, aux)``, the branch with its own
+    pre-norm: ``h + y``, or with ``hc`` streams ``h [B, T, hc * hidden]``
+    the hyper-connected read, branch and write.  Returns ``(h', aux)``."""
+    if cfg.hc == 1:
+        y, aux = branch(h)
+        return h + y, aux
+    # imported here: a model with one stream loads and traces none of it
+    from paddle_tpu.ops import hyper_connection as hc
+
+    pre, post, res = hc.coefficients(
+        h, lp[f"hc{which}_phi"], lp[f"hc{which}_b"], lp[f"hc{which}_alpha"],
+        n=cfg.hc, iters=cfg.hc_iters, eps=cfg.hc_eps, clamp=cfg.hc_clamp)
+    y, aux = branch(hc.read(h, pre))
+    return hc.write(h, res, post, y), aux
+
+
 def mla_project(lp, cfg, u):
     """u [B, T, hidden] (normed) -> (q_nope [B, T, H, nope], q_rope
     [B, T, H, rope], c [B, T, rank] normed, k_r [B, T, 1, rope]), before
@@ -185,14 +311,15 @@ def swiglu(x, gate, up, down):
 
 
 def ffn(lp, cfg, h, live):
-    """``h + FFN(RMS(h))``: the dense SwiGLU, or the routed experts plus
-    the shared one.  h [.., hidden]; ``live`` [..] bool: rows that route
-    (``ops/moe.py``).  Returns ``(h', experts int32 [.., k] or None)``."""
+    """The FFN branch ``FFN(RMS(h))`` (the caller adds it): the dense
+    SwiGLU, or the routed experts plus the shared one.  h [.., hidden];
+    ``live`` [..] bool: rows that route (``ops/moe.py``).  Returns ``(y,
+    experts int32 [.., k] or None)``."""
     with jax.named_scope("norm"):
         x = rmsnorm(h, lp["ln2"], cfg.eps)
     if "router" not in lp:
         with jax.named_scope("mlp"):
-            return h + swiglu(x, lp["gate"], lp["up"], lp["down"]), None
+            return swiglu(x, lp["gate"], lp["up"], lp["down"]), None
     lead = x.shape[:-1]
     flat = x.reshape(-1, x.shape[-1])
     experts, gates = route(flat, lp["router"], lp["router_bias"],
@@ -201,30 +328,37 @@ def ffn(lp, cfg, h, live):
                    lp["e_up"], lp["e_down"])
     with jax.named_scope("moe.shared"):
         y = y + swiglu(flat, lp["s_gate"], lp["s_up"], lp["s_down"])
-    return h + y.reshape(h.shape), experts.reshape(*lead, cfg.top_k)
+    return y.reshape(h.shape), experts.reshape(*lead, cfg.top_k)
 
 
-def block_forward(lp, cfg, h, theta):
-    """One layer over whole sequences h [B, L, hidden] from position 0, no
-    cache: the EXPANDED attention (the plain model forward)."""
-    b, L, _ = h.shape
-    with jax.named_scope("norm"):
-        u = rmsnorm(h, lp["ln1"], cfg.eps)
-    q_nope, q_rope, c, k_r = mla_project(lp, cfg, u)
-    with jax.named_scope("attn.rope"):
-        q_rope, k_r = _apply_rope(q_rope, k_r, theta)
-    with jax.named_scope("mla.absorb"):
-        k_nope = jnp.einsum("blc,hdc->blhd", c, lp["w_uk"])
-        v = jnp.einsum("blc,hcd->blhd", c, lp["w_uv"])
-    with jax.named_scope("attn.core"):
-        s = (jnp.einsum("blhd,bmhd->bhlm", q_nope, k_nope)
-             + jnp.einsum("blhd,bmd->bhlm", q_rope, k_r[:, :, 0])
-             ).astype(jnp.float32) * cfg.scale
-        s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -jnp.inf)
-        o = jnp.einsum("bhlm,bmhd->blhd",
-                       jax.nn.softmax(s, -1).astype(v.dtype), v)
-    h = h + attn_out(lp, cfg, o)
-    return ffn(lp, cfg, h, jnp.ones((b, L), bool))[0]
+def block_forward(lp, cfg, h, cos_t, sin_t):
+    """One layer over whole sequences from position 0, no cache: the
+    EXPANDED attention (the plain model forward).  h [B, L, hidden], or
+    [B, L, hc * hidden]; ``cos_t``, ``sin_t``: ``rope_tables``."""
+    b, L = h.shape[:2]
+
+    def attn(u):
+        with jax.named_scope("norm"):
+            u = rmsnorm(u, lp["ln1"], cfg.eps)
+        q_nope, q_rope, c, k_r = mla_project(lp, cfg, u)
+        with jax.named_scope("attn.rope"):
+            q_rope, k_r = _rope_at(q_rope, k_r, cos_t, sin_t,
+                                   jnp.arange(L, dtype=jnp.int32)[None])
+        with jax.named_scope("mla.absorb"):
+            k_nope = jnp.einsum("blc,hdc->blhd", c, lp["w_uk"])
+            v = jnp.einsum("blc,hcd->blhd", c, lp["w_uv"])
+        with jax.named_scope("attn.core"):
+            s = (jnp.einsum("blhd,bmhd->bhlm", q_nope, k_nope)
+                 + jnp.einsum("blhd,bmd->bhlm", q_rope, k_r[:, :, 0])
+                 ).astype(jnp.float32) * cfg.scale
+            s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -jnp.inf)
+            o = jnp.einsum("bhlm,bmhd->blhd",
+                           jax.nn.softmax(s, -1).astype(v.dtype), v)
+        return attn_out(lp, cfg, o), None
+
+    h, _ = sublayer(lp, cfg, 1, h, attn)
+    live = jnp.ones((b, L), bool)
+    return sublayer(lp, cfg, 2, h, lambda u: ffn(lp, cfg, u, live))[0]
 
 
 # -------------------------------------------------------------- the Layer
@@ -292,6 +426,28 @@ class Glm4MoeLiteMoE(Layer):
         self.shared_experts = Glm4MoeLiteMLP(c, f * c.n_shared_experts)
 
 
+class Glm4MoeLiteHyperConnection(Layer):
+    """One sub-layer's mixing parameters (``ops/hyper_connection.py``), born
+    in float32 whatever the model's dtype: ``phi [hc C, hc^2 + 2 hc]``
+    N(0, 1 / (hc C)), ``alpha`` ones, ``b`` zero but for 2 on the diagonal
+    of its ``res`` part (a fresh model keeps most of each stream where it
+    is)."""
+
+    def __init__(self, c):
+        super().__init__()
+        n, wide = c.hc_mult, c.hc_mult * c.hidden_size
+        cols = n * n + 2 * n
+        self.phi = self.create_parameter(
+            [wide, cols], dtype="float32",
+            default_initializer=_Born(wide ** -0.5))
+        self.b = self.create_parameter(
+            [cols], dtype="float32", default_initializer=_Fixed(
+                lambda shape: jnp.concatenate(
+                    [jnp.zeros(2 * n), 2.0 * jnp.eye(n).reshape(-1)])))
+        self.alpha = self.create_parameter(
+            [3], dtype="float32", default_initializer=_Fixed(jnp.ones))
+
+
 class Glm4MoeLiteDecoderLayer(Layer):
     def __init__(self, c, dense):
         super().__init__()
@@ -301,6 +457,9 @@ class Glm4MoeLiteDecoderLayer(Layer):
                                                 _ones)
         self.mlp = (Glm4MoeLiteMLP(c, c.intermediate_size) if dense
                     else Glm4MoeLiteMoE(c))
+        if c.hc_mult > 1:
+            self.attn_hc = Glm4MoeLiteHyperConnection(c)
+            self.mlp_hc = Glm4MoeLiteHyperConnection(c)
 
     def weights(self):
         """The layer's weights under the names the pure functions read
@@ -328,6 +487,11 @@ class Glm4MoeLiteDecoderLayer(Layer):
                       e_down=m.experts_down.weight,
                       s_gate=s.gate_proj.weight, s_up=s.up_proj.weight,
                       s_down=s.down_proj.weight)
+        for which, name in ((1, "attn_hc"), (2, "mlp_hc")):
+            hc = getattr(self, name, None)
+            if hc is not None:
+                lp.update({f"hc{which}_phi": hc.phi, f"hc{which}_b": hc.b,
+                           f"hc{which}_alpha": hc.alpha})
         return lp
 
 
@@ -362,17 +526,22 @@ class Glm4MoeLiteForCausalLM(Layer):
         return GLM4_MOE_LITE_FAMILY
 
     def forward(self, input_ids):
-        cfg, theta = statics_of(self.config), float(self.config.rope_theta)
+        cfg = statics_of(self.config)
+        cos_t, sin_t = rope_tables(self.config, input_ids.shape[1],
+                                   self.model.embed_tokens.weight.data.dtype)
         with jax.named_scope("embed"):
-            h = apply("glm4_moe_lite_embed", lambda e, ids: e[ids],
+            h = apply("glm4_moe_lite_embed",
+                      lambda e, ids: stream_in(cfg, e[ids]),
                       self.model.embed_tokens.weight, input_ids)
         for layer in self.model.layers:
             names, params = zip(*layer.weights().items())
             h = apply("glm4_moe_lite_block",
                       lambda x, *ws: block_forward(
-                          dict(zip(names, ws)), cfg, x, theta), h, *params)
+                          dict(zip(names, ws)), cfg, x, cos_t, sin_t),
+                      h, *params)
 
         def head(x, norm, w):
+            x = stream_out(cfg, x)
             with jax.named_scope("norm"):
                 x = rmsnorm(x, norm, cfg.eps)
             with jax.named_scope("lm_head"):

@@ -1,6 +1,13 @@
 """GLM-4.7-Flash's serving programs and its serving family: the model seam's
 third implementation, beside ``models/llama_decode.py`` and
-``models/falcon_h1_decode.py``.
+``models/falcon_h1_decode.py``.  Xing4.0-29B-A4B (``models/xing4.py``) is
+served by the SAME two programs: its residual path (``cfg.hc`` streams
+under a hyper-connection: the state is ``[B, T, hc * hidden]`` from the
+embedding to the row sum before the head, and never leaves a program run —
+no cache leaf and no seam field knows of it), its softmax scale
+(``cfg.scale_mult``) and its rope table (``rope_tables``) are facts of the
+family's statics and parameters; with ``cfg.hc == 1`` the programs are the
+plain-residual ones.
 
 Same contracts as the Llama programs (fixed batch ``B``, static shapes,
 per-slot liveness carried by the ``lengths`` operand, donated caches, the
@@ -31,11 +38,10 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models.glm4_moe_lite import (
-    attn_out, ffn, mla_project, rmsnorm, statics_of,
+    attn_out, ffn, mla_project, rmsnorm, rope_tables, statics_of, stream_in,
+    stream_out, sublayer,
 )
-from paddle_tpu.models.llama_decode import (
-    _greedy_pick, _rope_at, _rope_tables,
-)
+from paddle_tpu.models.llama_decode import _greedy_pick, _rope_at
 from paddle_tpu.models.serving_family import RowsLeaves, ServingFamily
 from paddle_tpu.observability.compilecache import CompileCacheMonitor
 from paddle_tpu.ops.decode_attention import (
@@ -70,9 +76,8 @@ def _decode_params_of(model, lmax):
     else:
         t0 = time.perf_counter()
         params = extract_decode_params(model)
-        params["_rope"] = _rope_tables(lmax, cfg.rope,
-                                       float(model.config.rope_theta),
-                                       params["embed"].dtype)
+        params["_rope"] = rope_tables(model.config, lmax,
+                                      params["embed"].dtype)
         model._decode_cache = (live_w, lmax, params)
         _mon.miss("decode_params", seconds=time.perf_counter() - t0)
     return params, cfg
@@ -103,11 +108,23 @@ def _attend(lp, cfg, u, positions, cos_t, sin_t, attend):
     return attn_out(lp, cfg, o), rows
 
 
+def _layer(lp, cfg, h, positions, cos_t, sin_t, attend, live):
+    """The two residual sub-layers both programs share: h [B, T, hidden]
+    (or [B, T, hc * hidden]) -> (h', rows', experts [B, T, k] or None).
+    ``attend``: the program's cache append and read (``_attend``);
+    ``live`` [B, T]: rows that route."""
+    def attn(u):
+        with jax.named_scope("norm"):
+            u = rmsnorm(u, lp["ln1"], cfg.eps)
+        return _attend(lp, cfg, u, positions, cos_t, sin_t, attend)
+
+    h, rows = sublayer(lp, cfg, 1, h, attn)
+    h, experts = sublayer(lp, cfg, 2, h, lambda u: ffn(lp, cfg, u, live))
+    return h, rows, experts
+
+
 def _layer_decode(lp, cfg, h, cache, lengths, cos_t, sin_t, chunk_size):
     """One layer over ONE new token of every slot: h [B, 1, hidden]."""
-    with jax.named_scope("norm"):
-        u = rmsnorm(h, lp["ln1"], cfg.eps)
-
     def attend(q, new):
         # on the chip the read's gather of a block's chunk becomes a loop of
         # window copies whose operations keep this CALL's path and lose the
@@ -118,9 +135,9 @@ def _layer_decode(lp, cfg, h, cache, lengths, cos_t, sin_t, chunk_size):
                 chunk_size=chunk_size, v_width=cfg.kv_rank)
         return out, rows
 
-    a, rows = _attend(lp, cfg, u, lengths[:, None], cos_t, sin_t, attend)
     live = lengths < cache[0].shape[1]
-    h, experts = ffn(lp, cfg, h + a, live[:, None])
+    h, rows, experts = _layer(lp, cfg, h, lengths[:, None], cos_t, sin_t,
+                              attend, live[:, None])
     return h, (rows,), None if experts is None else live_routes(
         experts[:, 0], live)
 
@@ -130,8 +147,6 @@ def _layer_prefill(lp, cfg, h, cache, slot, offset, n_valid, cos_t, sin_t,
     """One layer over a [1, P] prompt chunk of ``slot``; ``n_valid`` of its
     positions are real."""
     t = h.shape[1]
-    with jax.named_scope("norm"):
-        u = rmsnorm(h, lp["ln1"], cfg.eps)
     positions = offset[None, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
 
     def attend(q, new):
@@ -140,14 +155,17 @@ def _layer_prefill(lp, cfg, h, cache, slot, offset, n_valid, cos_t, sin_t,
             chunk_size=chunk_size, v_width=cfg.kv_rank)
         return out, rows
 
-    a, rows = _attend(lp, cfg, u, positions, cos_t, sin_t, attend)
     live = jnp.arange(t, dtype=jnp.int32) < n_valid
-    h, experts = ffn(lp, cfg, h + a, live[None, :])
+    h, rows, experts = _layer(lp, cfg, h, positions, cos_t, sin_t, attend,
+                              live[None, :])
     return h, (rows,), None if experts is None else live_routes(
         experts[0], live)
 
 
 def _logits(params, cfg, h):
+    """Logits of the picked rows' state h [B, hidden] (or [B, hc *
+    hidden])."""
+    h = stream_out(cfg, h)
     with jax.named_scope("norm"):
         h = rmsnorm(h, params["norm"], cfg.eps)
     with jax.named_scope("lm_head"):
@@ -183,7 +201,7 @@ def _serving_prefill_chunk_impl(params, cfg, tokens, offset, prompt_len,
     slot = slot.astype(jnp.int32)
     n_valid = jnp.clip(prompt_len[0].astype(jnp.int32) - offset, 0, t)
     with jax.named_scope("embed"):
-        h = params["embed"][tokens]
+        h = stream_in(cfg, params["embed"][tokens])
     cos_t, sin_t = params["_rope"]
     new_caches, routes = [], []
     for lp, cache in zip(params["layers"], caches):
@@ -219,7 +237,7 @@ def _serving_decode_steps_impl(params, cfg, cur, caches, dev_lengths,
     def body(carry, _):
         tok, ok, caches, lengths = carry
         with jax.named_scope("embed"):
-            h = params["embed"][tok[:, None]]
+            h = stream_in(cfg, params["embed"][tok[:, None]])
         new_caches, routes = [], []
         for lp, cache in zip(params["layers"], caches):
             h, cache, r = _layer_decode(lp, cfg, h, cache, lengths, cos_t,

@@ -26,7 +26,7 @@ from __future__ import annotations
 import functools
 
 __all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "EXPERT_SCOPES",
-           "LOOPS", "SPANS", "COUNTERS", "ATTN_RESIDUALS"]
+           "RESIDUAL_SCOPES", "LOOPS", "SPANS", "COUNTERS", "ATTN_RESIDUALS"]
 
 # model components, the same names in the serving programs
 # (models/llama_decode.py, ops/decode_attention.py) and the training model
@@ -55,6 +55,16 @@ STATE_SCOPES = ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.state_update",
 # STATE_SCOPES is (benchmark/lib/glm4_moe_lite_reduce.py reduces with both).
 EXPERT_SCOPES = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine",
                  "moe.shared", "mla.absorb")
+# a hyper-connected residual path (ops/hyper_connection.py: a token's state
+# is n streams; every sub-layer computes its mixing coefficients — the
+# flattened norm, one small float32 product, the clamped Sinkhorn iteration —,
+# reads the branch's input as a weighted sum of the streams and writes the
+# branch's output back into all of them).  Used by both serving programs and
+# the whole-sequence forward of a model whose ``hc_mult`` is over 1
+# (models/glm4_moe_lite.py); a model with one stream opens none of them.  A
+# tuple of its own for the reason STATE_SCOPES is
+# (benchmark/lib/xing4_reduce.py reduces with these too).
+RESIDUAL_SCOPES = ("hc.coeff", "hc.read", "hc.write")
 # the compiled loops, so that a %while in a device trace can be told: the
 # n_steps scan of the decode program and the cache-chunk loop of the
 # chunked attention read

@@ -1,4 +1,8 @@
 """A routed expert FFN for serving: static shapes under uneven routing.
+Two published models run through it (GLM-4.7-Flash's experts of ``[2048,
+1536]``, Xing4.0-29B-A4B's of ``[3584, 1024]``): nothing here knows which
+but the grouped product's tile, which is a function of the product's shape
+(``_tile``).
 
 ``models/moe_llm.py``'s training FFN lets every expert compute every token
 (experts x tokens of work).  Here the cost follows the (token, expert) PAIRS
@@ -53,12 +57,27 @@ def live_routes(experts, live):
     return jnp.where(live[:, None], experts, -1).astype(jnp.int8)
 
 
-# rows, contraction and output columns of one tile of the grouped product
-# (on a v5e, alone, at 64 experts of [2048, 1536]: 120 live pairs over 45
-# experts 0.42 ms a product against 0.35 of weight bytes, 1,024 pairs over
-# 59 experts 0.61 against 0.45; ``jax.lax.ragged_dot`` 0.68 / 1.29, the
-# default 128-cubed tiles 3.1 / 4.6 — PERF.md, PR 33)
+# rows, contraction and output columns of one tile of the grouped product,
+# by the product's ``(K, N)``, each found alone on a v5e (ms a product at
+# 200 live pairs over 60 experts / 1,024 / 2,048 pairs over 64; beside them
+# the touched experts' weight bytes at the HBM peak):
+# - [2048, 1536] and [1536, 2048] (PR 33; again PR 35): 0.55 / 0.64 / 0.68
+#   and 0.55 / 0.65 / 0.71 against 0.46 / 0.49; ``jax.lax.ragged_dot`` 0.68 /
+#   1.29, the default 128-cubed tiles 3.1 / 4.6;
+# - [3584, 1024] (PR 35): the WHOLE contraction in one tile, 0.64 / 0.87 /
+#   0.91 against 0.54 / 0.57 — at GLM's 2,048 the second tile is a quarter
+#   mask, 0.92 / 1.05 / 1.17; (128, 2048, 1024) 0.71 / 0.81 / 0.91 is the
+#   better at 1,024 pairs and the worse in decode; (128, 3584, 1024) does
+#   not fit the kernel's 16 MB;
+# - [1024, 3584] (PR 35): half the output columns a tile, 0.64 / 0.74 / 0.81;
+#   512 columns 0.65 / 0.79 / 0.87; all 3,584 do not fit.
+# PERF.md section 6 (PR 35) has the whole table.
+_TILES = {(3584, 1024): (128, 3584, 512), (1024, 3584): (128, 1024, 1792)}
 _TILE = (128, 2048, 512)
+
+
+def _tile(k, n):
+    return _TILES.get((k, n), _TILE)
 
 
 def _grouped(x, w, sizes, interpret=None):
@@ -72,7 +91,7 @@ def _grouped(x, w, sizes, interpret=None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     m, k = x.shape
-    tm, tk, tn = _TILE
+    tm, tk, tn = _tile(k, w.shape[2])
     # the package runs with x64 on, under which the library's tile count
     # (a ``sum`` of int32) is an int64 scalar operand the TPU compiler
     # refuses: trace the call with 32-bit defaults

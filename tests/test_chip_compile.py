@@ -314,16 +314,18 @@ def test_state_space_serving_programs_compile_in_place(one_chip, program):
 # GLM-4.7-Flash's widths (the benchmark's glm47flash_code_steady cell): the
 # dense layer and one expert layer at the cell's geometry
 def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608,
-                 rows=T_PREFILL):
-    """The same two programs of the GLM-4.7-Flash cell; returns ``(lowered,
-    latent leaf's shape, experts' shape)``.  The grouped product's
-    ``interpret`` rule is steered to its TPU branch for the trace."""
+                 rows=T_PREFILL, config=None):
+    """The same two programs of the GLM-4.7-Flash cell (or, with
+    ``config``, of another model of its family: a dense and an expert
+    layer); returns ``(lowered, latent leaf's shape, experts' shape)``.
+    The grouped product's ``interpret`` rule is steered to its TPU branch
+    for the trace."""
     from paddle_tpu.models import glm4_moe_lite_decode as gd
     from paddle_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                                  statics_of)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    c = Glm4MoeLiteConfig(num_hidden_layers=2)
+    c = config or Glm4MoeLiteConfig(num_hidden_layers=2)
     cfg = statics_of(c)
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     bf16 = functools.partial(sds, dtype=jnp.bfloat16)
@@ -338,6 +340,13 @@ def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608,
             "w_uk": bf16((heads, cfg.nope, cfg.kv_rank)),
             "w_uv": bf16((heads, cfg.kv_rank, cfg.v_dim)),
             "wo": bf16((heads * cfg.v_dim, h))}
+    if cfg.hc > 1:
+        cols = cfg.hc * cfg.hc + 2 * cfg.hc
+        for which in (1, 2):
+            attn.update({
+                f"hc{which}_phi": sds((cfg.hc * h, cols), jnp.float32),
+                f"hc{which}_b": sds((cols,), jnp.float32),
+                f"hc{which}_alpha": sds((3,), jnp.float32)})
     dense = dict(attn, gate=bf16((h, c.intermediate_size)),
                  up=bf16((h, c.intermediate_size)),
                  down=bf16((c.intermediate_size, h)))
@@ -456,6 +465,71 @@ def test_two_chunk_prefill_run_reads_weights_as_stored(one_chip, config,
                 r'op_name="[^"]*moe\.experts[^"]*pallas_call', uses[0])
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < _WIDE_TEMP_MB[config][2] * 2 ** 20, temp
+
+
+# Xing4.0-29B-A4B's widths (the benchmark's xing4_reason_steady cell): GLM's
+# two programs with four residual streams under a hyper-connection, a dense
+# and an expert layer at the cell's geometry (64 slots x 2,304 rows).
+# Instructions that run on their own under ``hc.coeff``, a sub-layer:
+# (decode, the two-chunk prefill run).  The Sinkhorn iteration written on
+# [rows, 4, 4] arrays with sum(axis) compiles to (88, 91).
+_HC_COEFF_OWN = (25, 28)
+_XING_TEMP_MB = {"decode_steps": (13.7, 32), "prefill_chunk": (39.4, 64)}
+
+
+@pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
+def test_hyper_connected_programs_compile_in_place(one_chip, program,
+                                                   monkeypatch):
+    """The decode program and the ``[1, 2 * 256]`` prefill run of the Xing4
+    cell compile for the chip at published widths (hidden 3584, 32 heads,
+    64 experts of [3584, 1024], four streams of 20 Sinkhorn steps): nothing
+    of a weight's size and nothing that holds the whole state (a dim of
+    4 x 3584) is copied, transposed or converted on its own, the
+    coefficients stay a stated small number of kernels a sub-layer, each
+    stacked expert tensor is an operand of ONE grouped product, and the
+    temporaries stay under the ceiling."""
+    from paddle_tpu.models.xing4 import Xing4Config
+
+    c = Xing4Config(num_hidden_layers=2, first_k_dense_replace=1)
+    rows = 2 * T_PREFILL
+    lowered, leaf, experts = _glm_program(
+        one_chip, program, monkeypatch, lmax=2304, rows=rows, config=c)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    own = _own_instructions(text)
+    relayout = re.compile(
+        r"= \w+\[([\d,]+)\]\S* (?:copy|transpose|convert)\(")
+    state = c.hc_mult * c.hidden_size
+    for ln in own:
+        m = relayout.search(ln)
+        if m is None:
+            continue
+        dims = [int(x) for x in m.group(1).split(",")]
+        size = functools.reduce(lambda a, b: a * b, dims)
+        assert state not in dims, ln[:160]
+        assert size < 2 ** 22 or rows in dims, ln[:160]
+    coeff = [ln for ln in own if re.search(
+        r'op_name="[^"]*hc\.coeff', ln) and re.search(
+        r" (?:fusion|reduce|convolution|custom-call|copy)\(", ln)]
+    # two layers, two sub-layers each; a multi-output fusion of the
+    # iteration carries no op_name and is not counted in either form
+    ceiling = _HC_COEFF_OWN[program == "prefill_chunk"]
+    assert 0 < len(coeff) <= 4 * ceiling, len(coeff)
+    for name in ("hc.read", "hc.write"):
+        assert re.search(r'op_name="[^"]*%s' % re.escape(name), text)
+    # (the decode program hands its operands through the step loop's
+    # tuple, so there a leaf's name shows on that tuple too)
+    for leaf_name in ("e_gate", "e_up", "e_down"):
+        uses = [ln for ln in text.split("\n") if " tuple(" not in ln
+                and re.search(r"[(,] ?%%params\S*%s\S*[,)]" % leaf_name, ln)]
+        if program == "prefill_chunk":
+            assert len(uses) == 1, (leaf_name, [u[:120] for u in uses])
+            assert "tpu_custom_call" in uses[0] and re.search(
+                r'op_name="[^"]*moe\.experts[^"]*pallas_call', uses[0])
+    assert len(re.findall(r'op_name="[^"]*moe\.experts[^"]*pallas_call',
+                          text)) >= 3
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < _XING_TEMP_MB[program][1] * 2 ** 20, temp
 
 
 # The decode program's cache read (PERF.md, PR 30).  Counts of the whole

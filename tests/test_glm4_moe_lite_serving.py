@@ -106,9 +106,9 @@ def test_absorbed_attention_is_the_expanded_one(config, model):
     lp = params["layers"][0]
     rng = np.random.default_rng(3)
     h = jnp.asarray(rng.standard_normal((1, 24, 64)), jnp.float32)
-    want = block_forward(lp, cfg, h, float(model.config.rope_theta))
-    cache = fam.init_layer_cache(cfg, 1, LMAX, "float32")
     cos_t, sin_t = params["_rope"]
+    want = block_forward(lp, cfg, h, cos_t, sin_t)
+    cache = fam.init_layer_cache(cfg, 1, LMAX, "float32")
     got, _, routes = gd._layer_prefill(
         lp, cfg, h, cache, jnp.int32(0), jnp.int32(0), jnp.int32(24), cos_t,
         sin_t, None)

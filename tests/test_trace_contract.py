@@ -24,8 +24,10 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.llama_decode import (
     serving_decode_steps, serving_prefill_chunk,
 )
+from paddle_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM
 from paddle_tpu.observability.trace import (
-    COUNTERS, EXPERT_SCOPES, LOOPS, SCOPES, SPANS, STATE_SCOPES,
+    COUNTERS, EXPERT_SCOPES, LOOPS, RESIDUAL_SCOPES, SCOPES, SPANS,
+    STATE_SCOPES,
 )
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.static.functionalize import build_train_step
@@ -41,7 +43,9 @@ MODULE_NAMES = {"decode": "serving_decode_steps",
                 "ssm_decode": "serving_decode_steps",
                 "ssm_prefill": "serving_prefill_chunk",
                 "moe_decode": "serving_decode_steps",
-                "moe_prefill": "serving_prefill_chunk"}
+                "moe_prefill": "serving_prefill_chunk",
+                "hc_decode": "serving_decode_steps",
+                "hc_prefill": "serving_prefill_chunk"}
 SERVING = ("embed", "norm", "attn.qkv", "attn.rope", "attn.kv_write",
            "attn.core", "attn.out", "mlp", "lm_head", "sample",
            "attn.core.chunks")
@@ -58,6 +62,11 @@ APPLIES = {
     # the dense first layer keeps ``mlp``
     "moe_decode": SERVING + EXPERT_SCOPES + ("decode.steps",),
     "moe_prefill": SERVING + EXPERT_SCOPES,
+    # the same two programs over a hyper-connected model (models/xing4.py):
+    # GLM's names and the residual path's three, which GLM's carry none of
+    "hc_decode": SERVING + EXPERT_SCOPES + RESIDUAL_SCOPES
+    + ("decode.steps",),
+    "hc_prefill": SERVING + EXPERT_SCOPES + RESIDUAL_SCOPES,
     "train": ("embed", "norm", "attn.qkv", "attn.rope", "attn.core",
               "attn.out", "mlp", "lm_head", "loss", "optimizer"),
 }
@@ -95,6 +104,14 @@ def tiny_moe_engine(**kw):
                          decode_chunk=16, **kw)
 
 
+def tiny_hc_engine(**kw):
+    paddle.seed(0)
+    model = Xing4ForCausalLM(Xing4Config.tiny())
+    model.eval()
+    return ServingEngine(model, batch_size=2, max_len=64, prefill_chunk=16,
+                         decode_chunk=16, **kw)
+
+
 def tiny_train_step(seed=0):
     paddle.seed(seed)
     model = LlamaForCausalLM(LlamaConfig.tiny(
@@ -114,7 +131,20 @@ def lowered():
     step, ids = tiny_train_step()
     ssm = tiny_ssm_engine()
     glm = tiny_moe_engine()
+    hc = tiny_hc_engine()
     return {
+        "hc_decode":
+            glm4_moe_lite_decode.serving_decode_steps.__wrapped__.lower(
+                hc._params, hc._cfg, rows, hc._kv.caches, rows,
+                n_steps=hc._sync, chunk_size=hc._chunk, block_tables=None,
+                program_key=hc._pk),
+        "hc_prefill":
+            glm4_moe_lite_decode.serving_prefill_chunk.__wrapped__.lower(
+                hc._params, hc._cfg, jnp.zeros((1, 16), jnp.int32), scalar,
+                jnp.zeros((1,), jnp.int32), hc._kv.caches, scalar,
+                hist=None, hist_len=None, with_hist=False,
+                chunk_size=hc._chunk, block_tables=None,
+                program_key=hc._pk),
         "moe_decode":
             glm4_moe_lite_decode.serving_decode_steps.__wrapped__.lower(
                 glm._params, glm._cfg, rows, glm._kv.caches, rows,
@@ -184,7 +214,8 @@ def test_scope_names_the_compiled_operations(op_names, program, name):
 @pytest.mark.parametrize("program", sorted(APPLIES))
 def test_program_carries_no_name_outside_its_list(op_names, program):
     found = {c for path in op_names[program] for c in components(path)
-             if c in SCOPES + STATE_SCOPES + EXPERT_SCOPES + LOOPS}
+             if c in SCOPES + STATE_SCOPES + EXPERT_SCOPES + RESIDUAL_SCOPES
+             + LOOPS}
     assert found == set(APPLIES[program])
 
 
@@ -192,11 +223,11 @@ def test_loops_are_named(op_names):
     """A %while of a device trace can be told: the cache-chunk loop's own
     ``while`` sits under its name, and the step's operations under the
     scan's (at ``sync_every=1`` XLA takes the one-trip loop itself away)."""
-    for program in ("decode", "ssm_decode", "moe_decode"):
+    for program in ("decode", "ssm_decode", "moe_decode", "hc_decode"):
         assert any("decode.steps/while/body/" in path
                    for path in op_names[program])
     for program in ("decode", "prefill", "ssm_decode", "ssm_prefill",
-                    "moe_decode", "moe_prefill"):
+                    "moe_decode", "moe_prefill", "hc_decode", "hc_prefill"):
         assert any(path.endswith("attn.core.chunks/while")
                    for path in op_names[program])
 
@@ -212,6 +243,31 @@ def test_latent_read_is_named_at_its_call(op_names):
         for p in inside)
 
 
+def test_whole_sequence_forward_of_a_hyper_connected_model_is_named():
+    """``Xing4ForCausalLM.forward`` (the plain model forward, expanded
+    attention) opens the residual path's three scopes too; GLM's forward
+    opens none of them."""
+    def paths(model):
+        from paddle_tpu.models.glm4_moe_lite import (block_forward,
+                                                     rope_tables, statics_of,
+                                                     stream_in)
+
+        cfg = statics_of(model.config)
+        lp = {k: v.data for k, v in model.model.layers[-1].weights().items()}
+        cos_t, sin_t = rope_tables(model.config, 8, "float32")
+        h = stream_in(cfg, jnp.zeros((1, 8, model.config.hidden_size),
+                                     jnp.float32))
+        text = jax.jit(lambda h: block_forward(lp, cfg, h, cos_t, sin_t)
+                       ).lower(h).compile().as_text()
+        return {c for path in re.findall(r'op_name="([^"]+)"', text)
+                for c in components(path)}
+
+    paddle.seed(0)
+    assert set(RESIDUAL_SCOPES) <= paths(Xing4ForCausalLM(Xing4Config.tiny()))
+    assert not set(RESIDUAL_SCOPES) & paths(
+        Glm4MoeLiteForCausalLM(Glm4MoeLiteConfig.tiny()))
+
+
 def test_backward_and_recompute_keep_the_forwards_names(op_names):
     paths = op_names["train"]
     for name in ("attn.core", "mlp", "loss"):
@@ -222,7 +278,7 @@ def test_backward_and_recompute_keep_the_forwards_names(op_names):
 
 
 @pytest.mark.parametrize("name", SCOPES + STATE_SCOPES + EXPERT_SCOPES
-                         + LOOPS + SPANS)
+                         + RESIDUAL_SCOPES + LOOPS + SPANS)
 def test_vocabulary_avoids_the_readers_patterns(name):
     assert not any(pat in name or name in pat for pat in READER_PATTERNS)
     assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)*", name)
@@ -230,7 +286,8 @@ def test_vocabulary_avoids_the_readers_patterns(name):
 
 def test_applies_covers_the_vocabulary():
     assert set().union(*map(set, APPLIES.values())) \
-        == set(SCOPES + STATE_SCOPES + EXPERT_SCOPES + LOOPS)
+        == set(SCOPES + STATE_SCOPES + EXPERT_SCOPES + RESIDUAL_SCOPES
+               + LOOPS)
 
 
 def test_state_counters_are_the_names_the_readers_ask_for():
