@@ -126,9 +126,10 @@ def _layer(lp, cfg, h, positions, cos_t, sin_t, attend, live):
 def _layer_decode(lp, cfg, h, cache, lengths, cos_t, sin_t, chunk_size):
     """One layer over ONE new token of every slot: h [B, 1, hidden]."""
     def attend(q, new):
-        # on the chip the read's gather of a block's chunk becomes a loop of
-        # window copies whose operations keep this CALL's path and lose the
-        # scopes inside it: the scope around the call is what names them
+        # decode_attention is a jit of its own: what the compiler makes at
+        # its boundary (and, for a span the read's row groups do not divide,
+        # the window copies its gather becomes) keeps this CALL's path and
+        # loses the scopes inside it: the scope around the call names them
         with jax.named_scope("attn.core"):
             out, rows, _, _ = decode_attention(
                 q, new, None, cache[0], None, lengths, scale=cfg.scale,

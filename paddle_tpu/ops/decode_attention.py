@@ -73,7 +73,15 @@ TPU-first design choices:
   are ``None``, the row is appended once and gathered once a chunk trip,
   scores run over the full row and the values are a slice of the gathered
   tile — same slot order, chunk and block rules, ``kv_rows_read`` unchanged
-  (dense float ``blhd`` only).
+  (dense float ``blhd`` only).  The per-slot read's gather takes the leaf
+  as ``[B * Lmax / S, S, R]``, ``S`` the rows one tile of its dtype packs
+  (16 bfloat16, 8 float32: ``_tile_rows``): that is the leaf's physical
+  order, so the view is free and a block's chunk is ONE gather of
+  ``C / S``-group windows, as the (k, v) leaves' ``[B * Lmax, Hkv, D]``
+  windows are.  Windows of the flat ``[B * Lmax, 1, R]`` view the TPU
+  compiler expands into a nested loop of window copies, a slot each
+  (~560 a decode run of 7 layers, latency-bound: PERF.md, PR 33 and
+  PR 36); only a span or chunk that ``S`` does not divide still reads so.
 * **GQA-native.**  kv heads are consumed directly (``[B, Hkv, G, ...]``
   einsums) — no ``repeat`` materialization, KV reads are 1/G of expanded
   heads.
@@ -100,6 +108,7 @@ TPU-first design choices:
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +117,7 @@ import numpy as np
 __all__ = ["init_kv_cache", "init_kv_pool", "decode_attention",
            "masked_lengths", "slot_prefill_attention", "kv_rows_read"]
 
+_LOG = logging.getLogger(__name__)
 _NEG_INF = -1e30
 
 # the supported cache storage dtypes — anything else is a loud ValueError,
@@ -358,6 +368,30 @@ def _slot_block(batch):
     return rows if batch > rows and batch % rows == 0 else None
 
 
+_flat_views = set()
+
+
+def _tile_rows(dtype, lmax, c):
+    """Rows of ONE latent rows leaf that a trip's gather takes as a unit:
+    what a tile of the leaf's dtype packs (16 for bfloat16, 8 for float32),
+    so that the ``[B * Lmax / S, S, R]`` view is the leaf's physical order
+    (a bitcast) and a window of ``C / S`` groups is one slot's chunk.  A
+    span or a chunk the group does not divide keeps the flat
+    ``[B * Lmax, 1, R]`` view (1), and says so once a process: that read
+    is a loop of window copies on the chip."""
+    s = 32 // jnp.dtype(dtype).itemsize
+    if lmax % s == 0 and c % s == 0:
+        return s
+    if (lmax, c, s) not in _flat_views:
+        _flat_views.add((lmax, c, s))
+        _LOG.warning(
+            "decode_attention: the latent rows leaf keeps its flat view "
+            "(span %d, chunk %d: not whole groups of %d rows) — on a TPU "
+            "a trip's read is a slot-by-slot loop of window copies "
+            "(logged once per process)", lmax, c, s)
+    return 1
+
+
 def _chunks_needed(lengths, t, c, lmax, xp=jnp):
     """Cache chunks each slot's read needs: ``ceil((length + T) / C)`` for
     a live slot, none for one parked by ``masked_lengths`` (offset >=
@@ -493,6 +527,9 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
     plain = layout == "blhd" and attn_bias is None and not quant \
         and block_table is None
     rows = _slot_block(b) if plain else None
+    group = 1
+    if rows is not None and v_cache is None:
+        group = _tile_rows(k_data.dtype, lmax, c)
     z = jnp.int32(0)
 
     def read_chunk(cache, i, start, slots):
@@ -519,14 +556,23 @@ def _attend_chunked(qg, k_cache, v_cache, lengths, q_pos, scale, layout,
             # view, which is the leaf's own order: indexing slot and
             # position apart (or slicing slot by slot) makes the TPU
             # compiler copy the whole cache into another order before the
-            # loop (PERF.md, PR 29 and PR 30)
+            # loop (PERF.md, PR 29 and PR 30).  ONE latent rows leaf is
+            # viewed in groups of the rows a tile packs (``_tile_rows``):
+            # windows of its unit-dim view are no gather to the TPU
+            # compiler but a nested loop of window copies, a slot each
+            # (PERF.md, PR 36)
+            view = (b * lmax // group, group * hkv, d)
+            flat = cache.reshape(view)
+            at = slots * lmax + start
+            if group > 1:
+                at = jax.lax.div(at, jnp.int32(group))
             blk = jax.lax.gather(
-                cache.reshape(b * lmax, hkv, d),
-                (slots * lmax + start)[:, None],
+                flat, at[:, None],
                 jax.lax.GatherDimensionNumbers(
                     offset_dims=(1, 2, 3), collapsed_slice_dims=(),
                     start_index_map=(0,)),
-                (c, hkv, d), mode="promise_in_bounds")
+                (c // group,) + view[1:],
+                mode="promise_in_bounds").reshape(rows, c, hkv, d)
         elif isinstance(cache, tuple):
             blk = _q8_dequant(
                 jax.lax.dynamic_slice(cache[0], (z, start, z, z),

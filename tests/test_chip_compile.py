@@ -369,6 +369,52 @@ def _glm_program(one_chip, program, monkeypatch, batch=64, lmax=4608,
         i32(()), chunk_size=256), leaf, experts
 
 
+def _chunk_loops(text):
+    """``[(the while's line, its body's lines)]`` of the cache-chunk loops
+    of a compiled text."""
+    comps = {name: lines for name, _, lines in _computations(text)}
+    return [(ln, comps[re.search(r"body=(%[\w.\-]+)", ln).group(1)])
+            for ln in text.split("\n") if " while(" in ln
+            and re.search(r'op_name="[^"]*attn\.core\.chunks/while"', ln)]
+
+
+def _one_gather_a_layer(text, leaf, layers=2):
+    """A trip of the decode program's per-slot read of ONE latent rows leaf
+    ``[B, Lmax, R]`` (bfloat16: a tile packs 16 rows) is exactly ONE
+    ``gather`` a layer, of 8 slots' windows of 256 / 16 groups of the
+    ``[B * Lmax / 16, 16, R]`` view; that view is a ``bitcast`` of the leaf
+    (its physical order) and nothing else of its shape is made; no
+    ``dynamic-update-slice`` assembles an ``[8, 256, 1, R]`` block slot by
+    slot and the chunk loop's body holds no loop of its own (what the TPU
+    compiler makes of windows of the flat ``[B * Lmax, 1, R]`` view: ~560
+    window copies a GLM decode run, PERF.md PR 33 and PR 36)."""
+    b, lmax, r = leaf
+    view = r"bf16\[%d,16,%d\]" % (b * lmax // 16, r)
+    gathers = [ln for ln in text.split("\n") if re.search(
+        r"= bf16\[8,16,16,%d\]\S* gather\(" % r, ln)]
+    assert len(gathers) == layers, gathers
+    assert all("slice_sizes={16,16,%d}" % r in ln for ln in gathers)
+    made = [op for op in re.findall(
+        r"= %s\S* (\w[\w\-]*)\(" % view, text) if op != "parameter"]
+    assert made == ["bitcast"] * layers, made
+    # each gather reads that view: directly, or as the parameter of the
+    # fusion that holds it
+    for name, fused, lines in _computations(text):
+        for ln in lines:
+            if ln in gathers:
+                operand = re.search(r"gather\((%[\w.\-]+),", ln).group(1)
+                shape = [x for x in lines if re.match(
+                    r"\s*(?:ROOT )?%s = " % re.escape(operand), x)]
+                assert shape and re.search(r"= %s" % view, shape[0]), shape
+    assert "bf16[%d,1,%d]" % (b * lmax, r) not in text
+    assert not re.search(
+        r"dynamic-update-slice\S* = bf16\[8,256,1,%d\]" % r, text)
+    loops = _chunk_loops(text)
+    assert len(loops) == layers
+    for _, body in loops:
+        assert not any(" while(" in ln for ln in body)
+
+
 @pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
 def test_expert_latent_serving_programs_compile_in_place(one_chip, program,
                                                          monkeypatch):
@@ -378,7 +424,9 @@ def test_expert_latent_serving_programs_compile_in_place(one_chip, program,
     layer), no weight — 2-D or a stacked expert tensor — and no latent
     cache leaf is copied into another order, and a chunk trip gathers the
     latent leaf ONCE a layer (the row is key and value both: not one
-    gather for keys and one for values, as a (k, v) family's two)."""
+    gather for keys and one for values, as a (k, v) family's two) — as a
+    ``gather`` the compiler keeps, not the loop of window copies it made
+    of the flat view's (``_one_gather_a_layer``)."""
     lowered, leaf, experts = _glm_program(one_chip, program, monkeypatch)
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -393,18 +441,32 @@ def test_expert_latent_serving_programs_compile_in_place(one_chip, program,
             n *= int(x)
         assert n not in sizes, m.group(0)
     if program == "decode_steps":
-        # a trip's read of the flat [B * Lmax, 1, R] view: a ``gather``, or
-        # what the compiler makes of it (a loop of window copies into ONE
-        # [8 slots, 256 rows, 1, R] buffer in fast memory) — one a layer
-        flat = "bf16[%d,1,%d]" % (leaf[0] * leaf[1], leaf[2])
-        reads = [ln for ln in text.split("\n") if " gather(" in ln
-                 and flat in ln] + re.findall(
-            r"ROOT %%dynamic-update-slice\S* = bf16\[8,256,1,%d\]" % leaf[2],
-            text)
-        assert len(reads) == 2, reads
+        _one_gather_a_layer(text, leaf)
     # donated caches are updated in place and nothing leaf-sized is made:
     # stored [B, Lmax, 1, 576] the leaf was copied whole four times a run
     # (768 MB of temporaries at 2 layers; now 8)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
+def test_latent_read_keeps_the_flat_view_of_an_uneven_span(one_chip,
+                                                           monkeypatch):
+    """The fall-back geometry: a span of 4,600 rows, which the 16 rows of a
+    bfloat16 tile do not divide, compiles too — through the flat
+    ``[B * Lmax, 1, R]`` view, one read a layer (a ``gather``, or the loop
+    of window copies into ONE ``[8 slots, 256 rows, 1, R]`` buffer in fast
+    memory that the compiler makes of it), and no grouped view."""
+    lowered, leaf, _ = _glm_program(one_chip, "decode_steps", monkeypatch,
+                                    lmax=4600)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    flat = "bf16[%d,1,%d]" % (leaf[0] * leaf[1], leaf[2])
+    reads = [ln for ln in text.split("\n") if " gather(" in ln
+             and flat in ln] + re.findall(
+        r"ROOT %%dynamic-update-slice\S* = bf16\[8,256,1,%d\]" % leaf[2],
+        text)
+    assert len(reads) == 2, reads
+    assert not re.search(r"= bf16\[\d+,16,%d\]" % leaf[2], text)
+    assert len(_chunk_loops(text)) == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
@@ -528,6 +590,9 @@ def test_hyper_connected_programs_compile_in_place(one_chip, program,
                 r'op_name="[^"]*moe\.experts[^"]*pallas_call', uses[0])
     assert len(re.findall(r'op_name="[^"]*moe\.experts[^"]*pallas_call',
                           text)) >= 3
+    if program == "decode_steps":
+        # the latent read at this cell's geometry (64 x 2,304, 32 heads)
+        _one_gather_a_layer(text, leaf)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < _XING_TEMP_MB[program][1] * 2 ** 20, temp
 

@@ -14,6 +14,7 @@ wrong route produces (a swapped expert reads 0.1-1).  In float32 the
 program's routes ARE the reference's own.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -211,6 +212,128 @@ def test_latent_read_is_the_kv_read_of_the_same_rows():
                                       np.asarray(k2[:, :, 0]))
     with pytest.raises(ValueError, match="one latent rows leaf"):
         decode_attention(q, new, new, rows, rows, lengths, v_width=vw)
+
+
+def _uneven(rng, batch, live, lmax):
+    """``live`` slots of uneven lengths (one empty, one a row short of the
+    span) scattered over a batch whose other slots are parked."""
+    lengths = np.full((batch,), lmax, np.int32)
+    lens = rng.integers(1, lmax - 1, live)
+    lens[:2] = (0, lmax - 1)
+    lengths[rng.permutation(batch)[:live]] = lens
+    return lengths
+
+
+# dtype, batch, span, chunk, live slots, rows a trip's gather groups
+GROUPED_READS = {
+    "bf16-clamped-tail": ("bfloat16", 8, 80, 32, 6, 16),
+    "f32-clamped-tail": ("float32", 8, 40, 16, 6, 8),
+    "bf16-parked": ("bfloat16", 16, 64, 16, 3, 16),
+    "bf16-64x7": ("bfloat16", 64, 96, 32, 7, 16),
+    "bf16-64x33": ("bfloat16", 64, 96, 32, 33, 16),
+    "f32-64x33": ("float32", 64, 48, 16, 33, 8),
+    # the fall-back geometry: a span the group does not divide
+    "bf16-flat-span": ("bfloat16", 8, 72, 16, 6, 1),
+    "f32-flat-chunk": ("float32", 8, 48, 12, 6, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_READS))
+def test_grouped_latent_read_is_bitwise_the_flat_read(case, monkeypatch,
+                                                      caplog):
+    """The per-slot read of ONE latent rows leaf gathers a block's chunk
+    from the ``[B * Lmax / S, S, R]`` view (``S`` rows a tile of the dtype:
+    16 bfloat16, 8 float32) wherever ``S`` divides span and chunk: the same
+    rows in the same trips as the flat ``[B * Lmax, 1, R]`` view's, so every
+    output — parked slots' too — is BITWISE the flat read's, the full read's
+    to rounding on live slots, and the appended leaf the same.  A geometry
+    the group does not divide keeps the flat view and says so once."""
+    from paddle_tpu.ops import decode_attention as da
+
+    dtype, b, lmax, chunk, live, group = GROUPED_READS[case]
+    assert da._slot_block(b) is not None
+    assert lmax % chunk or "tail" not in case
+    rng = np.random.default_rng(36)
+    r, vw, heads = 24, 16, 4
+    rows = jnp.asarray(rng.standard_normal((b, lmax, r)), dtype)
+    lengths = jnp.asarray(_uneven(rng, b, live, lmax))
+    q = jnp.asarray(rng.standard_normal((b, 1, heads, r)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((b, 1, 1, r)), dtype)
+
+    def read(chunk_size):
+        # a fresh jit: ``decode_attention`` answers from its trace cache
+        return jax.jit(lambda *a: da.decode_attention.__wrapped__(
+            *a, scale=0.25, chunk_size=chunk_size, v_width=vw))(
+                q, new, None, rows, None, lengths)
+
+    da._flat_views.clear()
+    seen = []
+    tile_rows = da._tile_rows
+    monkeypatch.setattr(da, "_tile_rows", lambda *a: seen.append(
+        tile_rows(*a)) or seen[-1])
+    with caplog.at_level("WARNING", logger=da.__name__):
+        got, leaf, _, _ = read(chunk)
+        read(chunk)
+    assert seen == [group, group]
+    assert len([m for m in caplog.messages if "flat view" in m]) == (
+        group == 1)
+    monkeypatch.setattr(da, "_tile_rows", lambda *a: 1)
+    flat, flat_leaf, _, _ = read(chunk)
+    full, full_leaf, _, _ = read(None)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(flat))
+    for other in (flat_leaf, full_leaf):
+        np.testing.assert_array_equal(np.asarray(leaf, np.float32),
+                                      np.asarray(other, np.float32))
+    on = np.asarray(lengths) < lmax
+    assert on.sum() == live and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[on], np.asarray(full)[on],
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("family", ["llama", "falcon_h1"])
+def test_kv_families_never_take_the_one_leaf_read(family, monkeypatch):
+    """The (k, v) families' decode programs (the Mistral and the Falcon-H1
+    cells') read through the per-slot branch as before: the rows' group is
+    never asked for, and a layer's two reads are gathers of ``[C, Hkv, D]``
+    windows of the ``[B * Lmax, Hkv, D]`` view.  (The lowered texts of both
+    programs at the tiny size are the parent's, line for line: PR 36's
+    check against a ``git archive`` copy.)"""
+    from paddle_tpu.models import falcon_h1_decode, llama_decode
+    from paddle_tpu.models.falcon_h1 import (FalconH1Config,
+                                             FalconH1ForCausalLM)
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops import decode_attention as da
+
+    def never(*a):
+        raise AssertionError("a (k, v) cache asked for the rows' group")
+
+    monkeypatch.setattr(da, "_tile_rows", never)
+    paddle.seed(0)
+    if family == "llama":
+        m, programs = LlamaForCausalLM(LlamaConfig.tiny(dtype="float32")), \
+            llama_decode
+    else:
+        m, programs = FalconH1ForCausalLM(FalconH1Config.tiny()), \
+            falcon_h1_decode
+    m.eval()
+    # 8 slots x 80 rows: the per-slot read in blocks of 4, on a geometry no
+    # other test traces (``decode_attention`` is a jit of its own)
+    eng = ServingEngine(m, batch_size=8, max_len=80, prefill_chunk=16,
+                        decode_chunk=16)
+    assert da._slot_block(8) == 4
+    rows = jnp.zeros((8,), jnp.int32)
+    text = programs.serving_decode_steps.__wrapped__.lower(
+        eng._params, eng._cfg, rows, eng._kv.caches, rows,
+        n_steps=eng._sync, chunk_size=eng._chunk, block_tables=None,
+        program_key=eng._pk).as_text()
+    k = eng._kv.caches[0][0]
+    hkv, d = k.shape[2], k.shape[3]
+    windows = re.findall(
+        r"stablehlo\.gather.*slice_sizes = array<i64: 16, %d, %d>.*"
+        r"tensor<%dx%dx%dx" % (hkv, d, 8 * 80, hkv, d), text)
+    # (``decode_attention`` is ONE function of the module, called a layer:
+    # K's read and V's)
+    assert len(windows) == 2, len(windows)
 
 
 # (d) the serving programs through the cache, logits at every position

@@ -233,10 +233,11 @@ def test_loops_are_named(op_names):
 
 
 def test_latent_read_is_named_at_its_call(op_names):
-    """On the chip the latent read's gather becomes a loop of window copies
-    whose operations keep the path of the ``decode_attention`` CALL and
-    lose the scopes inside it (PERF.md section 5, PR 33): the scope the
-    model opens around the call is what names them."""
+    """What the TPU compiler makes at ``decode_attention``'s jit boundary
+    (and of a flat view's gather: a loop of window copies, PERF.md section
+    5, PR 33 — since PR 36 only for a span the rows' groups do not divide)
+    keeps the path of the CALL and loses the scopes inside it: the scope
+    the model opens around the call is what names it."""
     inside = [p for p in op_names["moe_decode"] if "jit(decode_attention)" in p]
     assert inside and all(
         "attn.core" in components(p.split("jit(decode_attention)")[0])
