@@ -104,46 +104,26 @@ class Checks:
 
 
 # ------------------------------------------------------------ in the children
-class JaxEvents:
-    """Counts of JAX's own compile / persistent-cache events."""
+def compile_events(since=None):
+    """JAX's compile work so far, read from the program's own start-up
+    record (``paddle_tpu.observability.compilecache``): executables loaded
+    or compiled, how many of them the persistent cache answered, and the
+    seconds they took — or what was added after ``since`` (an earlier
+    reading)."""
+    from paddle_tpu.observability.compilecache import startup
 
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-    COMPILE = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.hits = self.misses = self.compiles = 0
-        self.compile_s = 0.0
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, name, **_):
-        if name == self.HIT:
-            self.hits += 1
-        elif name == self.MISS:
-            self.misses += 1
-
-    def _duration(self, name, secs, **_):
-        if name == self.COMPILE:
-            self.compiles += 1
-            self.compile_s += secs
-
-    def snapshot(self, since=None):
-        """The counts so far, or what was added after ``since`` (an
-        earlier snapshot)."""
-        now = dict(cache_hits=self.hits, cache_misses=self.misses,
-                   backend_compiles=self.compiles,
-                   compile_s=round(self.compile_s, 1))
-        if since is not None:
-            now = {k: round(v - since[k], 1) for k, v in now.items()}
-        return now
+    log = startup.entries()
+    loads = [e["t_end"] - e["t_start"] for e in log if e["stage"] == "load"]
+    now = dict(cache_hits=sum(e["stage"] == "cache_retrieval" for e in log),
+               backend_compiles=len(loads), compile_s=round(sum(loads), 1))
+    if since is not None:
+        now = {k: round(v - since[k], 1) for k, v in now.items()}
+    return now
 
 
 def start(phase, chip, n_devices=1):
     """Common child prologue: the device (asserted to be a TPU when
-    ``chip``), the compile cache, the event counters."""
+    ``chip``) and the compile cache."""
     import jax
 
     from paddle_tpu.utils.compile_cache import enable_compile_cache
@@ -161,7 +141,7 @@ def start(phase, chip, n_devices=1):
         raise SystemExit(f"[{phase}] needs {n_devices} devices, jax sees "
                          f"{len(jax.devices())}")
     say(phase, compile_cache_dir=cache_dir)
-    return device, JaxEvents()
+    return device
 
 
 def sync_probe(phase, size):
@@ -322,7 +302,7 @@ def serve_phase(size=FULL, chip=True):
     from paddle_tpu.serving import ServingEngine
 
     phase = "serve"
-    device, events = start(phase, chip)
+    device = start(phase, chip)
     check = Checks(phase)
     t0 = time.perf_counter()
     say(phase, native_libs_built=native_build(),
@@ -344,12 +324,12 @@ def serve_phase(size=FULL, chip=True):
     firsts = {}
     for label, opts in (("default", {}), ("fused", size["fused"])):
         tag = f"{phase}/{label}"
-        before = events.snapshot()
+        before = compile_events()
         eng = ServingEngine(model, **size["serve"], **opts)
         reqs, cold_s = run_wave(eng, prompts, new)
         say(tag, wave="cold", requests=len(reqs), seconds=round(cold_s, 2),
             tokens=sum(len(r.output_ids) for r in reqs),
-            **events.snapshot(since=before))
+            **compile_events(since=before))
         check(f"{label}: every request done with {new} tokens",
               all_done(reqs, new), [r.status for r in reqs])
         on = {d for leaf in engine_leaves(eng) for d in leaf.devices()}
@@ -360,7 +340,7 @@ def serve_phase(size=FULL, chip=True):
             check, f"{label}: first tokens agree with the model's own "
             f"forward (finite logits)", firsts[label], refs)
         # the second wave: new prompt lengths on the warm engine
-        before, retraced = events.snapshot(), ""
+        before, retraced = compile_events(), ""
         try:
             with assert_no_retrace():
                 reqs2, warm_s = run_wave(eng, prompts2, new)
@@ -370,7 +350,7 @@ def serve_phase(size=FULL, chip=True):
             say(tag, wave="warm", requests=len(reqs2),
                 seconds=round(warm_s, 2),
                 tokens=sum(len(r.output_ids) for r in reqs2),
-                **events.snapshot(since=before))
+                **compile_events(since=before))
         check(f"{label}: warm wave done, nothing retraced",
               not retraced and all_done(reqs2, new), retraced)
         if label == "fused":
@@ -386,7 +366,7 @@ def serve_phase(size=FULL, chip=True):
         del eng
         gc.collect()
     say(phase, first_tokens_default=firsts["default"],
-        first_tokens_fused=firsts["fused"], **events.snapshot())
+        first_tokens_fused=firsts["fused"], **compile_events())
     return device, check.failed
 
 
@@ -402,7 +382,7 @@ def train_phase(size=FULL, chip=True):
     from paddle_tpu.static.functionalize import build_train_step
 
     phase = "train"
-    device, events = start(phase, chip)
+    device = start(phase, chip)
     check = Checks(phase)
     sync_probe(phase, size)
     tr = size["train"]
@@ -431,7 +411,7 @@ def train_phase(size=FULL, chip=True):
         losses.append(float(loss.numpy()))
         secs.append(round(time.perf_counter() - t0, 2))
     say(phase, losses=[round(l, 4) for l in losses], step_seconds=secs,
-        tokens_per_step=tr["batch"] * tr["seq"], **events.snapshot())
+        tokens_per_step=tr["batch"] * tr["seq"], **compile_events())
     check("loss finite and falling",
           all(np.isfinite(losses)) and losses[2] < losses[0], losses)
     # the compiled text, lowered from the step's own live operands as
@@ -440,7 +420,7 @@ def train_phase(size=FULL, chip=True):
     t0 = time.perf_counter()
     text = step.lower(ids, ids).compile().as_text()
     say(phase, lower_and_compile_s=round(time.perf_counter() - t0, 1),
-        **events.snapshot())
+        **compile_events())
     if chip:
         calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
         for kernel, marker in (("flash-attention fwd", "_flash_fwd_pallas"),
@@ -471,7 +451,7 @@ def multichip_phase(size=FULL, chip=True):
     from paddle_tpu.serving import ServingEngine
 
     phase = "multichip"
-    device, events = start(phase, chip, n_devices=4)
+    device = start(phase, chip, n_devices=4)
     check = Checks(phase)
     if chip:
         check("jax.device_count() == 4", jax.device_count() == 4,
@@ -483,12 +463,12 @@ def multichip_phase(size=FULL, chip=True):
 
     engines, firsts = {}, {}
     for label, opts in (("single", {}), ("tp4", dict(mesh=mesh))):
-        before = events.snapshot()
+        before = compile_events()
         eng = engines[label] = ServingEngine(model, **size["serve"], **opts)
         reqs, secs = run_wave(eng, prompts, new)
         say(f"{phase}/{label}", requests=len(reqs), seconds=round(secs, 2),
             tokens=sum(len(r.output_ids) for r in reqs),
-            **events.snapshot(since=before))
+            **compile_events(since=before))
         check(f"{label}: every request done with {new} tokens",
               all_done(reqs, new), [r.status for r in reqs])
         firsts[label] = [r.output_ids[0] for r in reqs]
@@ -554,7 +534,7 @@ def multichip_phase(size=FULL, chip=True):
           f"max_abs_diff={worst:.4f}")
     check("first tokens agree (ties inside the tolerance aside)", flips == 0,
           f"single={firsts['single']} tp4={firsts['tp4']}")
-    say(phase, **events.snapshot())
+    say(phase, **compile_events())
     for eng in engines.values():
         eng.close()
     return device, check.failed

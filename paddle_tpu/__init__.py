@@ -7,7 +7,15 @@ and the paddle.* API surface users of the reference expect.  See SURVEY.md for t
 component-by-component mapping to the reference (PaddlePaddle @ /root/reference)."""
 from __future__ import annotations
 
-import jax as _jax
+import sys as _sys
+import time as _time
+
+# the start-up record's ``import`` phase (observability/compilecache.py) is
+# stamped here and on the last line; it holds JAX's import only when this
+# package is the first to ask for it
+_IMPORT_T0, _JAX_WAS_IMPORTED = _time.perf_counter(), "jax" in _sys.modules
+
+import jax as _jax  # noqa: E402
 
 # float64/int64 parity with Paddle (reference default int dtype is int64; fp64 kernels
 # exist on every backend).  Creation ops still default to float32.
@@ -273,3 +281,8 @@ def _register_inplace_variants():
 
 
 _register_inplace_variants()
+
+from paddle_tpu.observability import compilecache as _compilecache  # noqa: E402
+
+_compilecache.note_phase("import", _IMPORT_T0, _time.perf_counter(),
+                         jax_imported_before=_JAX_WAS_IMPORTED)

@@ -26,7 +26,8 @@ from __future__ import annotations
 import functools
 
 __all__ = ["span", "chrome_event", "SCOPES", "STATE_SCOPES", "EXPERT_SCOPES",
-           "RESIDUAL_SCOPES", "LOOPS", "SPANS", "COUNTERS", "ATTN_RESIDUALS"]
+           "RESIDUAL_SCOPES", "LOOPS", "SPANS", "STAGES", "COUNTERS",
+           "ATTN_RESIDUALS"]
 
 # model components, the same names in the serving programs
 # (models/llama_decode.py, ops/decode_attention.py) and the training model
@@ -78,11 +79,23 @@ LOOPS = ("decode.steps", "attn.core.chunks")
 ATTN_RESIDUALS = ("attn.res.q", "attn.res.k", "attn.res.v", "attn.res.out",
                   "attn.res.lse")
 # host spans: the engine's phases (serving/engine.py::_phase) and the
-# train step's dispatch (static/functionalize.py)
+# train step's dispatch (static/functionalize.py); then the phases of the
+# start-up record (observability/compilecache.py::phase — a span AND an
+# entry of the in-memory log): an engine's construction, its weights pytree
+# and its cache leaves, the train step's construction, a generation-2
+# collection, and the package's import — the one that is stamped, not
+# opened (it ends before a profiler session could hold it)
 SPANS = ("serving.submit", "serving.step", "serving.admit",
          "serving.spend_prefill", "serving.prefill_chunk",
          "serving.dispatch", "serving.drain", "serving.drain.wait",
-         "serving.emit", "train.step")
+         "serving.emit", "train.step",
+         "serving.init", "serving.init.params", "serving.init.cache",
+         "train.build", "host.gc", "import")
+# the compile stages of the start-up record, one entry an event of JAX's
+# (observability/compilecache.py): tracing to a jaxpr, lowering it to a
+# module, the backend's compile-or-load and, inside that, the persistent
+# cache's retrieval; ``first_call`` is the monitored dispatch that held them
+STAGES = ("trace", "lower", "load", "cache_retrieval", "first_call")
 
 # the engine's counters and gauges that a measurement reads
 # (serving/metrics.py): steps, tokens and prefill chunks (the benchmark's
@@ -93,14 +106,17 @@ SPANS = ("serving.submit", "serving.step", "serving.admit",
 # count at each decode dispatch's host lengths by the read's own rule
 # (ops.decode_attention.kv_rows_read); read / live is the over-read; and
 # what a routed expert FFN served: live (token, expert) pairs by expert,
-# experts touched and counted runs by program (decode / prefill)
+# experts touched and counted runs by program (decode / prefill); and the
+# process-wide seconds of the compile stages by program
+# (observability/compilecache.py, the default registry)
 COUNTERS = ("serving_steps_total", "serving_tokens_emitted_total",
             "serving_prefill_chunks_total", "serving_prefill_runs_total",
             "serving_state_bytes", "serving_state_resets_total",
             "serving_kv_rows_read_total", "serving_kv_rows_live_total",
             "serving_moe_expert_tokens_total",
             "serving_moe_experts_touched_total",
-            "serving_moe_dispatches_total")
+            "serving_moe_dispatches_total",
+            "compile_stage_seconds_total")
 
 SPAN_EVENT_TYPE = "Span"
 

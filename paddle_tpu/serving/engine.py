@@ -87,6 +87,7 @@ tracks the longest LIVE context instead of ``max_len``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import threading
 import time
@@ -100,6 +101,7 @@ from jax.errors import JaxRuntimeError
 
 from paddle_tpu.models.llama_decode import _canon_weight_dtype
 from paddle_tpu.models.serving_family import family_of
+from paddle_tpu.observability.compilecache import phase as startup_phase
 from paddle_tpu.observability.flightrecorder import (
     FlightRecorder, RequestTrace,
 )
@@ -436,6 +438,20 @@ class _Phase:
         return False
 
 
+def _under_init_phase(init):
+    """``ServingEngine.__init__`` under the start-up record's
+    ``serving.init`` phase (observability/compilecache.py).  The flight
+    recorder does not exist when construction begins, so this one phase's
+    event is written when it ends, with the ``seconds`` every phase's
+    event carries."""
+    @functools.wraps(init)
+    def __init__(self, *args, **kwargs):
+        with startup_phase("serving.init", step=-1) as ph:
+            init(self, *args, **kwargs)
+        self._event("init", seconds=ph.seconds)
+    return __init__
+
+
 class ServingEngine:
     """Fixed-batch continuous-batching engine over one causal LM.
 
@@ -545,6 +561,7 @@ class ServingEngine:
     into a ``MetricsExporter``.
     """
 
+    @_under_init_phase
     def __init__(self, model, batch_size=8, max_len=2048, mode="greedy",
                  spec_k=8, sync_every=1, detokenizer=None, registry=None,
                  instrument=True, decode_chunk=256, prefill_chunk=256,
@@ -649,6 +666,9 @@ class ServingEngine:
             self._slo = SLOTracker(
                 objectives=slo, policy=_POLICY,
                 registry=self._m.registry if self._m is not None else None)
+        # the scheduler-step index: -1 until the first step, which is what
+        # construction's own phases carry
+        self._step_idx = -1
         self._traces = OrderedDict()   # rid -> RequestTrace, newest last
         self._trace_cap = 1024
         self._trace_lock = threading.Lock()
@@ -705,7 +725,8 @@ class ServingEngine:
         # programs, its cache leaves, its partition rules — is read off
         # this one record; options the family cannot serve raise here
         fam = self._fam = family_of(model)
-        self._params, self._cfg = fam.decode_params(model, self._lmax)
+        with self._init_phase("init.params"):
+            self._params, self._cfg = fam.decode_params(model, self._lmax)
         rows = fam.rows_leaves(self._cfg)
         nh, (nkv, hd) = rows.query_heads, rows.row
         fam.check_options(dict(
@@ -893,20 +914,21 @@ class ServingEngine:
                     f"heads not shardable {n}-way along {tp_axis!r}: "
                     f"num_attention_heads={nh}, num_key_value_heads={nkv} "
                     f"(the KV cache shards along heads)")
-            self._params, pspecs = shard_decode_params(
-                self._params, mesh, axis=tp_axis,
-                rules=fam.tp_rules(tp_axis))
-            dspecs = None
-            if self._dspec:
-                if dnkv % n or dnh % n:
-                    raise ValueError(
-                        f"draft heads not shardable {n}-way along "
-                        f"{tp_axis!r}: num_attention_heads={dnh}, "
-                        f"num_key_value_heads={dnkv} (the draft KV "
-                        "shards along heads like the target's)")
-                self._dparams, dspecs = shard_decode_params(
-                    self._dparams, mesh, axis=tp_axis,
+            with self._init_phase("init.params"):
+                self._params, pspecs = shard_decode_params(
+                    self._params, mesh, axis=tp_axis,
                     rules=fam.tp_rules(tp_axis))
+                dspecs = None
+                if self._dspec:
+                    if dnkv % n or dnh % n:
+                        raise ValueError(
+                            f"draft heads not shardable {n}-way along "
+                            f"{tp_axis!r}: num_attention_heads={dnh}, "
+                            f"num_key_value_heads={dnkv} (the draft KV "
+                            "shards along heads like the target's)")
+                    self._dparams, dspecs = shard_decode_params(
+                        self._dparams, mesh, axis=tp_axis,
+                        rules=fam.tp_rules(tp_axis))
             d_layers = (len(self._dparams["layers"]) if self._dspec
                         else 0)
             self._tp = serving_tp_programs(
@@ -952,41 +974,42 @@ class ServingEngine:
                 "host_tier requires paged KV (kv_block=): only a block "
                 "pool has demotable prefix chains")
         self._host_min_blocks = max(1, int(host_tier_min_blocks))
-        if self._paged:
-            # a resident draft model is a second pool tenant: its chains
-            # grow in lockstep with the target's, so the default pool
-            # doubles (an explicit max_live_tokens is the caller's
-            # sizing decision and is respected as-is)
-            self._kv = PagedKVCacheManager(
-                len(self._params["layers"]), self._B, self._lmax, nkv, hd,
-                dtype, block=kv_block,
-                max_live_tokens=(int(max_live_tokens) if max_live_tokens
-                                 else (2 if self._dspec else 1)
-                                 * self._B * self._lmax),
-                sharding=cache_sharding, on_event=self._kv_event,
-                scale_sharding=scale_sharding, host_store=host_store)
-        else:
-            self._kv = KVCacheManager(
-                len(self._params["layers"]), self._B, self._lmax, nkv, hd,
-                dtype, sharding=cache_sharding,
-                scale_sharding=scale_sharding,
-                init_layer=lambda: fam.init_layer_cache(
-                    self._cfg, self._B, self._lmax, dtype))
-            if self._dspec:
-                # dense draft tenancy: a SEPARATE per-draft-layer cache
-                # list (dense rows are slot-indexed — cohabitation in the
-                # target's arrays would clobber it), same storage dtype
-                # rules and head sharding as the target's
-                from paddle_tpu.serving.kv_cache import _place_caches
-                ddtype = (self._kv_dtype if self._kv_dtype is not None
-                          else self._dparams["embed"].dtype)
-                self._dcaches = [
-                    fam.init_layer_cache(self._dcfg, self._B, self._lmax,
-                                         ddtype)
-                    for _ in range(len(self._dparams["layers"]))]
-                if cache_sharding is not None:
-                    self._dcaches = _place_caches(
-                        self._dcaches, cache_sharding, scale_sharding)
+        with self._init_phase("init.cache"):
+            if self._paged:
+                # a resident draft model is a second pool tenant: its chains
+                # grow in lockstep with the target's, so the default pool
+                # doubles (an explicit max_live_tokens is the caller's
+                # sizing decision and is respected as-is)
+                self._kv = PagedKVCacheManager(
+                    len(self._params["layers"]), self._B, self._lmax, nkv, hd,
+                    dtype, block=kv_block,
+                    max_live_tokens=(int(max_live_tokens) if max_live_tokens
+                                     else (2 if self._dspec else 1)
+                                     * self._B * self._lmax),
+                    sharding=cache_sharding, on_event=self._kv_event,
+                    scale_sharding=scale_sharding, host_store=host_store)
+            else:
+                self._kv = KVCacheManager(
+                    len(self._params["layers"]), self._B, self._lmax, nkv, hd,
+                    dtype, sharding=cache_sharding,
+                    scale_sharding=scale_sharding,
+                    init_layer=lambda: fam.init_layer_cache(
+                        self._cfg, self._B, self._lmax, dtype))
+                if self._dspec:
+                    # dense draft tenancy: a SEPARATE per-draft-layer cache
+                    # list (dense rows are slot-indexed — cohabitation in the
+                    # target's arrays would clobber it), same storage dtype
+                    # rules and head sharding as the target's
+                    from paddle_tpu.serving.kv_cache import _place_caches
+                    ddtype = (self._kv_dtype if self._kv_dtype is not None
+                              else self._dparams["embed"].dtype)
+                    self._dcaches = [
+                        fam.init_layer_cache(self._dcfg, self._B, self._lmax,
+                                             ddtype)
+                        for _ in range(len(self._dparams["layers"]))]
+                    if cache_sharding is not None:
+                        self._dcaches = _place_caches(
+                            self._dcaches, cache_sharding, scale_sharding)
         # a family with routed experts hands back, beside the tokens of a
         # dispatch and of a prefill chunk, the experts that served each
         # live row: drained with the tokens, counted, and appended to
@@ -1081,7 +1104,6 @@ class ServingEngine:
         self._retry_attempts = max(1, int(retry_attempts))
         self._retry_backoff = float(retry_backoff)
         self._faults = faults
-        self._step_idx = -1
         # fleet-facing host counters, maintained UNCONDITIONALLY (a
         # router reads them through stats() even on instrument=False
         # engines): paged prompt/reuse token totals (the fleet hit-rate
@@ -1541,6 +1563,14 @@ class ServingEngine:
             detail["rid"] = req.rid
         return _Phase(span("serving." + name, step=self._step_idx, **detail),
                       ev, observe)
+
+    def _init_phase(self, name):
+        """``_phase`` for a part of construction: its span is the start-up
+        record's ``phase`` (a span AND an entry of the in-memory log,
+        observability/compilecache.py), so what construction cost is read
+        from the program's own record after the fact."""
+        return _Phase(startup_phase("serving." + name, step=self._step_idx),
+                      self._event(name), None)
 
     # --------------------------------------------------- program dispatch
     # the four compiled entry points behind ONE seam: mesh=None dispatches

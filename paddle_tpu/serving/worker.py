@@ -157,6 +157,13 @@ class _WorkerProc:
         self._event("ready", pid=os.getpid(), role=self.role,
                     pool=self._pool)
         self._flush_events()
+        # the cold-start account of this replica: where the seconds before
+        # "ready" went, by phase and by (program, compile stage)
+        from ..observability.compilecache import report
+        _LOG.info("ready; start-up record (s, largest first): %s", "; ".join(
+            " ".join(filter(None, (r["name"], r["stage"],
+                                   f"{r['seconds']:.3f}")))
+            for r in report()[:24]))
 
     def _build_model(self, m=None):
         import paddle_tpu as paddle

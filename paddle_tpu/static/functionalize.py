@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.autograd import engine as _engine
-from paddle_tpu.observability.compilecache import CompileCacheMonitor
+from paddle_tpu.observability.compilecache import CompileCacheMonitor, phase
 from paddle_tpu.observability.metrics import get_registry
 from paddle_tpu.observability.trace import span
 from paddle_tpu.tensor.tensor import Tensor
@@ -302,8 +302,13 @@ def amp_args_from_strategy(strategy):
 
 def build_train_step(network, loss_fn, optimizer, recompute=False, donate=True,
                      amp_level=None, amp_dtype="bfloat16"):
-    return TrainStep(network, loss_fn, optimizer, recompute=recompute,
-                     donate=donate, amp_level=amp_level, amp_dtype=amp_dtype)
+    # the step's construction (the functional state, the optimizer's
+    # accumulators) is a phase of the start-up record; its first call is
+    # the ``first_call`` entry of ``functionalize/train_step``
+    with phase("train.build"):
+        return TrainStep(network, loss_fn, optimizer, recompute=recompute,
+                         donate=donate, amp_level=amp_level,
+                         amp_dtype=amp_dtype)
 
 
 def build_eval_fn(network, loss_fn=None):

@@ -93,6 +93,11 @@ class TestFleetSmoke:
         # context exit closed the fleet: graceful drain, rc 0 everywhere
         for name, proc in procs.items():
             assert proc.poll() == 0, (name, proc.poll())
+            # each replica logged its cold-start account once, at "ready"
+            # (observability/compilecache.py::report)
+            log = (tmp_path / f"{name}.log").read_text()
+            assert log.count("start-up record") == 1, log
+            assert "serving.init" in log and "import" in log
 
     def test_launch_rejects_invalid_config(self, tmp_path):
         cfg = FleetConfig(engine={"batch_size": 2, "max_len": 100,

@@ -7,7 +7,9 @@ programs by name: the XLA module names derived from the jitted functions
 ``op_name``, and the host spans of the engine's phases on the profiler's
 timeline.  A rename that silences a reader fails here first.
 """
+import gc
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +27,9 @@ from paddle_tpu.models.llama_decode import (
     serving_decode_steps, serving_prefill_chunk,
 )
 from paddle_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM
+from paddle_tpu.observability import compilecache
 from paddle_tpu.observability.trace import (
-    COUNTERS, EXPERT_SCOPES, LOOPS, RESIDUAL_SCOPES, SCOPES, SPANS,
+    COUNTERS, EXPERT_SCOPES, LOOPS, RESIDUAL_SCOPES, SCOPES, SPANS, STAGES,
     STATE_SCOPES,
 )
 from paddle_tpu.serving import Request, ServingEngine
@@ -331,12 +334,18 @@ def test_expert_counters_are_the_names_the_readers_ask_for():
 
 @pytest.mark.parametrize("name", COUNTERS)
 def test_engine_registers_every_counter_of_the_list(name):
-    """Every name of ``trace.COUNTERS`` is a series an instrumented engine
-    registers at construction, before a request has run."""
-    from paddle_tpu.observability.metrics import MetricsRegistry
+    """Every ``serving_*`` name of ``trace.COUNTERS`` is a series an
+    instrumented engine registers at construction, before a request has
+    run; the compile stages' seconds are the process's (the one listener
+    feeds the default registry, whatever registry an engine was given)."""
+    from paddle_tpu.observability.metrics import (
+        MetricsRegistry, get_registry,
+    )
 
     reg = MetricsRegistry()
     tiny_engine(registry=reg)
+    if not name.startswith("serving_"):
+        reg = get_registry()
     assert reg.get(name) is not None
 
 
@@ -403,22 +412,26 @@ def test_chunks_and_runs_read_the_numbers_computed_by_hand():
 # (c) the host spans, on the profiler's timeline
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """A few engine steps and two train steps under ONE profiler session:
-    (spans of the xplane, the engine's flight-recorder events)."""
+    """An engine and a train step built, a few engine steps, two train
+    steps and a full collection under ONE profiler session: (spans of the
+    xplane, the engine's flight-recorder events)."""
     trace_dir = tmp_path_factory.mktemp("trace")
-    eng = tiny_engine()
-    step, ids = tiny_train_step()
     with profiled(trace_dir):
+        eng = tiny_engine()
+        step, ids = tiny_train_step()
         for p, n in zip(PROMPTS, NEW):
             eng.submit(Request(p, n))
         eng.run()
         step(ids, ids)
         step(ids, ids)
-    return (host_events(trace_dir, ["serving.", "train."]),
+        gc.collect()
+    return (host_events(trace_dir, ["serving.", "train.", "host."]),
             eng.recorder.events())
 
 
-@pytest.mark.parametrize("name", SPANS)
+# ``import`` is the one phase that is stamped, not opened: it ends before a
+# profiler session could hold it
+@pytest.mark.parametrize("name", [n for n in SPANS if n != "import"])
 def test_span_is_an_event_of_the_xplane(traced, name):
     assert [e for e in traced[0] if e["name"] == name]
 
@@ -431,6 +444,8 @@ def test_span_is_an_event_of_the_xplane(traced, name):
     ("serving.drain", "serving.step"),
     ("serving.drain.wait", "serving.drain"),
     ("serving.emit", "serving.drain"),
+    ("serving.init.params", "serving.init"),
+    ("serving.init.cache", "serving.init"),
 ])
 def test_spans_nest_and_share_their_step(traced, child, parent):
     parents = [e for e in traced[0] if e["name"] == parent]
@@ -482,6 +497,186 @@ def test_final_chunk_is_marked_on_the_timeline():
     assert [m["final"] for m in chunks] == [False, True]
     first = [m["t"] for m in r.timeline() if m["phase"] == "decoding"][0]
     assert chunks[-1]["t"] <= r.t_first <= first
+
+
+# (c') the start-up record: compile stages by program, phases, collector
+# pauses (observability/compilecache.py), each test on a log of its own —
+# the process's has whatever the worker's earlier tests compiled
+@pytest.fixture
+def startup(monkeypatch):
+    log = compilecache.StartupLog()
+    monkeypatch.setattr(compilecache, "startup", log)
+    return log
+
+
+def monitored_program():
+    """A fresh monitor over a fresh jit (nothing else has traced it) whose
+    body calls an inner jit, as the serving programs do."""
+    mon = compilecache.CompileCacheMonitor("contract")
+
+    @jax.jit
+    def _contract_inner(x):
+        return x * 2.0
+
+    @jax.jit
+    def _contract_program(x):
+        mon.mark_trace("program")
+        return _contract_inner(x) + 1.0
+
+    return mon, _contract_program
+
+
+def test_a_miss_leaves_its_stages_under_one_first_call(startup):
+    from paddle_tpu.observability.metrics import get_registry
+
+    mon, fn = monitored_program()
+    hist = get_registry().get("compile_seconds").labels(
+        cache="contract", program="program")
+    seconds_before = hist.sum
+    x = jnp.arange(7.0)
+    jax.block_until_ready(x)
+    startup_before = len(startup.entries())
+    t0 = time.perf_counter()
+    mon.call("program", fn, x)
+    t1 = time.perf_counter()
+    mine = startup.entries()[startup_before:]
+    assert [e["stage"] for e in mine] == ["trace", "lower", "load",
+                                          "first_call"]
+    assert all((e["cache"], e["program"]) == ("contract", "program")
+               for e in mine)
+    trace, lower, load, first = mine
+    # the drivers' clock, in order, the stages inside the dispatch
+    assert t0 <= first["t_start"] <= trace["t_start"] <= trace["t_end"] \
+        <= lower["t_end"] <= load["t_end"] <= first["t_end"] <= t1
+    assert lower["t_start"] >= trace["t_end"] - 1e-4
+    assert load["t_start"] >= lower["t_end"] - 1e-4
+    assert len({e["tid"] for e in mine}) == 1
+    # the inner jit's trace is folded into the program's, JAX's names kept
+    assert trace["inner"] >= 1 and "_contract_program" in trace["fun_name"]
+    assert "_contract_program" in load["fun_name"]
+    # ``compile_seconds`` and ``first_call`` are one measurement
+    assert hist.sum - seconds_before == pytest.approx(
+        first["t_end"] - first["t_start"])
+    # the second call is a hit: two stores, no entry
+    mon.call("program", fn, x)
+    assert len(startup.entries()) == startup_before + 4
+    stages = get_registry().get("compile_stage_seconds_total")
+    for e in (trace, lower, load):
+        assert stages.labels(cache="contract", program="program",
+                             stage=e["stage"]).value > 0
+
+
+def test_a_compile_outside_a_monitored_call_keeps_jaxs_name(startup):
+    compilecache.CompileCacheMonitor("contract")     # the listener is on
+
+    @jax.jit
+    def _contract_helper(x):
+        return x - 3.0
+
+    _contract_helper(jnp.arange(5.0))
+    mine = [e for e in startup.entries()
+            if "_contract_helper" in e.get("fun_name", "")]
+    assert [e["stage"] for e in mine] == ["trace", "lower", "load"]
+    assert all((e["cache"], e["program"]) == ("-", "-") for e in mine)
+    assert not [e for e in startup.entries() if e["stage"] == "first_call"]
+
+
+def test_the_log_keeps_its_first_entries_when_full():
+    log = compilecache.StartupLog(capacity=3)
+    for k in range(5):
+        log.add({"stage": "phase", "name": f"p{k}", "t_start": k,
+                 "t_end": k + 1, "parent": None, "tid": 0})
+    assert [e["name"] for e in log.entries()] == ["p0", "p1", "p2"]
+    assert log.dropped == 2
+
+
+def test_inner_traces_fold_across_what_lies_between_them():
+    """A collection (or a helper's load) that ends between two inner
+    traces does not stop the fold: the outer trace takes both."""
+    log = compilecache.StartupLog()
+    mk = lambda stage, t0, t1, **kw: dict(
+        stage=stage, cache="c", program="p", fun_name=stage, t_start=t0,
+        t_end=t1, parent=None, tid=7, **kw)
+    log.add_stage(mk("trace", 0.1, 0.2))                # an earlier program
+    log.add_stage(mk("trace", 1.0, 1.2, inner=3))
+    log.add({"stage": "phase", "name": "host.gc", "t_start": 1.3,
+             "t_end": 1.4, "parent": None, "tid": 7})
+    log.add_stage(mk("load", 1.45, 1.5))
+    log.add_stage(dict(mk("trace", 1.5, 1.6), tid=8))   # another thread's
+    log.add_stage(mk("trace", 1.5, 1.6))
+    own = log.add_stage(mk("trace", 0.9, 2.0))
+    assert own == pytest.approx(1.1 - 0.2 - 0.1)
+    assert [(e["stage"], e["t_start"], e.get("inner"), e["tid"])
+            for e in log.entries()] == [
+        ("trace", 0.1, None, 7), ("phase", 1.3, None, 7),
+        ("load", 1.45, None, 7), ("trace", 1.5, None, 8),
+        ("trace", 0.9, 5, 7)]
+
+
+def test_construction_phases_are_entries_with_their_parents(startup):
+    eng = tiny_engine()
+    tiny_train_step()
+    by = {e["name"]: e for e in startup.entries() if e["stage"] == "phase"}
+    assert {"serving.init", "serving.init.params", "serving.init.cache",
+            "train.build"} <= set(by)
+    init = by["serving.init"]
+    for child in ("serving.init.params", "serving.init.cache"):
+        assert by[child]["parent"] == "serving.init"
+        assert init["t_start"] <= by[child]["t_start"] \
+            <= by[child]["t_end"] <= init["t_end"]
+    assert by["serving.init"]["parent"] is by["train.build"]["parent"] is None
+    # a compile inside a phase names the phase that was open
+    @jax.jit
+    def _contract_inside(x):
+        return x + 5.0
+
+    with compilecache.phase("train.build"):
+        _contract_inside(jnp.arange(4.0))
+    inside = [e for e in startup.entries()
+              if "_contract_inside" in e.get("fun_name", "")]
+    assert len(inside) == 3
+    assert all(e["parent"] == "train.build" for e in inside)
+    kinds = [e["kind"] for e in eng.recorder.events()]
+    assert kinds[:3] == ["init.params", "init.cache", "init"]
+
+
+def test_a_full_collection_is_one_host_gc_entry(startup):
+    compilecache.CompileCacheMonitor("contract")     # the callback is on
+    pauses = lambda: sum(e.get("name") == "host.gc"
+                         for e in startup.entries())
+    gc.collect()
+    n = pauses()
+    gc.collect(0)
+    gc.collect(1)
+    assert pauses() == n
+    gc.collect()
+    assert pauses() == n + 1 >= 2
+
+
+def test_every_name_of_the_record_is_in_the_vocabulary(startup):
+    mon, fn = monitored_program()
+    mon.call("program", fn, jnp.arange(3.0))
+    tiny_engine()
+    tiny_train_step()
+    gc.collect()
+    seen = startup.entries()
+    assert {e["stage"] for e in seen} == set(STAGES) - {"cache_retrieval"} \
+        | {"phase"}
+    assert {e["name"] for e in seen if e["stage"] == "phase"} <= set(SPANS)
+    assert "compile_stage_seconds_total" in COUNTERS
+    rows = compilecache.report()
+    assert rows == sorted(rows, key=lambda r: -r["seconds"])
+    assert {"kind": "program", "name": "contract/program",
+            "stage": "first_call"}.items() <= {
+        k: v for r in rows if r["name"] == "contract/program"
+        and r["stage"] == "first_call" for k, v in r.items()}.items()
+
+
+def test_the_packages_import_is_the_first_entry():
+    first = compilecache.startup.entries()[0]
+    assert (first["stage"], first["name"]) == ("phase", "import")
+    assert first["t_end"] > first["t_start"]
+    assert isinstance(first["detail"]["jax_imported_before"], bool)
 
 
 # (d) tracing changes nothing that is served or learned
