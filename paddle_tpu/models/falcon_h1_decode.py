@@ -27,7 +27,8 @@ invariants (``tests/test_falcon_h1_serving.py`` holds each):
   real input, so the padded end advances neither;
 - **parking**: the decode program keeps the state and the tail of every slot
   whose length operand is ``Lmax`` (``masked_lengths``: freed slots and slots
-  mid-prefill) bit for bit.  The pipeline's one-step-late stale step may
+  mid-prefill) bit for bit; the state update (``ops.ssm.ssm_state_update``)
+  never reads or writes a parked slot's state.  The pipeline's one-step-late stale step may
   still update a slot that was just retired; the next tenant's first chunk
   is dispatched after it in device program order and resets the slot.
 """

@@ -101,10 +101,12 @@ STAGES = ("trace", "lower", "load", "cache_retrieval", "first_call")
 # (serving/metrics.py): steps, tokens and prefill chunks (the benchmark's
 # decode_batch_mean and window record), the runs of the prefill program
 # that spent those chunks (chunks / runs: the chunks one read of the
-# weights served), the recurrent state beside the K/V
-# rows, and the decode cache read against the rows it needs — one layer's
-# count at each decode dispatch's host lengths by the read's own rule
-# (ops.decode_attention.kv_rows_read); read / live is the over-read; and
+# weights served), the recurrent state beside the K/V rows and the slots
+# whose state one layer's decode update reads against those it skips
+# (ops.ssm.ssm_state_update), and the decode cache read against the rows
+# it needs — one layer's count at each decode dispatch's host lengths by
+# the read's own rule (ops.decode_attention.kv_rows_read); read / live is
+# the over-read; and
 # what a routed expert FFN served: live (token, expert) pairs by expert,
 # experts touched and counted runs by program (decode / prefill); and the
 # process-wide seconds of the compile stages by program
@@ -112,6 +114,8 @@ STAGES = ("trace", "lower", "load", "cache_retrieval", "first_call")
 COUNTERS = ("serving_steps_total", "serving_tokens_emitted_total",
             "serving_prefill_chunks_total", "serving_prefill_runs_total",
             "serving_state_bytes", "serving_state_resets_total",
+            "serving_state_slots_read_total",
+            "serving_state_slots_skipped_total",
             "serving_kv_rows_read_total", "serving_kv_rows_live_total",
             "serving_moe_expert_tokens_total",
             "serving_moe_experts_touched_total",

@@ -1611,23 +1611,31 @@ class ServingEngine:
         """The lengths operand of one decode dispatch (every slot that is
         not ``active`` parked), counted on the way: the rows one layer's
         cache read touches at these lengths against the rows its live
-        slots attend to (``serving_kv_rows_*_total``) — a few integer
-        operations on the host's mirror, nothing on the device.  Spec
-        rounds count their ``k + 1`` verify tokens at the mirror's
-        lengths, which trail the device's by the round in flight."""
+        slots attend to (``serving_kv_rows_*_total``) and, for a family
+        with recurrent state, the slots whose state one layer's update
+        reads against those it skips (``serving_state_slots_*_total``) —
+        a few integer operations on the host's mirror, nothing on the
+        device.  Spec rounds count their ``k + 1`` verify tokens at the
+        mirror's lengths, which trail the device's by the round in
+        flight."""
         m = self._m
         if m is not None:
             spec = self._mode == "spec"
             # the tree verify of a draft model attends under a bias
             plain = not (self._paged or self._q8 or (spec and self._dspec))
             for step in range(1 if spec else self._sync):
+                lengths = np.where(active, self._kv.lengths + step,
+                                   self._kv.max_len)
                 read, live = kv_rows_read(
-                    np.where(active, self._kv.lengths + step,
-                             self._kv.max_len),
-                    self._spec_k + 1 if spec else 1, self._chunk,
+                    lengths, self._spec_k + 1 if spec else 1, self._chunk,
                     self._kv.max_len, plain)
                 m.kv_rows_read.inc(read)
                 m.kv_rows_live.inc(live)
+                if self._state_idx:
+                    # the update's own rule: a slot is live below Lmax
+                    slots = int(np.sum(lengths < self._kv.max_len))
+                    m.state_slots_read.inc(slots)
+                    m.state_slots_skipped.inc(len(lengths) - slots)
         return self._kv.device_lengths(active)
 
     def _call_decode(self, cur, dev_len):

@@ -181,6 +181,18 @@ class EngineMetrics:
             "serving_state_resets_total",
             "slot admissions whose first prefill chunk zeroed the slot's "
             "recurrent state", L).labels(**lbl)
+        # the decode state update against the slots it skips (one layer's
+        # count at each decode dispatch's host lengths, by the update's own
+        # rule: ops.ssm.ssm_state_update); skipped / (read + skipped) is
+        # the share of the whole batch's state bytes not moved
+        self.state_slots_read = reg.counter(
+            "serving_state_slots_read_total",
+            "slots whose recurrent state one layer's decode update reads, "
+            "summed over decode dispatches and steps", L).labels(**lbl)
+        self.state_slots_skipped = reg.counter(
+            "serving_state_slots_skipped_total",
+            "parked slots one layer's decode update skips, summed over "
+            "decode dispatches and steps", L).labels(**lbl)
         # the decode cache read against the rows it needs (one layer's
         # count at each decode dispatch's host lengths, by the read's own
         # rule: ops.decode_attention.kv_rows_read); read / live is the

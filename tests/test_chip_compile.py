@@ -18,6 +18,7 @@ every test file), in this ONE file, in the test's own process.
 """
 import collections
 import functools
+import math
 import re
 
 import jax
@@ -293,12 +294,18 @@ def _falcon_program(one_chip, program, batch=64, lmax=1024, layers=2):
 
 
 @pytest.mark.parametrize("program", ["decode_steps", "prefill_chunk"])
-def test_state_space_serving_programs_compile_in_place(one_chip, program):
+def test_state_space_serving_programs_compile_in_place(one_chip, program,
+                                                       monkeypatch):
     """The two Falcon-H1 serving programs compile for the chip at published
     widths, read every weight in the order it is stored (the Q/K/V barrier
     of ``falcon_h1.attn_qkv``), and update the float32 recurrent state
     ``[64, 32, 128, 256]`` in place: no copy of a state leaf (4 of them
-    would be the cell's 1.6 GB again)."""
+    would be the cell's 1.6 GB again).  The decode update is the Pallas
+    kernel under ``ssm.state_update`` (its ``interpret`` rule steered to
+    the TPU branch for the trace), and no select over a whole state leaf
+    is left of the update that read and wrote every slot."""
+    if program == "decode_steps":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lowered, state = _falcon_program(one_chip, program)
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -309,6 +316,21 @@ def test_state_space_serving_programs_compile_in_place(one_chip, program):
     # donated caches are updated in place: the temporaries stay far under
     # one state leaf (268 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+    if program == "decode_steps":
+        kernels = [ln for ln in text.split("\n")
+                   if "custom_call_target=\"tpu_custom_call\"" in ln
+                   and "ssm_state_update" in ln]
+        assert len(kernels) == 2                      # one a layer
+        assert all("ssm.state_update" in _op_name(ln) for ln in kernels)
+        assert [ln for ln in text.split("\n")
+                if (m := re.search(r"= f32\[([\d,]+)\]\S* select\(", ln))
+                and math.prod(map(int, m.group(1).split(",")))
+                == math.prod(state)] == []
+
+
+def _op_name(line):
+    m = re.search(r'op_name="([^"]*)"', line)
+    return m.group(1) if m else ""
 
 
 # GLM-4.7-Flash's widths (the benchmark's glm47flash_code_steady cell): the

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import _wide_runs as W
 import paddle_tpu as paddle
@@ -161,6 +162,45 @@ def test_state_update_is_one_step_of_the_recurrence_and_parks():
         np.testing.assert_allclose(y[b], wy[0], rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(new[b], ws, rtol=1e-5, atol=1e-5)
     assert (np.asarray(new[1]) == np.asarray(state[1])).all()
+
+
+def whole_batch_update(x, dt, a, bm, cm, d, state):
+    """The decode update over every slot, in plain ``jnp`` (the body the
+    kernel replaced): what each live slot must read."""
+    keep = jnp.exp(dt * a)[..., None, None]
+    add = (dt[..., None] * x)[..., None] * bm[:, :, None, None, :]
+    new = state * keep + add
+    y = jnp.sum(new * cm[:, :, None, None, :], axis=-1) + x * d[..., None]
+    return y, new
+
+
+@pytest.mark.parametrize("live", [
+    [0, 0, 0, 0, 0], [1, 1, 1, 1, 1], [0, 0, 1, 0, 0], [0, 0, 0, 0, 1],
+    [0, 1, 0, 1, 1]], ids=["none", "all", "one", "last", "scattered"])
+def test_state_update_moves_only_the_live_slots(live):
+    """The kernel in the TPU interpreter (its buffers start as NaN): the
+    live slots read the whole-batch formula, a parked slot's state comes
+    back bit for bit and its ``y`` is finite — with no live slot too,
+    where place 0 still visits a slot.  Toy widths, one head block."""
+    x, dt, a, bm, cm, d, _ = (v.astype(jnp.float32)
+                              for v in scan_operands(5, seed=7))
+    g, e, p, n = 2, 4, 8, 16
+    state = jax.random.normal(jax.random.PRNGKey(8), (5, g, e, p, n),
+                              jnp.float32)
+    want_y, want_s = whole_batch_update(x, dt, a, bm, cm, d, state)
+    y, new = ssm.ssm_state_update(x, dt, a, bm, cm, d, state,
+                                  jnp.array(live, bool),
+                                  interpret=pltpu.InterpretParams())
+    y, new, state = np.asarray(y), np.asarray(new), np.asarray(state)
+    for b, on in enumerate(live):
+        if on:
+            np.testing.assert_allclose(new[b], want_s[b], rtol=1e-6,
+                                       atol=1e-6)
+            np.testing.assert_allclose(y[b], want_y[b], rtol=1e-5,
+                                       atol=1e-5)
+        else:
+            assert (new[b] == state[b]).all()
+            assert np.isfinite(y[b]).all()
 
 
 def test_conv_tail_is_carried_across_a_chunk_boundary():
@@ -408,6 +448,16 @@ def test_state_counters(model):
     assert reg.get("serving_state_bytes").labels(**lbl).value \
         == 2 * per_slot * model.config.num_hidden_layers
     assert reg.get("serving_state_resets_total").labels(**lbl).value == 3
+    read = reg.get("serving_state_slots_read_total").labels(**lbl)
+    skipped = reg.get("serving_state_slots_skipped_total").labels(**lbl)
+    assert read.value > 0 and (read.value + skipped.value) % 2 == 0
+    # at a known mask: each of the dispatch's sync_every steps reads the
+    # live slot and skips the parked one, then reads both
+    for mask, live in (([True, False], 1), ([True, True], 2)):
+        r0, s0 = read.value, skipped.value
+        eng._decode_lengths(np.array(mask))
+        assert read.value - r0 == live * eng._sync
+        assert (read.value - r0) + (skipped.value - s0) == 2 * eng._sync
     # a model without recurrent state reports none
     reg2 = MetricsRegistry()
     llama = LlamaForCausalLM(LlamaConfig.tiny(dtype="float32"))
@@ -415,8 +465,10 @@ def test_state_counters(model):
     eng2 = ServingEngine(llama, batch_size=2, max_len=LMAX, registry=reg2)
     eng2.submit(Request(prompts((9,))[0], 3))
     eng2.run()
-    assert reg2.get("serving_state_bytes").labels(**lbl).value == 0
-    assert reg2.get("serving_state_resets_total").labels(**lbl).value == 0
+    for name in ("serving_state_bytes", "serving_state_resets_total",
+                 "serving_state_slots_read_total",
+                 "serving_state_slots_skipped_total"):
+        assert reg2.get(name).labels(**lbl).value == 0
 
 
 # (e) what cannot be served raises at construction, naming what is missing
